@@ -17,6 +17,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmarks import (table1_kernel_svm, table2_wordpairs, fig45_cws_mse,
                         fig6_tstar_only, fig78_linear_svm, bench_cws_kernel,
                         roofline)
+from repro.launch.compile_cache import setup_compile_cache
 
 SUITES = {
     "table1": table1_kernel_svm.run,
@@ -35,6 +36,7 @@ def main() -> None:
                     default=bool(os.environ.get("REPRO_FAST")))
     ap.add_argument("--only", default="")
     args = ap.parse_args()
+    setup_compile_cache()
 
     failures = []
     for name, fn in SUITES.items():

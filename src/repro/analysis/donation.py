@@ -31,7 +31,7 @@ __all__ = ["audit_donation", "alias_roots"]
 
 _MAX_DEPTH = 8
 
-_CALL_PRIMS = ("pjit", "closed_call", "core_call", "remat", "remat2",
+_CALL_PRIMS = ("jit", "pjit", "closed_call", "core_call", "remat", "remat2",
                "custom_jvp_call", "custom_vjp_call")
 
 

@@ -208,7 +208,7 @@ class _Interp:
         if handler is not None:
             out = handler(eqn, ins)
             return out if isinstance(out, list) else [out]
-        if name in ("pjit", "closed_call", "core_call", "remat_call",
+        if name in ("jit", "pjit", "closed_call", "core_call", "remat_call",
                     "checkpoint", "custom_jvp_call", "custom_vjp_call",
                     "custom_vjp_call_jaxpr"):
             sub = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr") \
@@ -285,6 +285,25 @@ class _Interp:
         hi = np.nanmax(np.where(np.isnan(cands), -np.inf, cands), axis=0)
         return self._wrap_check(eqn, eqn.outvars[0].aval.shape, a.dtype,
                                 lo, hi, "mul")
+
+    def p_dot_general(self, eqn, ins):
+        """Monotone bound for nonnegative operands without batch dims
+        (the packed emit's 0/1-selection matmul): the product of the
+        lower bounds and of the upper bounds; anything else is TOP."""
+        a, b = ins
+        out = eqn.outvars[0].aval
+        (ca, cb), (ba, bb) = eqn.params["dimension_numbers"]
+        if ba or bb or np.any(a.lo < 0) or np.any(b.lo < 0):
+            return _top(out.shape, out.dtype)
+
+        def contract(x, y):
+            return np.tensordot(np.broadcast_to(x, a.shape),
+                                np.broadcast_to(y, b.shape),
+                                axes=(tuple(ca), tuple(cb)))
+        with np.errstate(invalid="ignore"):
+            lo, hi = contract(a.lo, b.lo), contract(a.hi, b.hi)
+        return self._wrap_check(eqn, out.shape, out.dtype, lo, hi,
+                                "dot_general")
 
     def p_neg(self, eqn, ins):
         (a,) = ins

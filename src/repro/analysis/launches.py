@@ -90,6 +90,14 @@ def _index_map_fn(block_mapping) -> Optional[Callable]:
     return run
 
 
+def _block_dim(b) -> int:
+    """Block extent of one dim: a plain int, or a ``pl.Blocked`` /
+    ``pl.Element`` wrapper carrying ``block_size``; a squeezed dim is 1."""
+    if isinstance(b, int):
+        return b
+    return int(getattr(b, "block_size", 1))
+
+
 def _launch_of_eqn(eqn) -> PallasLaunch:
     gm = eqn.params["grid_mapping"]
     grid = tuple(int(g) for g in gm.grid)
@@ -97,7 +105,7 @@ def _launch_of_eqn(eqn) -> PallasLaunch:
     n_scratch = gm.num_scratch_operands
 
     def operand(role, bm, padded):
-        block = tuple(int(b) for b in bm.block_shape)
+        block = tuple(_block_dim(b) for b in bm.block_shape)
         return OperandInfo(
             role=role,
             name=str(getattr(bm, "origin", "") or role),

@@ -53,10 +53,13 @@ __all__ = ["audit_determinism", "audit_trio_signatures",
 NONDETERMINISTIC_PRIMS = ("rng_bit_generator", "rng_uniform")
 ORDER_SENSITIVE_SCATTERS = ("scatter-add", "scatter_add", "scatter-mul",
                             "scatter_mul")
-# "psum2" is the shard_map-internal spelling of psum; it canonicalizes
-# to "psum" for both detection and per-site blessing
-COLLECTIVE_PRIMS = ("psum", "psum2", "all_gather", "all_to_all",
-                    "ppermute", "reduce_scatter", "pmax", "pmin")
+# "psum2" and "psum_invariant" (shard_map with replication checks on)
+# are internal spellings of psum; they canonicalize to "psum" for both
+# detection and per-site blessing
+COLLECTIVE_PRIMS = ("psum", "psum2", "psum_invariant", "all_gather",
+                    "all_to_all", "ppermute", "reduce_scatter", "pmax",
+                    "pmin")
+_CANONICAL = {"psum2": "psum", "psum_invariant": "psum"}
 
 
 def _is_float(dt) -> bool:
@@ -108,7 +111,7 @@ def audit_determinism(fn, args, *, name: str = "fn",
 
     def visit(eqn):
         pname = eqn.primitive.name
-        canonical = pname[:-1] if pname.endswith("2") else pname
+        canonical = _CANONICAL.get(pname, pname)
         if pname in allow or canonical in allow:
             return
         if pname in NONDETERMINISTIC_PRIMS:

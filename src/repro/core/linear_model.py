@@ -72,6 +72,24 @@ def hashed_logits(params: LinearParams, codes: Array) -> Array:
     return gathered.sum(axis=1) + params.b
 
 
+def _bag_sum(rows: Array) -> Array:
+    """(n, k, C) gathered rows -> (n, C), summed over k in one fixed
+    pairwise order (zero-padded to a power of two, then halved).
+
+    A plain ``sum(axis=1)`` leaves the order to the compiler, which picks
+    it per program: the fused serving executable and the offline
+    ``features`` -> ``bag_logits`` composition then differ in the last
+    bit.  Elementwise adds in a fixed tree compile to the same result in
+    any program, on any backend."""
+    k = rows.shape[1]
+    p = 1 << max(k - 1, 0).bit_length()
+    rows = jnp.pad(rows, ((0, 0), (0, p - k), (0, 0)))
+    while rows.shape[1] > 1:
+        h = rows.shape[1] // 2
+        rows = rows[:, :h] + rows[:, h:]
+    return rows[:, 0]
+
+
 def bag_logits(params: LinearParams, idx: Array) -> Array:
     """idx: (n, k) int32 GLOBAL feature indices in [0, F) — exactly what
     repro.pipeline.FeaturePipeline.features emits.  Embedding-bag gather
@@ -91,9 +109,9 @@ def bag_logits(params: LinearParams, idx: Array) -> Array:
     # mode="clip" (a no-op on the already-clamped indices) skips
     # jnp.take's negative-wraparound add of num_features, which cannot
     # even trace once the table reaches 2^31 rows (int32 overflow)
-    return jnp.take(params.w,
-                    idx.astype(jnp.int32).clip(0, num_features - 1),
-                    axis=0, mode="clip").sum(axis=1) + params.b
+    return _bag_sum(jnp.take(params.w,
+                             idx.astype(jnp.int32).clip(0, num_features - 1),
+                             axis=0, mode="clip")) + params.b
 
 
 def check_bag_table_size(num_hashes: int, b: int) -> int:
@@ -160,8 +178,8 @@ def bag_logits_packed(params: LinearParams, packed: Array, *,
     idx = (offs + codes).astype(jnp.int32)
     # mode="clip" as in bag_logits: at the 2^31-row boundary table the
     # default negative-wraparound add would overflow int32 at trace time
-    return jnp.take(params.w, idx.clip(0, num_features - 1),
-                    axis=0, mode="clip").sum(axis=1) + params.b
+    return _bag_sum(jnp.take(params.w, idx.clip(0, num_features - 1),
+                             axis=0, mode="clip")) + params.b
 
 
 def init_bag_packed(key: Array, num_hashes: int, b: int,

@@ -75,8 +75,11 @@ def threefry2x32(k0: Array, k1: Array, x0: Array, x1: Array):
 
 
 def _uniform(bits: Array) -> Array:
-    """Top 24 bits -> fp32 uniform in [0, 1) (exact: 24-bit mantissa)."""
-    return (bits >> np.uint32(8)).astype(jnp.float32) * np.float32(2.0 ** -24)
+    """Top 24 bits -> fp32 uniform in [0, 1) (exact: 24-bit mantissa).
+    The cast goes through int32 (the value is below 2^24, so nothing
+    changes): Mosaic lowers no direct uint32 -> float32 conversion."""
+    top = (bits >> np.uint32(8)).astype(jnp.int32)
+    return top.astype(jnp.float32) * np.float32(2.0 ** -24)
 
 
 def _exp1(u: Array) -> Array:

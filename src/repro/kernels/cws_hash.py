@@ -10,8 +10,10 @@ TPU adaptation (vs the paper's per-vector CPU loop):
     is written to HBM once per (row, hash) tile at the last D step;
   * inside a grid step we loop over the BD dimensions with a fori_loop,
     each iteration doing rank-2 (BN x BK) VPU math (broadcast of the
-    column log u against the parameter row) — no rank-3 temporaries, so
-    VMEM stays at ~6 tiles regardless of BD;
+    column log u against the parameter row) — no rank-3 temporaries.
+    log u is staged once per grid step as a transposed (BD, BN) tile so
+    the loop reads dimension d as a sublane row (Mosaic refuses a
+    dynamic lane slice);
   * the kernel is VPU-bound (log/floor/mul on 8x128 lanes) and
     HBM-traffic-dominated by the 3 parameter matrices (DESIGN.md §2).
 
@@ -32,10 +34,11 @@ PACKED emit variants (``cws_encode_packed_pallas`` /
 ``cws_encode_rng_packed_pallas``) share the same accumulation loop and
 ``_encode_emit`` body but pack the b = b_i + b_t bit codes of each grid
 step's BK hashes into uint32 words in VMEM (b in {1, 2, 4, 8},
-word-aligned per row, shift/or only — no gathers): output traffic drops
-from 4·BN·BK bytes per tile to b/8·BN·BK.  Hash columns past the real k
-are zeroed before packing so pad bits are deterministic zeros, and the
-word layout matches ``core.hashing.pack_codes`` bit-for-bit.
+word-aligned per row, by exact 0/1-selection matmuls — no gathers):
+output traffic drops from 4·BN·BK bytes per tile to b/8·BN·BK.  Hash
+columns past the real k are zeroed before packing so pad bits are
+deterministic zeros, and the word layout matches
+``core.hashing.pack_codes`` bit-for-bit.
 """
 from __future__ import annotations
 
@@ -61,18 +64,28 @@ def _packed_bk(bk: int, k: int, b: int) -> int:
     return -(-bk // cpw) * cpw
 
 
-def _accum_loop(logu, r_ref, logc_ref, beta_ref, d_step, bd, carry):
+def _stage_logu(x_ref, lut_ref):
+    """log u of the (BN, BD) x tile, stored transposed as (BD, BN): the
+    accumulation loop then reads dimension d as a sublane row
+    (``lut_ref[pl.ds(d, 1), :]``).  Mosaic lowers that read; it refuses
+    a dynamic lane slice ``logu[:, d]`` of the untransposed tile."""
+    x = x_ref[...]
+    lut_ref[...] = jnp.transpose(
+        jnp.where(x > 0, jnp.log(jnp.maximum(x, 1e-38)), -jnp.inf))
+
+
+def _accum_loop(lut_ref, r_ref, logc_ref, beta_ref, d_step, bd, carry):
     """Run the BD-dimension argmin update on a (best_a, best_i[, best_t])
     carry; t tracking is skipped when the carry has no t slot."""
     track_t = len(carry) == 3
 
     def body(d, carry):
         a, i = carry[0], carry[1]
-        lu = logu[:, d][:, None]                   # (BN, 1)
-        r = r_ref[d, :][None, :]                   # (1, BK)
-        lc = logc_ref[d, :][None, :]
-        be = beta_ref[d, :][None, :]
-        tt = jnp.floor(lu / r + be)                # (BN, BK)
+        lu = jnp.transpose(lut_ref[pl.ds(d, 1), :])  # (BN, 1)
+        r = r_ref[pl.ds(d, 1), :]                    # (1, BK)
+        lc = logc_ref[pl.ds(d, 1), :]
+        be = beta_ref[pl.ds(d, 1), :]
+        tt = jnp.floor(lu / r + be)                  # (BN, BK)
         la = lc - r * (tt - be + 1.0)
         la = jnp.where(jnp.isfinite(lu), la, jnp.inf)
         upd = la < a
@@ -86,8 +99,12 @@ def _accum_loop(logu, r_ref, logc_ref, beta_ref, d_step, bd, carry):
     return jax.lax.fori_loop(0, bd, body, carry)
 
 
+def _logu_scratch(bn, bd):
+    return pltpu.VMEM((bd, bn), jnp.float32)    # transposed log u tile
+
+
 def _cws_kernel(x_ref, r_ref, logc_ref, beta_ref, istar_ref, tstar_ref,
-                best_a, best_i, best_t, *, bd: int, n_d_steps: int):
+                lut, best_a, best_i, best_t, *, bd: int, n_d_steps: int):
     d_step = pl.program_id(2)
 
     @pl.when(d_step == 0)
@@ -96,10 +113,8 @@ def _cws_kernel(x_ref, r_ref, logc_ref, beta_ref, istar_ref, tstar_ref,
         best_i[...] = jnp.full_like(best_i[...], NEG_SENTINEL)
         best_t[...] = jnp.zeros_like(best_t[...])
 
-    x = x_ref[...]            # (BN, BD)
-    logu = jnp.where(x > 0, jnp.log(jnp.maximum(x, 1e-38)), -jnp.inf)
-
-    a1, i1, t1 = _accum_loop(logu, r_ref, logc_ref, beta_ref, d_step, bd,
+    _stage_logu(x_ref, lut)
+    a1, i1, t1 = _accum_loop(lut, r_ref, logc_ref, beta_ref, d_step, bd,
                              (best_a[...], best_i[...], best_t[...]))
     best_a[...] = a1
     best_i[...] = i1
@@ -111,7 +126,8 @@ def _cws_kernel(x_ref, r_ref, logc_ref, beta_ref, istar_ref, tstar_ref,
         tstar_ref[...] = jnp.clip(best_t[...], -2 ** 30, 2 ** 30).astype(jnp.int32)
 
 
-def _cws_encode_kernel(x_ref, r_ref, logc_ref, beta_ref, idx_ref, *scratch,
+def _cws_encode_kernel(x_ref, r_ref, logc_ref, beta_ref, idx_ref, lut,
+                       *scratch,
                        bd: int, n_d_steps: int, b_i: int, b_t: int, bk: int,
                        packed: bool = False, num_hashes: int = 0):
     """Fused CWS -> b-bit code -> embedding-bag index.  ``scratch`` is
@@ -130,11 +146,9 @@ def _cws_encode_kernel(x_ref, r_ref, logc_ref, beta_ref, idx_ref, *scratch,
         if b_t:
             best_t[...] = jnp.zeros_like(best_t[...])
 
-    x = x_ref[...]
-    logu = jnp.where(x > 0, jnp.log(jnp.maximum(x, 1e-38)), -jnp.inf)
-
+    _stage_logu(x_ref, lut)
     carry = (best_a[...], best_i[...]) + ((best_t[...],) if b_t else ())
-    out = _accum_loop(logu, r_ref, logc_ref, beta_ref, d_step, bd, carry)
+    out = _accum_loop(lut, r_ref, logc_ref, beta_ref, d_step, bd, carry)
     best_a[...] = out[0]
     best_i[...] = out[1]
     if b_t:
@@ -142,23 +156,39 @@ def _cws_encode_kernel(x_ref, r_ref, logc_ref, beta_ref, idx_ref, *scratch,
 
     @pl.when(d_step == n_d_steps - 1)
     def _emit():
-        idx_ref[...] = _encode_emit(best_i[...],
-                                    best_t[...] if b_t else None,
-                                    hash_block, bk, b_i, b_t,
-                                    packed=packed, num_hashes=num_hashes)
+        emitted = _encode_emit(best_i[...], best_t[...] if b_t else None,
+                               hash_block, bk, b_i, b_t,
+                               packed=packed, num_hashes=num_hashes)
+        idx_ref[...] = emitted.reshape(idx_ref.shape)
 
 
 def _pack_words(code, b):
-    """(BN, BK) b-bit codes -> (BN, BK*b/32) uint32 words via shift/or
-    over the 32/b strided lane phases (no gathers; lane j of word w is
-    code column w*(32/b)+j at bit offset j*b — the core.hashing.pack_codes
-    layout)."""
+    """(BN, BK) b-bit codes -> (BN, BK*b/32) uint32 words in the
+    core.hashing.pack_codes layout: code column w*(32/b)+m lands in word
+    w at bit offset m*b.
+
+    Mosaic lowers no strided lane slice, so the columns are gathered by
+    two 0/1-selection matmuls, one per 16-bit half of the word: the
+    selection matrix holds 2^(m*b - half) where column j belongs to
+    word w.  Every operand is an integer below 2^16 and each output is a
+    sum of disjoint bit fields below 2^16, so the f32 products and sums
+    are exact at any MXU precision (bf16 passes included)."""
     cpw = 32 // b
-    c = code.astype(jnp.uint32)
-    packed = jnp.zeros((code.shape[0], code.shape[1] // cpw), jnp.uint32)
-    for j in range(cpw):
-        packed = packed | (c[:, j::cpw] << jnp.uint32(j * b))
-    return packed
+    bk = code.shape[1]
+    bw = bk // cpw
+    col = jax.lax.broadcasted_iota(jnp.int32, (bk, bw), 0)
+    word = jax.lax.broadcasted_iota(jnp.int32, (bk, bw), 1)
+    c = code.astype(jnp.float32)                   # < 2^b <= 2^8: exact
+    halves = []
+    for half in (0, 16):
+        sel = jnp.zeros((bk, bw), jnp.float32)
+        for m in range(cpw):
+            if half <= m * b < half + 16:
+                sel = jnp.where(col == word * cpw + m,
+                                float(1 << (m * b - half)), sel)
+        part = jnp.dot(c, sel, preferred_element_type=jnp.float32)
+        halves.append(part.astype(jnp.int32).astype(jnp.uint32))
+    return halves[0] | (halves[1] << jnp.uint32(16))
 
 
 def _encode_emit(i, best_t, hash_block, bk, b_i, b_t, *, packed=False,
@@ -231,6 +261,7 @@ def cws_hash_pallas(x: jax.Array, r: jax.Array, log_c: jax.Array,
         out_shape=[jax.ShapeDtypeStruct((np_, kp_), jnp.int32),
                    jax.ShapeDtypeStruct((np_, kp_), jnp.int32)],
         scratch_shapes=[
+            _logu_scratch(bn, bd),
             pltpu.VMEM((bn, bk), jnp.float32),   # best log_a
             pltpu.VMEM((bn, bk), jnp.int32),     # best index
             pltpu.VMEM((bn, bk), jnp.float32),   # best t (cast on emit)
@@ -260,7 +291,8 @@ def cws_encode_pallas(x: jax.Array, r: jax.Array, log_c: jax.Array,
     np_, dp_, kp_ = xp.shape[0], xp.shape[1], rp.shape[1]
     n_d_steps = dp_ // bd
 
-    scratch = [pltpu.VMEM((bn, bk), jnp.float32),    # best log_a
+    scratch = [_logu_scratch(bn, bd),
+               pltpu.VMEM((bn, bk), jnp.float32),    # best log_a
                pltpu.VMEM((bn, bk), jnp.int32)]      # best index
     if b_t:
         scratch.append(pltpu.VMEM((bn, bk), jnp.float32))   # best t
@@ -306,7 +338,7 @@ def _regen_step(key_ref, d_step, bd, bk, r_s, c_s, b_s):
 
 
 def _cws_hash_rng_kernel(x_ref, key_ref, istar_ref, tstar_ref,
-                         r_s, c_s, b_s, best_a, best_i, best_t,
+                         lut, r_s, c_s, b_s, best_a, best_i, best_t,
                          *, bd: int, n_d_steps: int, bk: int):
     d_step = pl.program_id(2)
 
@@ -317,10 +349,8 @@ def _cws_hash_rng_kernel(x_ref, key_ref, istar_ref, tstar_ref,
         best_t[...] = jnp.zeros_like(best_t[...])
 
     _regen_step(key_ref, d_step, bd, bk, r_s, c_s, b_s)
-    x = x_ref[...]
-    logu = jnp.where(x > 0, jnp.log(jnp.maximum(x, 1e-38)), -jnp.inf)
-
-    a1, i1, t1 = _accum_loop(logu, r_s, c_s, b_s, d_step, bd,
+    _stage_logu(x_ref, lut)
+    a1, i1, t1 = _accum_loop(lut, r_s, c_s, b_s, d_step, bd,
                              (best_a[...], best_i[...], best_t[...]))
     best_a[...] = a1
     best_i[...] = i1
@@ -332,7 +362,8 @@ def _cws_hash_rng_kernel(x_ref, key_ref, istar_ref, tstar_ref,
         tstar_ref[...] = jnp.clip(best_t[...], -2 ** 30, 2 ** 30).astype(jnp.int32)
 
 
-def _cws_encode_rng_kernel(x_ref, key_ref, idx_ref, r_s, c_s, b_s, *scratch,
+def _cws_encode_rng_kernel(x_ref, key_ref, idx_ref, lut, r_s, c_s, b_s,
+                           *scratch,
                            bd: int, n_d_steps: int, b_i: int, b_t: int,
                            bk: int, packed: bool = False,
                            num_hashes: int = 0):
@@ -349,11 +380,9 @@ def _cws_encode_rng_kernel(x_ref, key_ref, idx_ref, r_s, c_s, b_s, *scratch,
             best_t[...] = jnp.zeros_like(best_t[...])
 
     _regen_step(key_ref, d_step, bd, bk, r_s, c_s, b_s)
-    x = x_ref[...]
-    logu = jnp.where(x > 0, jnp.log(jnp.maximum(x, 1e-38)), -jnp.inf)
-
+    _stage_logu(x_ref, lut)
     carry = (best_a[...], best_i[...]) + ((best_t[...],) if b_t else ())
-    out = _accum_loop(logu, r_s, c_s, b_s, d_step, bd, carry)
+    out = _accum_loop(lut, r_s, c_s, b_s, d_step, bd, carry)
     best_a[...] = out[0]
     best_i[...] = out[1]
     if b_t:
@@ -361,10 +390,10 @@ def _cws_encode_rng_kernel(x_ref, key_ref, idx_ref, r_s, c_s, b_s, *scratch,
 
     @pl.when(d_step == n_d_steps - 1)
     def _emit():
-        idx_ref[...] = _encode_emit(best_i[...],
-                                    best_t[...] if b_t else None,
-                                    hash_block, bk, b_i, b_t,
-                                    packed=packed, num_hashes=num_hashes)
+        emitted = _encode_emit(best_i[...], best_t[...] if b_t else None,
+                               hash_block, bk, b_i, b_t,
+                               packed=packed, num_hashes=num_hashes)
+        idx_ref[...] = emitted.reshape(idx_ref.shape)
 
 
 def _rng_setup(x, num_hashes, bn, bk, bd):
@@ -415,7 +444,7 @@ def cws_hash_rng_pallas(x: jax.Array, key: jax.Array, num_hashes: int, *,
         out_specs=[out_spec, out_spec],
         out_shape=[jax.ShapeDtypeStruct((np_, kp_), jnp.int32),
                    jax.ShapeDtypeStruct((np_, kp_), jnp.int32)],
-        scratch_shapes=_param_scratch(bd, bk) + [
+        scratch_shapes=[_logu_scratch(bn, bd)] + _param_scratch(bd, bk) + [
             pltpu.VMEM((bn, bk), jnp.float32),   # best log_a
             pltpu.VMEM((bn, bk), jnp.int32),     # best index
             pltpu.VMEM((bn, bk), jnp.float32),   # best t (cast on emit)
@@ -448,7 +477,7 @@ def cws_encode_rng_pallas(x: jax.Array, key: jax.Array, num_hashes: int, *,
     np_, dp_ = xp.shape
     n_d_steps = dp_ // bd
 
-    scratch = _param_scratch(bd, bk) + [
+    scratch = [_logu_scratch(bn, bd)] + _param_scratch(bd, bk) + [
         pltpu.VMEM((bn, bk), jnp.float32),       # best log_a
         pltpu.VMEM((bn, bk), jnp.int32)]         # best index
     if b_t:
@@ -480,6 +509,26 @@ def cws_encode_rng_pallas(x: jax.Array, key: jax.Array, num_hashes: int, *,
 # columns (>= num_hashes) zero their bits (they share words with real
 # codes at ragged k·b).  The row dimension needs no care: rows pack
 # independently (word-aligned), pad rows slice off as usual.
+#
+# The output is 3-D, (hash blocks, rows, BK·b/32), with block
+# (1, BN, BK·b/32): Mosaic needs a block's last dim to be a multiple of
+# 128 or the whole array dim, and BK·b/32 words is neither in 2-D.  The
+# wrapper puts each row's hash blocks back side by side.
+
+
+def _packed_out(np_, kp_, bn, bk, b):
+    """(out_spec, out_shape) of the packed emit: one (1, BN, BK·b/32)
+    block per (row block, hash block)."""
+    bw = bk * b // 32                       # packed words per hash block
+    spec = pl.BlockSpec((1, bn, bw), lambda i, j, s: (j, i, 0))
+    return spec, jax.ShapeDtypeStruct((kp_ // bk, np_, bw), jnp.uint32)
+
+
+def _row_words(blocks, n, k, b):
+    """(hash blocks, rows, words) -> (n, ceil(k·b/32)) row-major words."""
+    hb, np_, bw = blocks.shape
+    words = jnp.transpose(blocks, (1, 0, 2)).reshape(np_, hb * bw)
+    return words[:n, :packed_width(k, b)]
 
 
 @functools.partial(jax.jit,
@@ -504,15 +553,15 @@ def cws_encode_packed_pallas(x: jax.Array, r: jax.Array, log_c: jax.Array,
     xp, rp, lcp, bep = _pad_operands(x, r, log_c, beta, bn, bk, bd)
     np_, dp_, kp_ = xp.shape[0], xp.shape[1], rp.shape[1]
     n_d_steps = dp_ // bd
-    bw = bk * b // 32                       # packed words per hash block
 
-    scratch = [pltpu.VMEM((bn, bk), jnp.float32),    # best log_a
+    scratch = [_logu_scratch(bn, bd),
+               pltpu.VMEM((bn, bk), jnp.float32),    # best log_a
                pltpu.VMEM((bn, bk), jnp.int32)]      # best index
     if b_t:
         scratch.append(pltpu.VMEM((bn, bk), jnp.float32))   # best t
 
     in_specs, _ = _cws_specs(bn, bk, bd)
-    out_spec = pl.BlockSpec((bn, bw), lambda i, j, s: (i, j))
+    out_spec, out_shape = _packed_out(np_, kp_, bn, bk, b)
     kernel = functools.partial(_cws_encode_kernel, bd=bd,
                                n_d_steps=n_d_steps, b_i=b_i, b_t=b_t,
                                bk=bk, packed=True, num_hashes=k)
@@ -521,11 +570,11 @@ def cws_encode_packed_pallas(x: jax.Array, r: jax.Array, log_c: jax.Array,
         grid=(np_ // bn, kp_ // bk, n_d_steps),
         in_specs=in_specs,
         out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((np_, kp_ * b // 32), jnp.uint32),
+        out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
     )(xp, rp, lcp, bep)
-    return words[:n, :packed_width(k, b)]
+    return _row_words(words, n, k, b)
 
 
 @functools.partial(jax.jit,
@@ -547,15 +596,14 @@ def cws_encode_rng_packed_pallas(x: jax.Array, key: jax.Array,
         x, num_hashes + ((-num_hashes) % bk), bn, bk, bd)
     np_, dp_ = xp.shape
     n_d_steps = dp_ // bd
-    bw = bk * b // 32
 
-    scratch = _param_scratch(bd, bk) + [
+    scratch = [_logu_scratch(bn, bd)] + _param_scratch(bd, bk) + [
         pltpu.VMEM((bn, bk), jnp.float32),       # best log_a
         pltpu.VMEM((bn, bk), jnp.int32)]         # best index
     if b_t:
         scratch.append(pltpu.VMEM((bn, bk), jnp.float32))    # best t
 
-    out_spec = pl.BlockSpec((bn, bw), lambda i, j, s: (i, j))
+    out_spec, out_shape = _packed_out(np_, kp_, bn, bk, b)
     kernel = functools.partial(_cws_encode_rng_kernel, bd=bd,
                                n_d_steps=n_d_steps, b_i=b_i, b_t=b_t,
                                bk=bk, packed=True, num_hashes=num_hashes)
@@ -564,11 +612,11 @@ def cws_encode_rng_packed_pallas(x: jax.Array, key: jax.Array,
         grid=(np_ // bn, kp_ // bk, n_d_steps),
         in_specs=in_specs,
         out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((np_, kp_ * b // 32), jnp.uint32),
+        out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
     )(xp, kw)
-    return words[:n, :packed_width(num_hashes, b)]
+    return _row_words(words, n, num_hashes, b)
 
 
 # ---------------------------------------------------------------------------
@@ -576,9 +624,9 @@ def cws_encode_rng_packed_pallas(x: jax.Array, key: jax.Array,
 # ---------------------------------------------------------------------------
 # Interval proofs over the emit arithmetic the kernels share: the b-bit
 # code build (mask / clip / sentinel fold), the per-hash offset, and the
-# shift/or word packing — seeded with the hostile ranges the accumulator
-# actually produces (best_i carries the -1 sentinel, best_t is an
-# unbounded float before its clip).
+# selection-matmul word packing — seeded with the hostile ranges the
+# accumulator actually produces (best_i carries the -1 sentinel, best_t
+# is an unbounded float before its clip).
 
 from repro.kernels import registry as _registry  # noqa: E402
 

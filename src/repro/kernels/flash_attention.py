@@ -351,7 +351,6 @@ def sharded_flash_attention(q, k, v, window: int, block: int,
 
 def _sfa_fwd_impl(q, k, v, window, block, interpret, mesh, seq_axes,
                   batch_axes):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     local = q.shape[1] // axes_size(mesh, seq_axes)
@@ -366,13 +365,13 @@ def _sfa_fwd_impl(q, k, v, window, block, interpret, mesh, seq_axes,
             qs, ks, vs, window=window, blk_q=block, blk_k=block,
             interpret=interpret, q_base=base * local)
 
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec, sspec, None, None),
                   P(bspec, None, None, None),
                   P(bspec, None, None, None)),
         out_specs=P(bspec, sspec, None, None),
-        check_rep=False,
+        check_vma=False,
     )
     return f(q, k, v)
 
@@ -433,7 +432,6 @@ def _ring_fwd_impl(q, k, v, window, block, interpret, mesh, seq_axes,
     overlaps the collective with the flash inner loop (double-buffered:
     at most two K/V shards resident).  Returns (out, lse); lse feeds the
     reverse-ring backward."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = axes_size(mesh, seq_axes)
@@ -475,13 +473,13 @@ def _ring_fwd_impl(q, k, v, window, block, interpret, mesh, seq_axes,
         lse = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), 0.0)
         return out, lse[:, :sql, :, 0]
 
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec, sspec, None, None),
                   P(bspec, sspec, None, None),
                   P(bspec, sspec, None, None)),
         out_specs=(P(bspec, sspec, None, None), P(bspec, sspec, None)),
-        check_rep=False,
+        check_vma=False,
     )
     return f(q, k, v)
 
@@ -493,7 +491,6 @@ def _ring_bwd_impl(q, k, v, out, lse, g_out, window, block, interpret,
     so after n hops the accumulated dk/dv land back on their home shard.
     Per step each device recomputes its q-block x resident-k-block probs
     from lse and adds its contribution to the traveling dk/dv."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = axes_size(mesh, seq_axes)
@@ -550,13 +547,13 @@ def _ring_bwd_impl(q, k, v, out, lse, g_out, window, block, interpret,
         return (dq.reshape(b, sql, h, d).astype(qs.dtype),
                 dk.astype(ks.dtype), dv.astype(vs.dtype))
 
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec, sspec, None, None),) * 3
         + (P(bspec, sspec, None, None), P(bspec, sspec, None),
            P(bspec, sspec, None, None)),
         out_specs=(P(bspec, sspec, None, None),) * 3,
-        check_rep=False,
+        check_vma=False,
     )
     return f(q, k, v, out, lse, g_out)
 

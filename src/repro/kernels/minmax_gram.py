@@ -21,18 +21,23 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import registry
 
 
-def _minsum_kernel(x_ref, y_ref, out_ref, acc, *, bd: int, n_d_steps: int):
+def _minsum_kernel(x_ref, y_ref, out_ref, xt, yt, acc, *, bd: int,
+                   n_d_steps: int):
     d_step = pl.program_id(2)
 
     @pl.when(d_step == 0)
     def _init():
         acc[...] = jnp.zeros_like(acc[...])
 
-    x = x_ref[...]   # (BM, BD)
-    y = y_ref[...]   # (BN, BD)
+    # Both tiles are stored transposed, (BD, BM) and (BD, BN), so that
+    # dimension d is a sublane row: Mosaic lowers a dynamic sublane read
+    # and refuses the dynamic lane slice x[:, d].
+    xt[...] = jnp.transpose(x_ref[...])
+    yt[...] = jnp.transpose(y_ref[...])
 
     def body(d, a):
-        return a + jnp.minimum(x[:, d][:, None], y[:, d][None, :])
+        xc = jnp.transpose(xt[pl.ds(d, 1), :])     # (BM, 1)
+        return a + jnp.minimum(xc, yt[pl.ds(d, 1), :])
 
     acc[...] = jax.lax.fori_loop(0, bd, body, acc[...])
 
@@ -84,7 +89,9 @@ def _min_sum_pallas(x: jax.Array, y: jax.Array, *, bm: int, bn: int,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bd, bm), jnp.float32),
+                        pltpu.VMEM((bd, bn), jnp.float32),
+                        pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(xp, yp)
     return out[:m, :n]

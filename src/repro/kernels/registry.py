@@ -174,22 +174,27 @@ _VMEM_BUDGET = 8 * 2 ** 20   # conservative half of ~16MB/core
 # follows choose_blocks: b1 tiles the first problem axis (rows), b2 the
 # third (hashes/cols), bd the contraction/dims axis.
 _VMEM_MODELS: Dict[str, Callable[[int, int, int], int]] = {
-    # x tile + 3 param tiles + 3 accumulators + 2 output tiles
-    "cws": lambda bn, bk, bd: 4 * (bn * bd + 3 * bd * bk + 5 * bn * bk),
-    # x tile + 3 regenerated param tiles (scratch, single-buffered — no
-    # pipelined second copy) + 3 accumulators + 2 output tiles
-    "cws_rng": lambda bn, bk, bd: 4 * (bn * bd + 3 * bd * bk + 5 * bn * bk),
+    # x tile + its transposed log u scratch + 3 param tiles
+    # + 3 accumulators + 2 output tiles
+    "cws": lambda bn, bk, bd: 4 * (2 * bn * bd + 3 * bd * bk + 5 * bn * bk),
+    # x tile + transposed log u + 3 regenerated param tiles (scratch,
+    # single-buffered — no pipelined second copy) + 3 accumulators
+    # + 2 output tiles
+    "cws_rng": lambda bn, bk, bd: 4 * (2 * bn * bd + 3 * bd * bk
+                                       + 5 * bn * bk),
     # packed-emit twins: 3 fp32 accumulators (best_a/best_i/best_t) plus
     # the packed uint32 output tile of bn*bk*b/32 words — modeled at the
     # widest packed b (8 -> bk/4 words -> bn*bk bytes), so every legal b
     # fits whatever these admit.  Audited against the BlockSpec/scratch
     # footprint the kernels actually declare by repro.analysis.vmem.
-    "cws_packed": lambda bn, bk, bd: 4 * (bn * bd + 3 * bd * bk
+    "cws_packed": lambda bn, bk, bd: 4 * (2 * bn * bd + 3 * bd * bk
                                           + 3 * bn * bk) + bn * bk,
-    "cws_rng_packed": lambda bn, bk, bd: 4 * (bn * bd + 3 * bd * bk
+    "cws_rng_packed": lambda bn, bk, bd: 4 * (2 * bn * bd + 3 * bd * bk
                                               + 3 * bn * bk) + bn * bk,
-    # x tile + y tile + accumulator + output tile
-    "min_sum": lambda bm, bn, bd: 4 * (bm * bd + bn * bd + 2 * bm * bn),
+    # x tile + y tile, each with its transposed copy, + accumulator
+    # + output tile
+    "min_sum": lambda bm, bn, bd: 4 * (2 * bm * bd + 2 * bn * bd
+                                       + 2 * bm * bn),
 }
 _FAMILY_ALIASES = {"gram": "min_sum", "cws_hash": "cws", "cws_encode": "cws",
                    "cws_hash_rng": "cws_rng", "cws_encode_rng": "cws_rng",

@@ -3,23 +3,35 @@
 A FUNCTION, not a module-level constant: importing this module never
 touches jax device state (required so smoke tests see 1 CPU device while
 the dry-run sees 512 forced host devices).
+
+Every mesh here has ``Auto`` axes: shardings are propagated by the
+compiler, as the data-parallel paths (shard_map launches, eager gathers
+on sharded batches) are written for.  ``jax.make_mesh`` otherwise
+defaults to ``Explicit`` axes, under which an eager ``jnp.take`` on a
+row-sharded index batch is refused.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes, **kw):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         **kw)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_local_mesh():
     """Whatever devices exist, as a (data=N, model=1) mesh — used by the
     CPU examples and the single-host training driver."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return _mesh((n, 1), ("data", "model"))
 
 
 def make_data_mesh(ndev: int | None = None):
@@ -30,8 +42,7 @@ def make_data_mesh(ndev: int | None = None):
     if n > len(jax.devices()):
         raise ValueError(f"asked for {n} devices but only "
                          f"{len(jax.devices())} exist")
-    return jax.make_mesh((n, 1), ("data", "model"),
-                         devices=jax.devices()[:n])
+    return _mesh((n, 1), ("data", "model"), devices=jax.devices()[:n])
 
 
 def data_axis_size(mesh) -> int:

@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import setup_compile_cache
+
 
 def serve_bundle(args) -> None:
     """The featurize→score service: load bundle, warm buckets, fire
@@ -160,6 +162,7 @@ def main():
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    setup_compile_cache()
 
     if args.bundle is not None:
         serve_bundle(args)

@@ -469,13 +469,12 @@ class FeaturePipeline:
         key = (mesh, bool(donate))
         fn = self._sharded_fns.get(key)
         if fn is None:
-            from jax.experimental.shard_map import shard_map
-            body = shard_map(
+            body = jax.shard_map(
                 lambda xs, ps: self._launch_with(xs, ps),
                 mesh=mesh,
                 in_specs=(self._rows_pspec(), self.state_pspec()),
                 out_specs=self._rows_pspec(),
-                check_rep=False,
+                check_vma=False,
             )
             donate_argnums = registry.donate_argnums(0) if donate else ()
             fn = jax.jit(body, donate_argnums=donate_argnums)

@@ -147,12 +147,11 @@ def _make_sharded_update_step(cfg: TrainCfg, tx, n_micro: int,
             n_micro=n_micro, axis_name="data")
         return loss, grads
 
-    from jax.experimental.shard_map import shard_map
-    grads_fn = shard_map(
+    grads_fn = jax.shard_map(
         local_grads, mesh=mesh,
         in_specs=(P(), pipe.state_pspec(), P("data", None), P("data")),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
 
     @functools.partial(jax.jit, donate_argnums=donate)

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.experimental import pallas as pl
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.analysis import (audit_collectives, audit_completeness,
@@ -209,7 +209,7 @@ class TestCollectiveFixtures:
     def test_unbound_axis_name_fires(self):
         f = shard_map(lambda x: jax.lax.psum(x, "model"), mesh=_mesh1(),
                       in_specs=P("data"), out_specs=P("data"),
-                      check_rep=False)
+                      check_vma=False)
         findings = audit_collectives(f, (jnp.ones((4,)),), name="fx")
         assert any("unbound axis name" in f.message
                    for f in findings), _messages(findings)
@@ -218,7 +218,7 @@ class TestCollectiveFixtures:
         f = shard_map(
             lambda x: jax.lax.ppermute(x, "data", [(0, 0), (1, 0)]),
             mesh=_mesh1(), in_specs=P("data"), out_specs=P("data"),
-            check_rep=False)
+            check_vma=False)
         findings = audit_collectives(f, (jnp.ones((4,)),), name="fx")
         assert any("not a true permutation" in f.message
                    for f in findings), _messages(findings)
@@ -238,14 +238,14 @@ class TestCollectiveFixtures:
         f = shard_map(
             lambda x: jax.lax.psum(jax.lax.psum(x, "data"), "data"),
             mesh=_mesh1(), in_specs=P("data"), out_specs=P(),
-            check_rep=False)
+            check_vma=False)
         findings = audit_collectives(f, (jnp.ones((4,)),), name="fx")
         assert any("reduced twice" in f.message
                    for f in findings), _messages(findings)
 
     def test_blessed_point_count_fires(self):
         f = shard_map(lambda x: jax.lax.psum(x, "data"), mesh=_mesh1(),
-                      in_specs=P("data"), out_specs=P(), check_rep=False)
+                      in_specs=P("data"), out_specs=P(), check_vma=False)
         findings = audit_collectives(f, (jnp.ones((4,)),), name="fx",
                                      expected_psums=3)
         assert any("expected exactly 3 psum(s)" in f.message
@@ -579,6 +579,6 @@ class TestSuiteGreen:
         launch = rec["launch"]
         assert len(launch.grid) == 3
         assert len(launch.outputs) == 2          # i*, t*
-        assert len(launch.scratch) == 6          # 3 param + 3 accum tiles
+        assert len(launch.scratch) == 7          # log u + 3 param + 3 accum
         smem = [o for o in launch.inputs if o.memory_space == "smem"]
         assert len(smem) == 1                    # the regen key words
