@@ -47,6 +47,11 @@ from repro.launch.mesh import data_axis_size
 
 Array = jax.Array
 
+# Host span around one chunk's launch in the profiler's trace
+# (``jax.profiler``), with the chunk's ``rows``: inert unless a profile is
+# being taken, and then on the same clock as the device ops.
+LAUNCH_SPAN = "repro.featurize.launch"
+
 
 @dataclasses.dataclass(frozen=True)
 class FeatureSpec:
@@ -284,33 +289,37 @@ class FeaturePipeline:
         on_device = isinstance(x, jax.Array)
         for lo in range(0, n, rows):
             hi = min(lo + rows, n)
-            # host-resident rows (numpy/memmap) slice on the host, so only
-            # the chunk ever crosses to the device
-            chunk = (jax.lax.slice_in_dim(x, lo, hi, axis=0) if on_device
-                     else jnp.asarray(x[lo:hi]))
             m = hi - lo
-            # streamed ragged tails pad to the full chunk shape (the
-            # single-compile invariant); a lone short chunk (n <= rows)
-            # pads only to the data-axis multiple it must split into
-            target = rows if (m < rows and n > rows) else m + ((-m) % ndev)
-            if target > m:
-                chunk = jnp.pad(chunk, ((0, target - m), (0, 0)))
-                yield lo, hi, fn(chunk)[:m]
-            elif mesh is not None and launch is None and n <= rows:
-                # lone whole-array chunk: the full-range slice may alias
-                # the caller's live x on some backends — same policy as
-                # _features_sharded, never donate it
-                yield lo, hi, self._sharded_chunk_fn(
-                    mesh, donate=False)(chunk, self._state())
-            else:
-                yield lo, hi, fn(chunk)
+            with jax.profiler.TraceAnnotation(LAUNCH_SPAN, rows=m):
+                # host-resident rows (numpy/memmap) slice on the host, so
+                # only the chunk ever crosses to the device
+                chunk = (jax.lax.slice_in_dim(x, lo, hi, axis=0)
+                         if on_device else jnp.asarray(x[lo:hi]))
+                # streamed ragged tails pad to the full chunk shape (the
+                # single-compile invariant); a lone short chunk (n <= rows)
+                # pads only to the data-axis multiple it must split into
+                target = (rows if (m < rows and n > rows)
+                          else m + ((-m) % ndev))
+                if target > m:
+                    chunk = jnp.pad(chunk, ((0, target - m), (0, 0)))
+                    out = fn(chunk)[:m]
+                elif mesh is not None and launch is None and n <= rows:
+                    # lone whole-array chunk: the full-range slice may
+                    # alias the caller's live x on some backends — same
+                    # policy as _features_sharded, never donate it
+                    out = self._sharded_chunk_fn(
+                        mesh, donate=False)(chunk, self._state())
+                else:
+                    out = fn(chunk)
+            yield lo, hi, out
 
     def features(self, x: Array, *, mesh=None) -> Array:
         """x (n, D) nonneg -> embedding-bag indices (n, k) int32 into
         ``num_features`` — or, with ``spec.packed``, bit-packed codes
         (n, ``spec.packed_words``) uint32.  Streams in
         ``chunk_rows(mesh)`` row chunks; with a ``mesh`` every launch is
-        shard_mapped over its ``data`` axis."""
+        shard_mapped over its ``data`` axis.  Each launch's host work
+        (slice, pad, enqueue) is one ``LAUNCH_SPAN`` in a profile."""
         self._require_bucketed("features")
         n = x.shape[0]
         if n == 0:   # empty stream chunk: nothing to launch
@@ -318,8 +327,9 @@ class FeaturePipeline:
                 return jnp.zeros((0, self.spec.packed_words), jnp.uint32)
             return jnp.zeros((0, self.spec.num_hashes), jnp.int32)
         if n <= self.chunk_rows(mesh):
-            return self._launch(x) if mesh is None else \
-                self._features_sharded(x, mesh)
+            with jax.profiler.TraceAnnotation(LAUNCH_SPAN, rows=n):
+                return self._launch(x) if mesh is None else \
+                    self._features_sharded(x, mesh)
         return self._features_streamed(x, mesh=mesh)
 
     def hashes(self, x: Array):

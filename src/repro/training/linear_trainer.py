@@ -79,6 +79,12 @@ from repro.training.trainer import microbatch_grads
 
 Array = jax.Array
 
+# Host spans in the profiler's trace (``jax.profiler``): inert unless a
+# profile is being taken, and then on the same clock as the device ops.
+FIT_SPAN = "repro.fit"              # a whole fit or resume call
+SETUP_SPAN = "repro.fit.setup"      # everything before the first step
+STEP_SPAN = "repro.fit.step"        # one step on the host, its save excluded
+
 __all__ = ["fit_linear_streamed", "resume_linear_streamed",
            "fit_linear_streamed_resilient", "streamed_accuracy",
            "resume_streamed_accuracy", "export_served_model"]
@@ -356,58 +362,59 @@ def _stream_loop(S: _StreamSetup, params: LinearParams, state, start: int,
     try:
         for i in range(start, cfg.steps):
             epoch, pos = divmod(i, S.steps_per_epoch)
-            if watchdog is not None:
-                watchdog.start_step(i)
-            try:
-                if chaos is not None:
-                    chaos.fire("step", i)
-                if S.shuffle:
-                    if epoch != cur_epoch:
-                        perm = jax.random.permutation(
-                            jax.random.fold_in(S.key, epoch), S.n)
+            with jax.profiler.StepTraceAnnotation(STEP_SPAN, step_num=i):
+                if watchdog is not None:
+                    watchdog.start_step(i)
+                try:
+                    if chaos is not None:
+                        chaos.fire("step", i)
+                    if S.shuffle:
+                        if epoch != cur_epoch:
+                            perm = jax.random.permutation(
+                                jax.random.fold_in(S.key, epoch), S.n)
+                            if S.host_data:
+                                perm_host = np.asarray(perm)
+                            cur_epoch = epoch
                         if S.host_data:
-                            perm_host = np.asarray(perm)
-                        cur_epoch = epoch
-                    if S.host_data:
-                        sel = perm_host[pos * S.bs:(pos + 1) * S.bs]
-                        xb, yb = S.x[sel], S.labels_host[sel]
-                        if mesh is None:
-                            xb, yb = jnp.asarray(xb), jnp.asarray(yb)
+                            sel = perm_host[pos * S.bs:(pos + 1) * S.bs]
+                            xb, yb = S.x[sel], S.labels_host[sel]
+                            if mesh is None:
+                                xb, yb = jnp.asarray(xb), jnp.asarray(yb)
+                            else:
+                                # one host->device hop into the data layout
+                                xb = jax.device_put(xb, S.batch_shardings[0])
+                                yb = jax.device_put(yb, S.batch_shardings[1])
                         else:
-                            # one host->device hop into the data layout
-                            xb = jax.device_put(xb, S.batch_shardings[0])
-                            yb = jax.device_put(yb, S.batch_shardings[1])
+                            xb, yb = S.gather(S.x, S.labels, perm,
+                                              jnp.int32(pos))
+                        if mesh is None:
+                            # the gather buffer is ours alone -> safe to
+                            # donate to the featurization launch
+                            fb = pipe.launch_chunk(xb)
+                            params, state, _ = S.update(params, state, fb, yb,
+                                                        jnp.int32(i))
+                        else:
+                            # sharded: featurize runs INSIDE the shard_map
+                            params, state = S.update(params, state, S.pstate,
+                                                     xb, yb, jnp.int32(i))
+                    elif mesh is None:
+                        params, state, _ = S.update(params, state, S.fb_full,
+                                                    S.yb_full, jnp.int32(i))
                     else:
-                        xb, yb = S.gather(S.x, S.labels, perm,
-                                          jnp.int32(pos))
-                    if mesh is None:
-                        # the gather buffer is ours alone -> safe to
-                        # donate to the featurization launch
-                        fb = pipe.launch_chunk(xb)
-                        params, state, _ = S.update(params, state, fb, yb,
-                                                    jnp.int32(i))
-                    else:
-                        # sharded: featurize runs INSIDE the shard_map
                         params, state = S.update(params, state, S.pstate,
-                                                 xb, yb, jnp.int32(i))
-                elif mesh is None:
-                    params, state, _ = S.update(params, state, S.fb_full,
-                                                S.yb_full, jnp.int32(i))
-                else:
-                    params, state = S.update(params, state, S.pstate,
-                                             S.fb_full, S.yb_full,
-                                             jnp.int32(i))
+                                                 S.fb_full, S.yb_full,
+                                                 jnp.int32(i))
+                    if watchdog is not None:
+                        jax.block_until_ready(params)
+                except KeyboardInterrupt as e:
+                    # the watchdog monitor interrupts a hung step with
+                    # SIGINT; convert to the abort signal (a real Ctrl-C,
+                    # with no fired timeout, re-raises untouched)
+                    if watchdog is not None:
+                        watchdog.reraise_if_fired(e)
+                    raise
                 if watchdog is not None:
-                    jax.block_until_ready(params)
-            except KeyboardInterrupt as e:
-                # the watchdog monitor interrupts a hung step with
-                # SIGINT; convert to the abort signal (a real Ctrl-C,
-                # with no fired timeout, re-raises untouched)
-                if watchdog is not None:
-                    watchdog.reraise_if_fired(e)
-                raise
-            if watchdog is not None:
-                watchdog.end_step()
+                    watchdog.end_step()
             done = i + 1
             if (ckpt is not None and ckpt_every > 0
                     and (done % ckpt_every == 0 or done == cfg.steps)):
@@ -457,20 +464,23 @@ def fit_linear_streamed(params: LinearParams, pipe: FeaturePipeline,
     hung steps mid-flight); ``chaos=`` threads a deterministic fault
     plan through the step path (tests).  ``return_state=True`` returns
     ``(params, opt_state)`` instead of params alone."""
-    validate_bag_features(params, pipe.num_features, spec=pipe.spec)
-    S = _StreamSetup(pipe, x, labels, cfg, shuffle_key, n_microbatches,
-                     mesh)
-    ck = _as_checkpointer(ckpt, chaos) if ckpt is not None else None
-    if ck is not None and ckpt_every > 0:
-        _guard_fresh_dir(ck, "resume_linear_streamed")
-    state = S.tx.init(params)
-    if registry.on_tpu():
-        # the update step donates (params, state); the first call would
-        # otherwise donate — and delete — the CALLER's init table
-        params = jax.tree_util.tree_map(jnp.copy, params)
-    return _stream_loop(S, params, state, 0, ckpt=ck,
-                        ckpt_every=ckpt_every, watchdog=watchdog,
-                        chaos=chaos, return_state=return_state)
+    with jax.profiler.TraceAnnotation(FIT_SPAN):
+        with jax.profiler.TraceAnnotation(SETUP_SPAN):
+            validate_bag_features(params, pipe.num_features, spec=pipe.spec)
+            S = _StreamSetup(pipe, x, labels, cfg, shuffle_key,
+                             n_microbatches, mesh)
+            ck = _as_checkpointer(ckpt, chaos) if ckpt is not None else None
+            if ck is not None and ckpt_every > 0:
+                _guard_fresh_dir(ck, "resume_linear_streamed")
+            state = S.tx.init(params)
+            if registry.on_tpu():
+                # the update step donates (params, state); the first call
+                # would otherwise donate — and delete — the CALLER's init
+                # table
+                params = jax.tree_util.tree_map(jnp.copy, params)
+        return _stream_loop(S, params, state, 0, ckpt=ck,
+                            ckpt_every=ckpt_every, watchdog=watchdog,
+                            chaos=chaos, return_state=return_state)
 
 
 def resume_linear_streamed(ckpt, pipe: FeaturePipeline, x: Array,
@@ -507,39 +517,43 @@ def resume_linear_streamed(ckpt, pipe: FeaturePipeline, x: Array,
     microbatching, and shuffle key (if one is passed) must all match
     the checkpointed run — each mismatch raises loudly instead of
     resuming into silent garbage."""
-    ck = _as_checkpointer(ckpt, chaos)
-    target = latest_step(ck.ckpt_dir) if step is None else step
-    if target is None:
-        raise FileNotFoundError(
-            f"no committed checkpoint under {ck.ckpt_dir}; start with "
-            f"fit_linear_streamed(..., ckpt=, ckpt_every=)")
-    manifest = json.loads(
-        (ck.ckpt_dir / f"step_{target:08d}" / "manifest.json").read_text())
-    stream = manifest.get("extra", {}).get("stream")
-    if stream is None:
-        raise ValueError(
-            f"checkpoint step {target} under {ck.ckpt_dir} carries no "
-            f"stream state — not a fit_linear_streamed checkpoint")
+    with jax.profiler.TraceAnnotation(FIT_SPAN):
+        with jax.profiler.TraceAnnotation(SETUP_SPAN):
+            ck = _as_checkpointer(ckpt, chaos)
+            target = latest_step(ck.ckpt_dir) if step is None else step
+            if target is None:
+                raise FileNotFoundError(
+                    f"no committed checkpoint under {ck.ckpt_dir}; start "
+                    f"with fit_linear_streamed(..., ckpt=, ckpt_every=)")
+            manifest = json.loads((ck.ckpt_dir / f"step_{target:08d}"
+                                   / "manifest.json").read_text())
+            stream = manifest.get("extra", {}).get("stream")
+            if stream is None:
+                raise ValueError(
+                    f"checkpoint step {target} under {ck.ckpt_dir} carries "
+                    f"no stream state — not a fit_linear_streamed "
+                    f"checkpoint")
 
-    _check_match("pipeline fingerprint", stream["fingerprint"],
-                 pipe.fingerprint())
-    _check_match("TrainCfg", stream["cfg"], dataclasses.asdict(cfg))
-    _check_match("dataset rows", stream["n"], int(x.shape[0]))
-    _check_match("n_microbatches", stream["n_microbatches"],
-                 int(n_microbatches))
-    stored_key = jnp.asarray(np.asarray(stream["shuffle_key"], np.uint32))
-    if shuffle_key is not None:
-        _check_match("shuffle_key", stream["shuffle_key"],
-                     _key_data_list(shuffle_key))
+            _check_match("pipeline fingerprint", stream["fingerprint"],
+                         pipe.fingerprint())
+            _check_match("TrainCfg", stream["cfg"], dataclasses.asdict(cfg))
+            _check_match("dataset rows", stream["n"], int(x.shape[0]))
+            _check_match("n_microbatches", stream["n_microbatches"],
+                         int(n_microbatches))
+            stored_key = jnp.asarray(
+                np.asarray(stream["shuffle_key"], np.uint32))
+            if shuffle_key is not None:
+                _check_match("shuffle_key", stream["shuffle_key"],
+                             _key_data_list(shuffle_key))
 
-    S = _StreamSetup(pipe, x, labels, cfg, stored_key, n_microbatches,
-                     mesh)
-    restored = restore_checkpoint(ck.ckpt_dir, target, S.template(),
-                                  shardings=S.shardings())
-    return _stream_loop(S, restored["params"], restored["opt_state"],
-                        int(stream["next_step"]), ckpt=ck,
-                        ckpt_every=ckpt_every, watchdog=watchdog,
-                        chaos=chaos, return_state=return_state)
+            S = _StreamSetup(pipe, x, labels, cfg, stored_key,
+                             n_microbatches, mesh)
+            restored = restore_checkpoint(ck.ckpt_dir, target, S.template(),
+                                          shardings=S.shardings())
+        return _stream_loop(S, restored["params"], restored["opt_state"],
+                            int(stream["next_step"]), ckpt=ck,
+                            ckpt_every=ckpt_every, watchdog=watchdog,
+                            chaos=chaos, return_state=return_state)
 
 
 def fit_linear_streamed_resilient(params: LinearParams,

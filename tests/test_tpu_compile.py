@@ -17,6 +17,8 @@ be read back without one).
 """
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -127,3 +129,18 @@ def test_serving_bucket_blocks_compile_for_v5e(one_chip, rows):
     must still lower at each."""
     fn, shapes = _case("cws_encode", n=rows)
     assert "tpu_custom_call" in _compiled_text(fn, shapes, one_chip)
+
+
+@pytest.mark.parametrize("name", [
+    "cws_hash", "cws_encode", "cws_hash_rng", "cws_encode_rng",
+    "cws_encode_packed", "cws_encode_rng_packed",
+])
+def test_kernel_keeps_its_name_in_the_tpu_lowering(name):
+    """Each Mosaic kernel is named after its wrapper, whatever the kernel
+    body's Python function is called; profiles and their readers match
+    these names.  Lowering for the TPU needs no chip and no topology."""
+    fn, shapes = _case(name)
+    args = [jax.ShapeDtypeStruct(s, dt) for s, dt in shapes]
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert re.findall(r'kernel_name = "(\w+)"', text) == [f"{name}_pallas"]
