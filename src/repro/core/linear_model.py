@@ -1,11 +1,21 @@
 """Linear classifiers for (a) dense features and (b) CWS-hashed features.
 
 The hashed dataset (k hashes, each a one-hot over 2^{b_i+b_t} buckets) is an
-embedding-bag: logits_c = sum_j W_c[j, code_j] + b_c.  We therefore store
-W as (n_classes, k, width) and train with gathers — never materializing the
-one-hot matrix.  This is the exact structure of a vocab-sharded embedding
-table, so at scale W shards over the `model` mesh axis (width dim) and the
-batch over `data`, reusing the LM sharding rules.
+embedding-bag: logits_c = sum_j W_c[j, code_j] + b_c.  This is the exact
+structure of a vocab-sharded embedding table, so at scale W shards over the
+`model` mesh axis (width dim) and the batch over `data`, reusing the LM
+sharding rules.
+
+Two heads compute it.  ``hashed_logits``, ``bag_logits`` and
+``bag_logits_packed`` gather table rows (their backward is a scatter-add):
+they take arbitrary indices under a clamp policy, and serve the
+forward-only scoring paths and ``fit_linear``.  ``bag_logits_onehot``
+contracts a one-hot over hash blocks with the table on the MXU, and its
+backward is the same contraction transposed: the streamed trainer's head
+(repro.training.linear_trainer) up to its ``ONEHOT_MAX_WIDTH`` buckets a
+hash, where XLA's TPU gather and scatter-add move one table row at a time
+and the backward sorts its indices.  Both
+select the same rows exactly; f32 sums differ only in order.
 
 Losses: multiclass squared hinge (one-vs-rest, matching the paper's
 LIBLINEAR L2-loss setting) or softmax cross-entropy.  l2 reg corresponds to
@@ -112,6 +122,149 @@ def bag_logits(params: LinearParams, idx: Array) -> Array:
     return _bag_sum(jnp.take(params.w,
                              idx.astype(jnp.int32).clip(0, num_features - 1),
                              axis=0, mode="clip")) + params.b
+
+
+# rows of one one-hot block: the head's scratch is (_ONEHOT_ROWS, F) bf16
+# whatever the batch, so the batch_size == n path stays bounded.  512 is
+# four of the MXU's 128-row passes, and one block's one-hot is 268 MB at
+# k = 1,024, b = 8 where a backend materializes it (the CPU does; the TPU
+# compiler builds it inside the dot's fusion)
+_ONEHOT_ROWS = 512
+
+
+def _parts(x: Array) -> Array:
+    """f32 (..., C) -> bf16 (..., 3C): three parts whose f32 sum is ``x``
+    exactly, side by side, so one bf16 MXU pass (while 3C fits the 128
+    lanes) carries all three.
+
+    Each part is the top 16 bits of what is left (a bf16 is the top
+    half of an f32): the first takes sign, exponent and 7 mantissa
+    bits, the residual ``x - part`` is exact and holds the next 16, and
+    after the second cut at most 8 remain, which the third holds
+    whole.  Bit truncation, not a rounding cast, so nothing is narrowed
+    (exact for normal f32; parts below bf16's normal range may flush)."""
+    parts = []
+    for _ in range(3):
+        bits = jax.lax.bitcast_convert_type(x, jnp.uint32) >> 16
+        part = jax.lax.bitcast_convert_type(bits.astype(jnp.uint16),
+                                            jnp.bfloat16)
+        parts.append(part)
+        x = x - part.astype(jnp.float32)
+    return jnp.concatenate(parts, axis=-1)
+
+
+def _mxu_select(onehot: Array, parts: Array, dims) -> Array:
+    """``dot_general`` of an exact 0/1 bf16 one-hot with ``_parts(x)``,
+    accumulated in f32: every selected part is exact, and the three
+    parts' sums are added back in one fixed order."""
+    c = parts.shape[-1] // 3
+    out = jax.lax.dot_general(onehot, parts, dims,
+                              preferred_element_type=jnp.float32)
+    return (out[..., :c] + out[..., c:2 * c]) + out[..., 2 * c:]
+
+
+def _onehot_t(codes: Array, width: int) -> Array:
+    """(r, k) local codes -> the (k, width, r) exact 0/1 bf16 one-hot:
+    [j, v, i] is 1 where row i's hash j lands in bucket v.  Hash-major,
+    so (k, width) is the (k, width, C) view of the flat table's rows."""
+    hit = codes.T[:, None, :] == jnp.arange(width, dtype=codes.dtype)[:, None]
+    return hit.astype(jnp.bfloat16)
+
+
+# the forward contracts the one-hot's (hash, bucket) axes with the
+# table's; the backward contracts its row axis with the cotangent's
+_FWD_DIMS = (((0, 1), (0, 1)), ((), ()))
+_BWD_DIMS = (((2,), (0,)), ((), ()))
+
+
+def _rows(x: Array, i) -> Array:
+    """Row block ``i`` of ``x``: a dynamic slice, so the loops below
+    read the batch in place instead of a padded or re-laid-out copy."""
+    return jax.lax.dynamic_slice_in_dim(x, i * _ONEHOT_ROWS, _ONEHOT_ROWS)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _onehot_contract(width: int, w: Array, codes: Array) -> Array:
+    """Σ_j onehot(codes[:, j]) @ W_j over the (k, width, C) view of the
+    flat (F, C) table: logits without the bias.  Batches over
+    ``_ONEHOT_ROWS`` rows go block by block, the remainder last."""
+    w3 = _parts(w.reshape(codes.shape[1], width, -1))
+
+    def block(c):
+        return _mxu_select(_onehot_t(c, width), w3, _FWD_DIMS)
+
+    if codes.shape[0] <= _ONEHOT_ROWS:
+        return block(codes)
+    nb, rem = divmod(codes.shape[0], _ONEHOT_ROWS)
+    out = jax.lax.map(lambda i: block(_rows(codes, i)), jnp.arange(nb))
+    out = out.reshape(nb * _ONEHOT_ROWS, -1)
+    if rem:
+        out = jnp.concatenate([out, block(codes[nb * _ONEHOT_ROWS:])])
+    return out
+
+
+def _onehot_contract_fwd(width, w, codes):
+    return _onehot_contract(width, w, codes), codes
+
+
+def _onehot_contract_bwd(width, codes, g):
+    """dW = onehotᵀ @ g, the same exact contraction transposed (over
+    rows), summed over the row blocks in f32; the codes get no
+    cotangent.  No scatter: each table row's gradient is MXU
+    accumulation over the batch."""
+    k = codes.shape[1]
+
+    def block(c, gb):
+        return _mxu_select(_onehot_t(c, width), _parts(gb), _BWD_DIMS)
+
+    if codes.shape[0] <= _ONEHOT_ROWS:
+        dw = block(codes, g)
+    else:
+        nb, rem = divmod(codes.shape[0], _ONEHOT_ROWS)
+        dw = jax.lax.fori_loop(
+            0, nb, lambda i, dw: dw + block(_rows(codes, i), _rows(g, i)),
+            jnp.zeros((k, width, g.shape[1]), jnp.float32))
+        if rem:
+            tail = nb * _ONEHOT_ROWS
+            dw = dw + block(codes[tail:], g[tail:])
+    return dw.reshape(k * width, -1), None
+
+
+_onehot_contract.defvjp(_onehot_contract_fwd, _onehot_contract_bwd)
+
+
+def bag_logits_onehot(params: LinearParams, codes: Array) -> Array:
+    """Embedding-bag logits from LOCAL codes, by one-hot contraction.
+
+    codes: (n, k) int32 bucket ids in [0, width) — hash j's code into
+    its own block of the flat (F, C) table, F = k * width, which is how
+    ``FeaturePipeline`` lays out its indices (``j * width + code_j``).
+    So logits = Σ_j onehot(code_j) @ W_j: one contraction of an
+    (n, F) one-hot against the table, on the MXU, and its gradient is
+    the same contraction transposed.  The streamed trainer's head
+    (training.linear_trainer) up to its ``ONEHOT_MAX_WIDTH``: XLA's TPU
+    gather and scatter-add move one lane-padded table row at a time,
+    and the scatter sorts its indices.  The work per row grows with
+    k * width (the gather's with k), and each row block's one-hot is
+    materialized where the backend does not fuse it into the dot.
+
+    Exact in f32: the one-hot is 0/1 in bf16 and the table (forward) or
+    the logits' cotangent (backward) is split into three bf16 parts, so
+    every selected value is exact and sums accumulate in f32 — the same
+    rows as ``bag_logits`` to f32 round-off, in another summation order.
+    Batches go in blocks of 512 rows, so the one-hot is at most
+    (512, F) whatever n (the TPU compiler builds it inside the dot's
+    fusion, not in HBM).  Codes clamp into [0, width - 1],
+    ``hashed_logits``' policy per hash block."""
+    if codes.ndim != 2:
+        raise ValueError(f"bag codes must be (n, k); got {codes.shape}")
+    if params.w.ndim != 2 or params.w.shape[0] % codes.shape[1]:
+        raise ValueError(
+            f"bag params must be a flat (k * width, C) table for "
+            f"{codes.shape[1]} hashes; got w {params.w.shape}")
+    width = params.w.shape[0] // codes.shape[1]
+    codes = codes.astype(jnp.int32).clip(0, width - 1)
+    return _onehot_contract(width, params.w, codes) + params.b
 
 
 def check_bag_table_size(num_hashes: int, b: int) -> int:

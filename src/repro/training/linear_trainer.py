@@ -24,11 +24,23 @@ never bounce through host numpy).
 Epoch shuffling draws one permutation per epoch from ``shuffle_key``
 (ragged remainder dropped — a fresh permutation drops different rows each
 epoch); ``batch_size == n`` skips the permutation, since a full-batch
-gradient is order-invariant, and is then bit-identical to full-batch
-``fit_linear`` on precomputed features.  The update step shares the
-trainer's microbatch/donation machinery: grads via
+gradient is order-invariant, and is then bit-identical to a full-batch
+fit of the same head on the precomputed features (``fit_linear``'s loop;
+with the one-hot head, on the local codes).  The update step shares the trainer's microbatch/donation machinery: grads via
 ``trainer.microbatch_grads`` and (params, opt state) donated on TPU so
 Adam updates the table in place.
+
+The head (``_bag_logits_fn``) is the one-hot contraction
+``bag_logits_onehot`` on every surface here — the single-chip and the
+sharded update, the ``batch_size == n`` path and ``streamed_accuracy``:
+the pipeline's indices are block-laid (hash j in rows ``j * width ..``),
+so the contraction and its transpose run on the MXU, where a row gather
+and its scatter-add move one table row at a time.  Its work per row
+grows with the hash width, so specs wider than ``ONEHOT_MAX_WIDTH``
+buckets keep the gather, as do the serving head and ``fit_linear``.
+The choice follows the spec alone, not the backend, so the CPU tests
+run the chip's head (on the CPU the contraction is the slower at every
+width: it materializes each row block's one-hot).
 
 Data parallelism (DESIGN.md §11): pass ``mesh=`` to run every per-batch
 launch shard_mapped over the mesh's ``data`` axis — each device
@@ -68,8 +80,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import optim
 from repro.checkpoint import Checkpointer, latest_step, restore_checkpoint
+from repro.core.hashing import unpack_codes
 from repro.core.linear_model import (LinearParams, TrainCfg, _loss_fn,
-                                     bag_logits, bag_logits_packed, init_bag,
+                                     bag_logits, bag_logits_onehot, init_bag,
                                      make_linear_tx, validate_bag_features)
 from repro.kernels import registry
 from repro.launch.mesh import data_axis_size
@@ -90,21 +103,44 @@ __all__ = ["fit_linear_streamed", "resume_linear_streamed",
            "resume_streamed_accuracy", "export_served_model"]
 
 
+# widest hash block the trainer contracts.  The contraction's work per
+# row grows with k * width and the gather's with k alone; on a TPU v5e,
+# at k = 256 and 512 rows, the update step takes 12.7 against 19.8 ms at
+# 2^14 buckets and 26.3 against 23.7 ms at 2^15, so wider specs gather
+ONEHOT_MAX_WIDTH = 1 << 14
+
+
 def _bag_logits_fn(pipe: FeaturePipeline):
-    """The logits head matching the pipeline's output format: the plain
-    index-gather ``bag_logits``, or — for ``spec.packed`` pipelines —
-    ``bag_logits_packed`` bound to the spec's (k, b), which unpacks the
-    uint32 feature words in registers and gathers the same table.  Packed
-    and unpacked training at the same (b_i, b_t) are bit-identical: the
-    decoded indices match, so every downstream float op matches."""
+    """The trainer's logits head for the pipeline's output format (see
+    the module docstring for which surfaces contract and which gather).
+
+    Up to ``ONEHOT_MAX_WIDTH`` buckets a hash, the head takes local
+    codes — ``idx - j * width`` of the block-laid indices, or the
+    unpacked words of a ``spec.packed`` pipeline — and runs
+    ``bag_logits_onehot``.  Packed and unpacked training at the same
+    (b_i, b_t) are bit-identical: the local codes match, so every float
+    op matches.  Wider (unpacked) specs gather with ``bag_logits``;
+    packed words hold at most 8 bits, so they always contract."""
     spec = pipe.spec
-    if not getattr(spec, "packed", False):
+    if spec.width > ONEHOT_MAX_WIDTH:
         return bag_logits
-    return functools.partial(bag_logits_packed,
-                             num_hashes=spec.num_hashes, b=spec.bits)
+    k = spec.num_hashes
+    if getattr(spec, "packed", False):
+        def local_codes(fb):
+            return unpack_codes(fb, k, b=spec.bits)
+    else:
+        def local_codes(fb):
+            # the clamp keeps the subtraction inside int32 for any input
+            offs = jnp.arange(k, dtype=jnp.int32) * spec.width
+            return fb.astype(jnp.int32).clip(0, k * spec.width - 1) - offs
+
+    def logits(params, fb):
+        return bag_logits_onehot(params, local_codes(fb))
+
+    return logits
 
 
-def _make_update_step(cfg: TrainCfg, tx, n_micro: int, logits_fn=bag_logits):
+def _make_update_step(cfg: TrainCfg, tx, n_micro: int, logits_fn):
     """One donated jitted update on a featurized minibatch — the bag
     head riding the trainer's microbatch/donation machinery."""
     donate = registry.donate_argnums(0, 1)
@@ -625,8 +661,8 @@ def streamed_accuracy(params: LinearParams, pipe: FeaturePipeline,
     """Accuracy over pipeline features without materializing (n, k):
     walks ``pipe.feature_chunks`` and accumulates correct counts.  With
     ``mesh=`` each chunk launch is shard_mapped over ``data`` (same
-    chunk walk, so the count — an integer — is identical).  Packed
-    pipelines evaluate through ``bag_logits_packed`` — the chunks stay
+    chunk walk, so the count — an integer — is identical).  Scores
+    through the trainer's head (``_bag_logits_fn``); packed chunks stay
     uint32 words end to end.
 
     ``ckpt=``/``ckpt_every=N`` (chunks) checkpoint the partial count +
@@ -758,9 +794,9 @@ def _donation_site_update_step():
 @registry.register_numerics_site("trainer.grad_accum")
 def _numerics_site_grad_accum():
     # n_micro=2 so the microbatch gradient accumulator appears as a real
-    # scan carry — the dtype-flow check pins it to float32.  The
-    # embedding-bag backward is a float scatter-add; XLA's deterministic
-    # scatter lowering is a recorded dependency, blessed here by name.
+    # scan carry — the dtype-flow check pins it to float32.  The bag
+    # head's backward is a one-hot contraction, not a scatter: nothing is
+    # blessed, so a float scatter-add coming back into the update fails.
     pipe, cfg, tx, params = _analysis_setup()
     step = _make_update_step(cfg, tx, 2, _bag_logits_fn(pipe))
     state = tx.init(params)
@@ -768,8 +804,7 @@ def _numerics_site_grad_accum():
                               jnp.int32)
     yb = jax.ShapeDtypeStruct((cfg.batch_size,), jnp.int32)
     i = jnp.zeros((), jnp.int32)
-    return {"fn": lambda *a: step(*a), "args": (params, state, fb, yb, i),
-            "allow": ("scatter-add",)}
+    return {"fn": lambda *a: step(*a), "args": (params, state, fb, yb, i)}
 
 
 @registry.register_collective_site("trainer.sharded_update")

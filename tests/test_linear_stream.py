@@ -1,21 +1,26 @@
 """Streaming minibatch training (repro.training.linear_trainer) and the
 index-bounds/ragged-chunk correctness fixes that ride with it.
 
-Covers: streamed-vs-fullbatch parity (bit-identity at batch_size = n,
-accuracy parity for true minibatches), OOB/sentinel gather guards in
+Covers: streamed-vs-fullbatch parity (bit-identity at batch_size = n
+and on minibatches against a materialized fit with the trainer's one-hot
+head, accuracy parity for true minibatches), OOB/sentinel gather guards in
 bag_logits/hashed_logits, the single-compile ragged-streaming contract
 (counted via the donating chunk fn's jit cache), never-materializing the
 (n, k) index matrix (launch-shape assertions), and empty/one-row batches.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.linear_model import (LinearParams, TrainCfg, bag_logits,
+from repro import optim
+from repro.core.linear_model import (LinearParams, TrainCfg, _loss_fn,
+                                     bag_logits, bag_logits_onehot,
                                      fit_linear, hashed_logits, init_bag,
                                      init_hashed, linear_accuracy,
-                                     validate_bag_features)
+                                     make_linear_tx, validate_bag_features)
 from repro.analysis import compile_guard
 from repro.data.synthetic import make_template_classification
 from repro.pipeline import FeaturePipeline, FeatureSpec
@@ -44,22 +49,72 @@ def problem():
     return pipe, xtr, ytr, xte, yte
 
 
+def local_codes(pipe, feats):
+    """The pipeline's global indices as the trainer's head takes them:
+    hash j's code into its own block, idx - j * width."""
+    return feats - jnp.arange(pipe.spec.num_hashes) * pipe.spec.width
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def fit_onehot(params, codes, labels, *, cfg, shuffle_key=None):
+    """``fit_linear``'s loop on materialized local codes with the
+    trainer's head, ``bag_logits_onehot``: the full-batch gradient at
+    ``batch_size`` 0 or n, else the same per-epoch permutations."""
+    tx = make_linear_tx(cfg)
+    n, bs = codes.shape[0], cfg.batch_size
+
+    def update(i, params, state, xb, yb):
+        grads = jax.grad(_loss_fn)(params, xb, yb, cfg, bag_logits_onehot)
+        updates, state = tx.update(grads, state, params, i)
+        return optim.apply_updates(params, updates), state
+
+    if bs in (0, n):
+        return jax.lax.fori_loop(
+            0, cfg.steps, lambda i, c: update(i, *c, codes, labels),
+            (params, tx.init(params)))[0]
+
+    key = shuffle_key if shuffle_key is not None else jax.random.PRNGKey(0)
+
+    def step(i, carry):
+        params, state, perm = carry
+        pos = i % (n // bs)
+        perm = jax.lax.cond(
+            pos == 0,
+            lambda: jax.random.permutation(
+                jax.random.fold_in(key, i // (n // bs)), n),
+            lambda: perm)
+        idx = jax.lax.dynamic_slice_in_dim(perm, pos * bs, bs)
+        return (*update(i, params, state, jnp.take(codes, idx, axis=0),
+                        jnp.take(labels, idx, axis=0)), perm)
+
+    return jax.lax.fori_loop(0, cfg.steps, step,
+                             (params, tx.init(params),
+                              jnp.arange(n, dtype=jnp.int32)))[0]
+
+
 class TestStreamedParity:
     def test_batch_size_n_bit_identical_to_fullbatch(self, problem):
+        # full batch on precomputed features with the trainer's head (the
+        # one-hot contraction on local codes)
         pipe, xtr, ytr, _, _ = problem
         n = xtr.shape[0]
         p0 = init_bag(jax.random.PRNGKey(0), pipe.num_features, 3)
         feats = pipe.features(xtr)
+        codes = local_codes(pipe, feats)
         cfg0 = TrainCfg(n_classes=3, steps=40, lr=0.05, l2=1e-5)
         cfgn = TrainCfg(n_classes=3, steps=40, lr=0.05, l2=1e-5,
                         batch_size=n)
-        p_fb = fit_linear(p0, feats, ytr, cfg=cfg0, kind="bag")
+        p_fb = fit_onehot(p0, codes, ytr, cfg=cfg0)
         p_st = fit_linear_streamed(p0, pipe, xtr, ytr, cfg=cfgn)
         np.testing.assert_array_equal(np.asarray(p_fb.w), np.asarray(p_st.w))
         np.testing.assert_array_equal(np.asarray(p_fb.b), np.asarray(p_st.b))
-        # and fit_linear's own batch_size=n minibatch route is the same
-        p_mn = fit_linear(p0, feats, ytr, cfg=cfgn, kind="bag")
+        # and the batch_size=n minibatch route is the same, with either
+        # head
+        p_mn = fit_onehot(p0, codes, ytr, cfg=cfgn)
         np.testing.assert_array_equal(np.asarray(p_fb.w), np.asarray(p_mn.w))
+        p_fg = fit_linear(p0, feats, ytr, cfg=cfg0, kind="bag")
+        p_mg = fit_linear(p0, feats, ytr, cfg=cfgn, kind="bag")
+        np.testing.assert_array_equal(np.asarray(p_fg.w), np.asarray(p_mg.w))
 
     def test_minibatch_accuracy_parity(self, problem):
         pipe, xtr, ytr, xte, yte = problem
@@ -94,12 +149,11 @@ class TestStreamedParity:
         # materialized minibatch path walk the same batch sequence
         pipe, xtr, ytr, _, _ = problem
         p0 = init_bag(jax.random.PRNGKey(0), pipe.num_features, 3)
-        feats = pipe.features(xtr)
+        codes = local_codes(pipe, pipe.features(xtr))
         cfg = TrainCfg(n_classes=3, steps=30, lr=0.05, l2=1e-5,
                        batch_size=32)
         key = jax.random.PRNGKey(5)
-        p_mat = fit_linear(p0, feats, ytr, cfg=cfg, kind="bag",
-                           shuffle_key=key)
+        p_mat = fit_onehot(p0, codes, ytr, cfg=cfg, shuffle_key=key)
         p_str = fit_linear_streamed(p0, pipe, xtr, ytr, cfg=cfg,
                                     shuffle_key=key)
         np.testing.assert_allclose(np.asarray(p_mat.w), np.asarray(p_str.w),

@@ -144,3 +144,46 @@ def test_kernel_keeps_its_name_in_the_tpu_lowering(name):
     text = jax.jit(fn).trace(*args).lower(
         lowering_platforms=("tpu",)).as_text()
     assert re.findall(r'kernel_name = "(\w+)"', text) == [f"{name}_pallas"]
+
+
+@pytest.mark.parametrize("packed", [False, True])
+def test_trainer_update_has_no_scatter_sort_or_gather_for_v5e(one_chip,
+                                                              packed):
+    """The streamed trainer's jitted ``update`` at the train cell's size
+    (512 rows, k = 1,024, b = 8, 10 classes): the bag head is a one-hot
+    contraction, so the compiled step holds no scatter, no index sort
+    and no gather of table rows, and the one-hot is built inside the dot
+    fusions
+    (scratch far under one (512, F) bf16 block, 268 MB)."""
+    import types
+
+    from repro.core.linear_model import TrainCfg, init_bag, make_linear_tx
+    from repro.pipeline import FeatureSpec
+    from repro.training import linear_trainer as lt
+
+    spec = FeatureSpec(num_hashes=K, b_i=B_I, packed=packed)
+    cfg = TrainCfg(n_classes=10, steps=544, lr=0.05, l2=1e-5,
+                   batch_size=N)
+    tx = make_linear_tx(cfg)
+    with registry.force_donation():
+        step = lt._make_update_step(
+            cfg, tx, 1, lt._bag_logits_fn(types.SimpleNamespace(spec=spec)))
+    p0 = init_bag(jax.random.PRNGKey(0), spec.num_features, 10)
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    feats = ((N, spec.packed_words), jnp.uint32) if packed else \
+        ((N, K), jnp.int32)
+    args = (jax.tree_util.tree_map(sds, p0),
+            jax.tree_util.tree_map(sds, jax.eval_shape(tx.init, p0)),
+            jax.ShapeDtypeStruct(*feats, sharding=one_chip),
+            jax.ShapeDtypeStruct((N,), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
+    compiled = step.lower(*args).compile()
+    ops = re.findall(r"= (\w+)\[\S* (\w[\w-]*)\(", compiled.as_text())
+    assert not {op for _, op in ops} & {"scatter", "sort"}
+    # (a packed spec's unpack gathers its uint32 words, not table rows)
+    assert not [t for t, op in ops if op == "gather" and t[0] in "fb"]
+    onehot_block = N * spec.num_features * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < onehot_block // 16
