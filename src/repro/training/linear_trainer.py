@@ -46,7 +46,10 @@ Data parallelism (DESIGN.md §11): pass ``mesh=`` to run every per-batch
 launch shard_mapped over the mesh's ``data`` axis — each device
 featurizes its shard of the minibatch with the pipeline kernel, computes
 local grads through the shared ``microbatch_grads`` path, grads/loss are
-psum'd inside it, and the optimizer update stays replicated.  On a
+psum'd inside it, and the optimizer update stays replicated.  What every
+step passes in is placed on the mesh ahead of the loop (the launch
+state and the table at set-up, the row order once an epoch, the step's
+scalars from the host), so no step copies arrays between devices.  On a
 1-device mesh this is bit-identical to the unsharded path under the same
 ``shuffle_key``; on N devices the batch walk is identical and only
 gradient summation order differs (float reassociation).
@@ -97,6 +100,13 @@ Array = jax.Array
 FIT_SPAN = "repro.fit"              # a whole fit or resume call
 SETUP_SPAN = "repro.fit.setup"      # everything before the first step
 STEP_SPAN = "repro.fit.step"        # one step on the host, its save excluded
+
+
+def _shards(mesh) -> int:
+    """The setup span's ``shards``: devices the fit splits each batch
+    over (the mesh's ``data`` axis; 1 without a mesh)."""
+    return 1 if mesh is None else data_axis_size(mesh)
+
 
 __all__ = ["fit_linear_streamed", "resume_linear_streamed",
            "fit_linear_streamed_resilient", "streamed_accuracy",
@@ -324,6 +334,9 @@ class _StreamSetup:
             self.labels = jnp.asarray(labels)
             self.gather = _make_device_gather(bs, mesh)
 
+        # what every step passes in is placed on the mesh once: an array
+        # left on one device is copied to every other at each dispatch
+        self.replicated = None if mesh is None else NamedSharding(mesh, P())
         if mesh is None:
             self.update = _make_update_step(cfg, self.tx, n_microbatches,
                                             _bag_logits_fn(pipe))
@@ -332,7 +345,8 @@ class _StreamSetup:
             self.update = _make_sharded_update_step(
                 cfg, self.tx, n_microbatches, pipe, mesh,
                 featurize=self.shuffle)
-            self.pstate = pipe._state()
+            self.pstate = jax.device_put(
+                pipe._state(), NamedSharding(mesh, pipe.state_pspec()))
 
         self.fb_full = self.yb_full = None
         if not self.shuffle:
@@ -346,6 +360,21 @@ class _StreamSetup:
             if mesh is not None:
                 self.yb_full = jax.device_put(
                     self.yb_full, NamedSharding(mesh, P("data")))
+
+    def permutation(self, epoch: int):
+        """The epoch's row order (on the mesh, replicated once an epoch)."""
+        perm = jax.random.permutation(jax.random.fold_in(self.key, epoch),
+                                      self.n)
+        if self.replicated is None:
+            return perm
+        return jax.device_put(perm, self.replicated)
+
+    def scalar(self, v: int):
+        """A step's int32 argument (on the mesh, put on every device from
+        the host rather than made on one and copied to the others)."""
+        if self.replicated is None:
+            return jnp.int32(v)
+        return jax.device_put(np.int32(v), self.replicated)
 
     # -- the checkpoint payload ----------------------------------------
 
@@ -406,8 +435,7 @@ def _stream_loop(S: _StreamSetup, params: LinearParams, state, start: int,
                         chaos.fire("step", i)
                     if S.shuffle:
                         if epoch != cur_epoch:
-                            perm = jax.random.permutation(
-                                jax.random.fold_in(S.key, epoch), S.n)
+                            perm = S.permutation(epoch)
                             if S.host_data:
                                 perm_host = np.asarray(perm)
                             cur_epoch = epoch
@@ -422,24 +450,24 @@ def _stream_loop(S: _StreamSetup, params: LinearParams, state, start: int,
                                 yb = jax.device_put(yb, S.batch_shardings[1])
                         else:
                             xb, yb = S.gather(S.x, S.labels, perm,
-                                              jnp.int32(pos))
+                                              S.scalar(pos))
                         if mesh is None:
                             # the gather buffer is ours alone -> safe to
                             # donate to the featurization launch
                             fb = pipe.launch_chunk(xb)
                             params, state, _ = S.update(params, state, fb, yb,
-                                                        jnp.int32(i))
+                                                        S.scalar(i))
                         else:
                             # sharded: featurize runs INSIDE the shard_map
                             params, state = S.update(params, state, S.pstate,
-                                                     xb, yb, jnp.int32(i))
+                                                     xb, yb, S.scalar(i))
                     elif mesh is None:
                         params, state, _ = S.update(params, state, S.fb_full,
-                                                    S.yb_full, jnp.int32(i))
+                                                    S.yb_full, S.scalar(i))
                     else:
                         params, state = S.update(params, state, S.pstate,
                                                  S.fb_full, S.yb_full,
-                                                 jnp.int32(i))
+                                                 S.scalar(i))
                     if watchdog is not None:
                         jax.block_until_ready(params)
                 except KeyboardInterrupt as e:
@@ -501,7 +529,7 @@ def fit_linear_streamed(params: LinearParams, pipe: FeaturePipeline,
     plan through the step path (tests).  ``return_state=True`` returns
     ``(params, opt_state)`` instead of params alone."""
     with jax.profiler.TraceAnnotation(FIT_SPAN):
-        with jax.profiler.TraceAnnotation(SETUP_SPAN):
+        with jax.profiler.TraceAnnotation(SETUP_SPAN, shards=_shards(mesh)):
             validate_bag_features(params, pipe.num_features, spec=pipe.spec)
             S = _StreamSetup(pipe, x, labels, cfg, shuffle_key,
                              n_microbatches, mesh)
@@ -509,6 +537,8 @@ def fit_linear_streamed(params: LinearParams, pipe: FeaturePipeline,
             if ck is not None and ckpt_every > 0:
                 _guard_fresh_dir(ck, "resume_linear_streamed")
             state = S.tx.init(params)
+            if S.replicated is not None:
+                params, state = jax.device_put((params, state), S.replicated)
             if registry.on_tpu():
                 # the update step donates (params, state); the first call
                 # would otherwise donate — and delete — the CALLER's init
@@ -554,7 +584,7 @@ def resume_linear_streamed(ckpt, pipe: FeaturePipeline, x: Array,
     the checkpointed run — each mismatch raises loudly instead of
     resuming into silent garbage."""
     with jax.profiler.TraceAnnotation(FIT_SPAN):
-        with jax.profiler.TraceAnnotation(SETUP_SPAN):
+        with jax.profiler.TraceAnnotation(SETUP_SPAN, shards=_shards(mesh)):
             ck = _as_checkpointer(ckpt, chaos)
             target = latest_step(ck.ckpt_dir) if step is None else step
             if target is None:

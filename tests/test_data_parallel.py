@@ -4,7 +4,9 @@ composition (DESIGN.md §11).
 Single-device assertions (bit-identity of the mesh= paths against the
 unsharded ones) run everywhere; the multi-device parity tests activate
 under ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (the CI
-``sharded-smoke`` job) and skip otherwise.
+``sharded-smoke`` job) and skip otherwise.  The four-device fit against
+one device (``TestFourDevices``) runs everywhere, in a subprocess that
+forces four host devices.
 
 What is pinned down:
   * sharded+streamed composition pads ONCE to lcm(row_chunk, ndev) and
@@ -19,6 +21,11 @@ What is pinned down:
     key);
   * the param-free (create_regen) pipeline rides every sharded path.
 """
+import json
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -322,3 +329,92 @@ class TestMultiDeviceParity:
             sharded = pipe.features(x, mesh=mesh)
         np.testing.assert_array_equal(np.asarray(sharded),
                                       np.asarray(pipe.features(x)))
+
+
+FOUR_DEVICES = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import json
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.core.linear_model import TrainCfg, init_bag
+from repro.data.synthetic import make_template_classification
+from repro.launch.mesh import make_data_mesh
+from repro.pipeline import FeaturePipeline, FeatureSpec
+from repro.training import fit_linear_streamed, trainer
+
+ds = make_template_classification(3, n_train=1024, n_test=16, dim=32,
+                                  n_classes=3, mult_noise=1.1,
+                                  spike_prob=0.02, density=0.3)
+pipe = FeaturePipeline.create(jax.random.PRNGKey(7), 32,
+                              FeatureSpec(num_hashes=64, b_i=4))
+x, y = jnp.asarray(ds.x_train), jnp.asarray(ds.y_train)
+p0 = init_bag(jax.random.PRNGKey(1), pipe.num_features, 3)
+# 64 rows a device, 256 a step: 36 steps walk 9 epochs of 4 batches
+cfg = TrainCfg(n_classes=3, steps=36, lr=0.05, l2=1e-5, batch_size=256)
+key = jax.random.PRNGKey(5)
+
+
+def fit(mesh=None):
+    return fit_linear_streamed(p0, pipe, x, y, cfg=cfg, shuffle_key=key,
+                               mesh=mesh)
+
+
+def gaps(a, b):
+    return {k: float(jnp.linalg.norm(getattr(a, k) - getattr(b, k)) /
+                     jnp.linalg.norm(getattr(a, k))) for k in ("w", "b")}
+
+
+one = fit()
+out = {"devices": len(jax.devices()), "sound": gaps(one, fit(make_data_mesh(4)))}
+# with the data and the table on the mesh, no step copies an array from
+# one device to another (the launch state, the epoch's row order and the
+# step's scalars are placed on every device once)
+rep = NamedSharding(make_data_mesh(4), P())
+try:
+    with jax.transfer_guard_device_to_device("disallow"):
+        jax.block_until_ready(fit_linear_streamed(
+            jax.device_put(p0, rep), pipe, *jax.device_put((x, y), rep),
+            cfg=cfg, shuffle_key=key, mesh=make_data_mesh(4)))
+    out["device_to_device"] = None
+except Exception as e:
+    out["device_to_device"] = str(e)[:500]
+# the exchange left out: each device steps on its own shard's gradient
+trainer._pmean_loss_grads = lambda loss, grads, axis_name: (loss, grads)
+out["no_exchange"] = gaps(one, fit(make_data_mesh(4)))
+print(json.dumps(out))
+"""
+
+# only the order of the gradient's sum differs between one device and
+# four (about 1e-7 of each leaf's norm after 36 steps on the CPU)
+FOUR_DEVICE_RTOL = 1e-5
+
+
+@pytest.fixture(scope="module")
+def four_devices():
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", FOUR_DEVICES], cwd=root,
+                         env=env, capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    report = json.loads(out.stdout.strip().splitlines()[-1])
+    assert report["devices"] == 4
+    return report
+
+
+class TestFourDevices:
+    """fit_linear_streamed(mesh=make_data_mesh(4)) against the
+    single-device fit on the same 256-row global batches and shuffle
+    key: the leaves' norm-relative gaps after 36 steps."""
+
+    def test_four_devices_match_one(self, four_devices):
+        for leaf, gap in four_devices["sound"].items():
+            assert gap <= FOUR_DEVICE_RTOL, (leaf, gap)
+
+    def test_exchange_left_out_fails_the_tolerance(self, four_devices):
+        assert max(four_devices["no_exchange"].values()) > FOUR_DEVICE_RTOL
+
+    def test_steps_copy_nothing_between_devices(self, four_devices):
+        assert four_devices["device_to_device"] is None, \
+            four_devices["device_to_device"]

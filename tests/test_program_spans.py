@@ -1,7 +1,8 @@
 """The program's host spans in a profiler trace.
 
 ``fit_linear_streamed`` / ``resume_linear_streamed`` record ``repro.fit``
-around the call, ``repro.fit.setup`` before the first step and one
+around the call, ``repro.fit.setup`` before the first step (carrying
+``shards``, the devices each batch is split over) and one
 ``repro.fit.step`` per step (a step annotation carrying ``step_num``);
 ``FeaturePipeline.features`` records one ``repro.featurize.launch`` per
 chunk it launches, with the chunk's ``rows``.  Each test takes a profile
@@ -11,7 +12,10 @@ taken, and the outputs are the same bits whether one is or not.
 """
 import dataclasses
 import glob
+import json
 import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -151,3 +155,82 @@ def test_outputs_are_the_same_bits_under_a_profile(problem, tmp_path):
     for a, b in zip(jax.tree_util.tree_leaves(plain),
                     jax.tree_util.tree_leaves(traced)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("call", ["fit", "resume"])
+def test_setup_carries_one_shard_without_a_mesh(problem, tmp_path, call):
+    pipe, cfg, p0, x, y = problem
+    if call == "fit":
+        def work():
+            return fit_linear_streamed(p0, pipe, x, y, cfg=cfg)
+    else:
+        ck = Checkpointer(tmp_path / "ckpt")
+        with pytest.raises(ChaosKill):
+            fit_linear_streamed(p0, pipe, x, y, cfg=cfg, ckpt=ck,
+                                ckpt_every=2, chaos=ChaosPlan(kill_at(2)))
+        ck.wait()
+
+        def work():
+            return resume_linear_streamed(tmp_path / "ckpt", pipe, x, y,
+                                          cfg=cfg)
+    _, spans = profiled(tmp_path / "trace", work)
+    (setup,) = named(spans, "repro.fit.setup")
+    assert setup.args.get("shards") == 1
+
+
+FOUR_DEVICE_SETUP = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import glob, json, sys
+import jax
+from jax.profiler import ProfileData
+from repro.checkpoint import Checkpointer
+from repro.core.linear_model import TrainCfg, init_bag
+from repro.launch.mesh import make_data_mesh
+from repro.pipeline import FeaturePipeline, FeatureSpec
+from repro.runtime import ChaosKill, ChaosPlan, kill_at
+from repro.training import fit_linear_streamed, resume_linear_streamed
+
+tmp = sys.argv[1]
+pipe = FeaturePipeline.create(jax.random.PRNGKey(0), 16,
+                              FeatureSpec(num_hashes=8, b_i=2))
+x = jax.random.uniform(jax.random.PRNGKey(1), (64, 16))
+y = (x[:, 0] > x[:, 1]).astype("int32")
+p0 = init_bag(jax.random.PRNGKey(2), pipe.num_features, 2)
+cfg = TrainCfg(n_classes=2, steps=4, batch_size=16)
+mesh = make_data_mesh(4)
+ck = Checkpointer(os.path.join(tmp, "ckpt"))
+try:
+    fit_linear_streamed(p0, pipe, x, y, cfg=cfg, mesh=mesh, ckpt=ck,
+                        ckpt_every=2, chaos=ChaosPlan(kill_at(2)))
+except ChaosKill:
+    ck.wait()
+out = {}
+for call in ("fit", "resume"):
+    with jax.profiler.trace(os.path.join(tmp, call)):
+        if call == "fit":
+            params = fit_linear_streamed(p0, pipe, x, y, cfg=cfg, mesh=mesh)
+        else:
+            params = resume_linear_streamed(os.path.join(tmp, "ckpt"), pipe,
+                                            x, y, cfg=cfg, mesh=mesh)
+        jax.block_until_ready(params)
+    path = glob.glob(os.path.join(tmp, call, "**", "*.xplane.pb"),
+                     recursive=True)[-1]
+    out[call] = [dict(e.stats).get("shards")
+                 for plane in ProfileData.from_file(path).planes
+                 for line in plane.lines for e in line.events
+                 if e.name == "repro.fit.setup"]
+print(json.dumps(out))
+"""
+
+
+def test_setup_carries_four_shards_on_a_four_device_mesh(tmp_path):
+    """In a subprocess that forces four host devices."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", FOUR_DEVICE_SETUP,
+                          str(tmp_path)], cwd=root, env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    assert got == {"fit": [4], "resume": [4]}
