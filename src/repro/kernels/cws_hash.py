@@ -13,7 +13,9 @@ TPU adaptation (vs the paper's per-vector CPU loop):
     column log u against the parameter row) — no rank-3 temporaries.
     log u is staged once per grid step as a transposed (BD, BN) tile so
     the loop reads dimension d as a sublane row (Mosaic refuses a
-    dynamic lane slice);
+    dynamic lane slice).  The loop runs over the real dimensions only:
+    where BD does not divide D, the last D step's loop stops at D
+    (``tail_dims``), so the zero pad columns cost no iterations;
   * the kernel is VPU-bound (log/floor/mul on 8x128 lanes) and
     HBM-traffic-dominated by the 3 parameter matrices (DESIGN.md §2).
 
@@ -79,9 +81,27 @@ def _stage_logu(x_ref, lut_ref):
         jnp.where(x > 0, jnp.log(jnp.maximum(x, 1e-38)), -jnp.inf))
 
 
-def _accum_loop(lut_ref, r_ref, logc_ref, beta_ref, d_step, bd, carry):
-    """Run the BD-dimension argmin update on a (best_a, best_i[, best_t])
-    carry; t tracking is skipped when the carry has no t slot."""
+def tail_dims(d: int, bd: int) -> int:
+    """Real dimensions in the last D step of a (row, hash) tile: the
+    accumulation loop's trip count there.  ``bd`` is clamped to ``d`` as
+    the wrappers clamp it, so a D that ``bd`` divides, or a D below
+    ``bd``, gives the whole block: no tail."""
+    bd = min(bd, d)
+    return d - (-(-d // bd) - 1) * bd
+
+
+def loop_share(d: int, bd: int) -> float:
+    """Real D over the padded D: the share of the padded dimension loop
+    that the kernels run (1.0 where ``bd`` divides D)."""
+    bd = min(bd, d)
+    return d / (-(-d // bd) * bd)
+
+
+def _accum_loop(lut_ref, r_ref, logc_ref, beta_ref, d_step, bd, trips,
+                carry):
+    """Run the argmin update over the grid step's first ``trips``
+    dimensions on a (best_a, best_i[, best_t]) carry; t tracking is
+    skipped when the carry has no t slot."""
     track_t = len(carry) == 3
 
     def body(d, carry):
@@ -101,7 +121,31 @@ def _accum_loop(lut_ref, r_ref, logc_ref, beta_ref, d_step, bd, carry):
             return a, i, jnp.where(upd, tt, carry[2])
         return a, i
 
-    return jax.lax.fori_loop(0, bd, body, carry)
+    return jax.lax.fori_loop(0, trips, body, carry)
+
+
+def _accumulate(lut_ref, r_ref, logc_ref, beta_ref, best, *, bd: int,
+                n_d_steps: int, tail: int):
+    """One grid step's argmin update on the scratch accumulators
+    ``best`` (best_a, best_i[, best_t]).
+
+    The last D step loops over its ``tail`` real dimensions only: a pad
+    column (log u = -inf) can never win the strict ``<``, so leaving it
+    out changes no output bit.  Where ``bd`` divides D (``tail == bd``)
+    every step runs one loop of ``bd`` trips, with no branch."""
+    d_step = pl.program_id(2)
+
+    def run(trips):
+        out = _accum_loop(lut_ref, r_ref, logc_ref, beta_ref, d_step, bd,
+                          trips, tuple(ref[...] for ref in best))
+        for ref, v in zip(best, out):
+            ref[...] = v
+
+    if tail == bd:
+        run(bd)
+        return
+    pl.when(d_step < n_d_steps - 1)(lambda: run(bd))
+    pl.when(d_step == n_d_steps - 1)(lambda: run(tail))
 
 
 def _logu_scratch(bn, bd):
@@ -109,7 +153,8 @@ def _logu_scratch(bn, bd):
 
 
 def _cws_kernel(x_ref, r_ref, logc_ref, beta_ref, istar_ref, tstar_ref,
-                lut, best_a, best_i, best_t, *, bd: int, n_d_steps: int):
+                lut, best_a, best_i, best_t, *, bd: int, n_d_steps: int,
+                tail: int):
     d_step = pl.program_id(2)
 
     @pl.when(d_step == 0)
@@ -119,11 +164,8 @@ def _cws_kernel(x_ref, r_ref, logc_ref, beta_ref, istar_ref, tstar_ref,
         best_t[...] = jnp.zeros_like(best_t[...])
 
     _stage_logu(x_ref, lut)
-    a1, i1, t1 = _accum_loop(lut, r_ref, logc_ref, beta_ref, d_step, bd,
-                             (best_a[...], best_i[...], best_t[...]))
-    best_a[...] = a1
-    best_i[...] = i1
-    best_t[...] = t1
+    _accumulate(lut, r_ref, logc_ref, beta_ref, (best_a, best_i, best_t),
+                bd=bd, n_d_steps=n_d_steps, tail=tail)
 
     @pl.when(d_step == n_d_steps - 1)
     def _emit():
@@ -133,8 +175,9 @@ def _cws_kernel(x_ref, r_ref, logc_ref, beta_ref, istar_ref, tstar_ref,
 
 def _cws_encode_kernel(x_ref, r_ref, logc_ref, beta_ref, idx_ref, lut,
                        *scratch,
-                       bd: int, n_d_steps: int, b_i: int, b_t: int, bk: int,
-                       packed: bool = False, num_hashes: int = 0):
+                       bd: int, n_d_steps: int, tail: int, b_i: int,
+                       b_t: int, bk: int, packed: bool = False,
+                       num_hashes: int = 0):
     """Fused CWS -> b-bit code -> embedding-bag index.  ``scratch`` is
     (best_a, best_i) for the 0-bit scheme (b_t == 0) and
     (best_a, best_i, best_t) when t* bits are kept.  ``packed=True``
@@ -152,12 +195,8 @@ def _cws_encode_kernel(x_ref, r_ref, logc_ref, beta_ref, idx_ref, lut,
             best_t[...] = jnp.zeros_like(best_t[...])
 
     _stage_logu(x_ref, lut)
-    carry = (best_a[...], best_i[...]) + ((best_t[...],) if b_t else ())
-    out = _accum_loop(lut, r_ref, logc_ref, beta_ref, d_step, bd, carry)
-    best_a[...] = out[0]
-    best_i[...] = out[1]
-    if b_t:
-        best_t[...] = out[2]
+    _accumulate(lut, r_ref, logc_ref, beta_ref, scratch, bd=bd,
+                n_d_steps=n_d_steps, tail=tail)
 
     @pl.when(d_step == n_d_steps - 1)
     def _emit():
@@ -257,7 +296,8 @@ def cws_hash_pallas(x: jax.Array, r: jax.Array, log_c: jax.Array,
     n_d_steps = dp_ // bd
 
     in_specs, out_spec = _cws_specs(bn, bk, bd)
-    kernel = functools.partial(_cws_kernel, bd=bd, n_d_steps=n_d_steps)
+    kernel = functools.partial(_cws_kernel, bd=bd, n_d_steps=n_d_steps,
+                               tail=tail_dims(d, bd))
     i_star, t_star = pl.pallas_call(
         kernel,
         name="cws_hash_pallas",
@@ -305,7 +345,8 @@ def cws_encode_pallas(x: jax.Array, r: jax.Array, log_c: jax.Array,
 
     in_specs, out_spec = _cws_specs(bn, bk, bd)
     kernel = functools.partial(_cws_encode_kernel, bd=bd,
-                               n_d_steps=n_d_steps, b_i=b_i, b_t=b_t, bk=bk)
+                               n_d_steps=n_d_steps, tail=tail_dims(d, bd),
+                               b_i=b_i, b_t=b_t, bk=bk)
     idx = pl.pallas_call(
         kernel,
         name="cws_encode_pallas",
@@ -346,7 +387,7 @@ def _regen_step(key_ref, d_step, bd, bk, r_s, c_s, b_s):
 
 def _cws_hash_rng_kernel(x_ref, key_ref, istar_ref, tstar_ref,
                          lut, r_s, c_s, b_s, best_a, best_i, best_t,
-                         *, bd: int, n_d_steps: int, bk: int):
+                         *, bd: int, n_d_steps: int, tail: int, bk: int):
     d_step = pl.program_id(2)
 
     @pl.when(d_step == 0)
@@ -357,11 +398,8 @@ def _cws_hash_rng_kernel(x_ref, key_ref, istar_ref, tstar_ref,
 
     _regen_step(key_ref, d_step, bd, bk, r_s, c_s, b_s)
     _stage_logu(x_ref, lut)
-    a1, i1, t1 = _accum_loop(lut, r_s, c_s, b_s, d_step, bd,
-                             (best_a[...], best_i[...], best_t[...]))
-    best_a[...] = a1
-    best_i[...] = i1
-    best_t[...] = t1
+    _accumulate(lut, r_s, c_s, b_s, (best_a, best_i, best_t),
+                bd=bd, n_d_steps=n_d_steps, tail=tail)
 
     @pl.when(d_step == n_d_steps - 1)
     def _emit():
@@ -371,8 +409,8 @@ def _cws_hash_rng_kernel(x_ref, key_ref, istar_ref, tstar_ref,
 
 def _cws_encode_rng_kernel(x_ref, key_ref, idx_ref, lut, r_s, c_s, b_s,
                            *scratch,
-                           bd: int, n_d_steps: int, b_i: int, b_t: int,
-                           bk: int, packed: bool = False,
+                           bd: int, n_d_steps: int, tail: int, b_i: int,
+                           b_t: int, bk: int, packed: bool = False,
                            num_hashes: int = 0):
     d_step = pl.program_id(2)
     hash_block = pl.program_id(1)
@@ -388,12 +426,8 @@ def _cws_encode_rng_kernel(x_ref, key_ref, idx_ref, lut, r_s, c_s, b_s,
 
     _regen_step(key_ref, d_step, bd, bk, r_s, c_s, b_s)
     _stage_logu(x_ref, lut)
-    carry = (best_a[...], best_i[...]) + ((best_t[...],) if b_t else ())
-    out = _accum_loop(lut, r_s, c_s, b_s, d_step, bd, carry)
-    best_a[...] = out[0]
-    best_i[...] = out[1]
-    if b_t:
-        best_t[...] = out[2]
+    _accumulate(lut, r_s, c_s, b_s, scratch, bd=bd, n_d_steps=n_d_steps,
+                tail=tail)
 
     @pl.when(d_step == n_d_steps - 1)
     def _emit():
@@ -443,7 +477,8 @@ def cws_hash_rng_pallas(x: jax.Array, key: jax.Array, num_hashes: int, *,
     n_d_steps = dp_ // bd
 
     kernel = functools.partial(_cws_hash_rng_kernel, bd=bd,
-                               n_d_steps=n_d_steps, bk=bk)
+                               n_d_steps=n_d_steps, tail=tail_dims(d, bd),
+                               bk=bk)
     i_star, t_star = pl.pallas_call(
         kernel,
         name="cws_hash_rng_pallas",
@@ -492,7 +527,8 @@ def cws_encode_rng_pallas(x: jax.Array, key: jax.Array, num_hashes: int, *,
         scratch.append(pltpu.VMEM((bn, bk), jnp.float32))    # best t
 
     kernel = functools.partial(_cws_encode_rng_kernel, bd=bd,
-                               n_d_steps=n_d_steps, b_i=b_i, b_t=b_t, bk=bk)
+                               n_d_steps=n_d_steps, tail=tail_dims(d, bd),
+                               b_i=b_i, b_t=b_t, bk=bk)
     idx = pl.pallas_call(
         kernel,
         name="cws_encode_rng_pallas",
@@ -572,8 +608,9 @@ def cws_encode_packed_pallas(x: jax.Array, r: jax.Array, log_c: jax.Array,
     in_specs, _ = _cws_specs(bn, bk, bd)
     out_spec, out_shape = _packed_out(np_, kp_, bn, bk, b)
     kernel = functools.partial(_cws_encode_kernel, bd=bd,
-                               n_d_steps=n_d_steps, b_i=b_i, b_t=b_t,
-                               bk=bk, packed=True, num_hashes=k)
+                               n_d_steps=n_d_steps, tail=tail_dims(d, bd),
+                               b_i=b_i, b_t=b_t, bk=bk, packed=True,
+                               num_hashes=k)
     words = pl.pallas_call(
         kernel,
         name="cws_encode_packed_pallas",
@@ -615,8 +652,9 @@ def cws_encode_rng_packed_pallas(x: jax.Array, key: jax.Array,
 
     out_spec, out_shape = _packed_out(np_, kp_, bn, bk, b)
     kernel = functools.partial(_cws_encode_rng_kernel, bd=bd,
-                               n_d_steps=n_d_steps, b_i=b_i, b_t=b_t,
-                               bk=bk, packed=True, num_hashes=num_hashes)
+                               n_d_steps=n_d_steps, tail=tail_dims(d, bd),
+                               b_i=b_i, b_t=b_t, bk=bk, packed=True,
+                               num_hashes=num_hashes)
     words = pl.pallas_call(
         kernel,
         name="cws_encode_rng_packed_pallas",

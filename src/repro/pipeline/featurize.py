@@ -43,6 +43,7 @@ from repro.core.hashing import (encode, feature_indices, hashed_dim,
                                 unpack_codes)
 from repro.core.regen import key_words
 from repro.kernels import ops, registry
+from repro.kernels.cws_hash import loop_share
 from repro.launch.mesh import data_axis_size
 
 Array = jax.Array
@@ -290,16 +291,18 @@ class FeaturePipeline:
         for lo in range(0, n, rows):
             hi = min(lo + rows, n)
             m = hi - lo
-            with jax.profiler.TraceAnnotation(LAUNCH_SPAN, rows=m):
+            # streamed ragged tails pad to the full chunk shape (the
+            # single-compile invariant); a lone short chunk (n <= rows)
+            # pads only to the data-axis multiple it must split into
+            target = (rows if (m < rows and n > rows)
+                      else m + ((-m) % ndev))
+            with jax.profiler.TraceAnnotation(
+                    LAUNCH_SPAN, rows=m,
+                    cws_loop_share=self.loop_share(target // ndev)):
                 # host-resident rows (numpy/memmap) slice on the host, so
                 # only the chunk ever crosses to the device
                 chunk = (jax.lax.slice_in_dim(x, lo, hi, axis=0)
                          if on_device else jnp.asarray(x[lo:hi]))
-                # streamed ragged tails pad to the full chunk shape (the
-                # single-compile invariant); a lone short chunk (n <= rows)
-                # pads only to the data-axis multiple it must split into
-                target = (rows if (m < rows and n > rows)
-                          else m + ((-m) % ndev))
                 if target > m:
                     chunk = jnp.pad(chunk, ((0, target - m), (0, 0)))
                     out = fn(chunk)[:m]
@@ -319,7 +322,8 @@ class FeaturePipeline:
         (n, ``spec.packed_words``) uint32.  Streams in
         ``chunk_rows(mesh)`` row chunks; with a ``mesh`` every launch is
         shard_mapped over its ``data`` axis.  Each launch's host work
-        (slice, pad, enqueue) is one ``LAUNCH_SPAN`` in a profile."""
+        (slice, pad, enqueue) is one ``LAUNCH_SPAN`` in a profile, with
+        the chunk's ``rows`` and the launch's ``cws_loop_share``."""
         self._require_bucketed("features")
         n = x.shape[0]
         if n == 0:   # empty stream chunk: nothing to launch
@@ -327,7 +331,10 @@ class FeaturePipeline:
                 return jnp.zeros((0, self.spec.packed_words), jnp.uint32)
             return jnp.zeros((0, self.spec.num_hashes), jnp.int32)
         if n <= self.chunk_rows(mesh):
-            with jax.profiler.TraceAnnotation(LAUNCH_SPAN, rows=n):
+            ndev = 1 if mesh is None else data_axis_size(mesh)
+            with jax.profiler.TraceAnnotation(
+                    LAUNCH_SPAN, rows=n,
+                    cws_loop_share=self.loop_share(-(-n // ndev))):
                 return self._launch(x) if mesh is None else \
                     self._features_sharded(x, mesh)
         return self._features_streamed(x, mesh=mesh)
@@ -439,13 +446,23 @@ class FeaturePipeline:
                     table, self._launch_with(xc, state)))
         return self._scoring_fn
 
-    def _launch_with(self, x: Array, state) -> Array:
-        """One kernel launch on explicit state (CWSParams or key words)."""
+    def _blocks_for(self, rows: int, dim: int) -> Tuple[int, int, int]:
+        """(bn, bk, bd) of one kernel launch over (rows, dim)."""
         fam = "cws_rng" if self.param_free else "cws"
         if self.spec.packed:
             fam += "_packed"
-        bn, bk, bd = self.blocks or registry.choose_blocks(
-            x.shape[0], x.shape[1], self.spec.num_hashes, op=fam)
+        return self.blocks or registry.choose_blocks(
+            rows, dim, self.spec.num_hashes, op=fam)
+
+    def loop_share(self, rows: int) -> float:
+        """Real D over the padded D, for a launch of ``rows`` rows on one
+        device: the share of the padded dimension loop that the kernel
+        runs (``cws_loop_share`` of the launch and fit set-up spans)."""
+        return loop_share(self.dim, self._blocks_for(rows, self.dim)[2])
+
+    def _launch_with(self, x: Array, state) -> Array:
+        """One kernel launch on explicit state (CWSParams or key words)."""
+        bn, bk, bd = self._blocks_for(x.shape[0], x.shape[1])
         if self.param_free:
             fn = registry.resolve(self._op_name(), self._resolved_impl()).fn
             return fn(x, state, self.spec.num_hashes, b_i=self.spec.b_i,

@@ -102,10 +102,14 @@ SETUP_SPAN = "repro.fit.setup"      # everything before the first step
 STEP_SPAN = "repro.fit.step"        # one step on the host, its save excluded
 
 
-def _shards(mesh) -> int:
-    """The setup span's ``shards``: devices the fit splits each batch
-    over (the mesh's ``data`` axis; 1 without a mesh)."""
-    return 1 if mesh is None else data_axis_size(mesh)
+def _setup_args(pipe: FeaturePipeline, cfg: TrainCfg, mesh) -> dict:
+    """The setup span's arguments: ``shards``, the devices the fit splits
+    each batch over (the mesh's ``data`` axis; 1 without a mesh), and
+    ``cws_loop_share``, the share of the padded dimension loop that the
+    kernel runs in each device's launch of a step."""
+    shards = 1 if mesh is None else data_axis_size(mesh)
+    return {"shards": shards, "cws_loop_share":
+            pipe.loop_share(max(cfg.batch_size // shards, 1))}
 
 
 __all__ = ["fit_linear_streamed", "resume_linear_streamed",
@@ -529,7 +533,8 @@ def fit_linear_streamed(params: LinearParams, pipe: FeaturePipeline,
     plan through the step path (tests).  ``return_state=True`` returns
     ``(params, opt_state)`` instead of params alone."""
     with jax.profiler.TraceAnnotation(FIT_SPAN):
-        with jax.profiler.TraceAnnotation(SETUP_SPAN, shards=_shards(mesh)):
+        with jax.profiler.TraceAnnotation(SETUP_SPAN,
+                                          **_setup_args(pipe, cfg, mesh)):
             validate_bag_features(params, pipe.num_features, spec=pipe.spec)
             S = _StreamSetup(pipe, x, labels, cfg, shuffle_key,
                              n_microbatches, mesh)
@@ -584,7 +589,8 @@ def resume_linear_streamed(ckpt, pipe: FeaturePipeline, x: Array,
     the checkpointed run — each mismatch raises loudly instead of
     resuming into silent garbage."""
     with jax.profiler.TraceAnnotation(FIT_SPAN):
-        with jax.profiler.TraceAnnotation(SETUP_SPAN, shards=_shards(mesh)):
+        with jax.profiler.TraceAnnotation(SETUP_SPAN,
+                                          **_setup_args(pipe, cfg, mesh)):
             ck = _as_checkpointer(ckpt, chaos)
             target = latest_step(ck.ckpt_dir) if step is None else step
             if target is None:

@@ -114,6 +114,34 @@ class TestPackedKernels:
                                             impl=impl)
             assert (got == want).all(), impl
 
+    # the last D step's loop: a tail of 1, of bd - 1, bd dividing D (no
+    # tail branch), and D under bd (bd clamps to D)
+    @pytest.mark.parametrize("d,bd", [(33, 16), (47, 16), (48, 16),
+                                      (12, 32)])
+    @pytest.mark.parametrize("regen", [False, True])
+    def test_tail_shapes_match_unpacked_oracle(self, d, bd, regen):
+        n, k, b_i = 9, 20, 8
+        x = rand_nonneg(jax.random.PRNGKey(d), (n, d))
+        # row 0 all zero (the sentinel), row 1 nonzero only in the last
+        # D block
+        last = -(-d // min(bd, d)) * min(bd, d) - min(bd, d)
+        x = x.at[0].set(0.0).at[1, :last].set(0.0).at[1, last:].add(0.5)
+        blocks = dict(b_i=b_i, bn=8, bk=8, bd=bd)
+        if regen:
+            key = jax.random.PRNGKey(5)
+            idx = ops.cws_encode_rng(x, key, k, impl="reference", **blocks)
+            got = ops.cws_encode_rng_packed(x, key, k,
+                                            impl="pallas-interpret", **blocks)
+        else:
+            params = make_cws_params(jax.random.PRNGKey(1), d, k)
+            idx = ops.cws_encode(x, params, impl="reference", **blocks)
+            got = ops.cws_encode_packed(x, params, impl="pallas-interpret",
+                                        **blocks)
+        codes = idx - jnp.arange(k, dtype=jnp.int32) * (1 << b_i)
+        want = hashing.pack_codes(codes, b=b_i)
+        np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+        assert (np.asarray(codes[0]) == 0).all()
+
     def test_all_zero_rows_pack_to_bucket_zero(self):
         n, d, k = 9, 16, 24
         x = rand_nonneg(jax.random.PRNGKey(3), (n, d)).at[4].set(0.0)

@@ -4,8 +4,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs.minmax_paper import CONFIG
 from repro.core.cws import make_cws_params, cws_hash as cws_hash_core
-from repro.kernels import ops
+from repro.kernels import cws_hash as ch
+from repro.kernels import ops, registry
 from repro.kernels.ref import cws_hash_ref, minmax_gram_ref, min_sum_ref
 
 
@@ -16,6 +18,29 @@ def rand_nonneg(key, shape, sparsity=0.4, dtype=jnp.float32):
     return (mag * mask).astype(dtype)
 
 
+def last_block(d, bd):
+    """First column of the last D block (``bd`` clamped to D)."""
+    bd = min(bd, d)
+    return -(-d // bd) * bd - bd
+
+
+def with_edge_rows(x, bd):
+    """x with row 0 all zero (the sentinel) and row 1 nonzero only in
+    its last D block, the one whose loop stops at the real D."""
+    last = last_block(x.shape[1], bd)
+    return x.at[0].set(0.0).at[1, :last].set(0.0).at[1, last:].add(0.5)
+
+
+# D against bd at the last D step: a tail of 1, a tail of bd - 1, bd
+# dividing D (no tail branch), and D under bd (bd clamps to D)
+TAIL_SHAPES = [
+    # (n, D, k, bn, bk, bd)
+    (9, 33, 20, 8, 8, 16),
+    (9, 47, 20, 8, 8, 16),
+    (9, 48, 20, 8, 8, 16),
+    (9, 12, 20, 8, 8, 32),
+]
+
 CWS_SHAPES = [
     # (n, D, k, bn, bk, bd)
     (4, 8, 4, 4, 4, 8),
@@ -24,18 +49,21 @@ CWS_SHAPES = [
     (7, 128, 64, 8, 32, 32),
     (64, 300, 33, 32, 16, 128),
     (128, 64, 128, 128, 128, 64),
-]
+] + TAIL_SHAPES
 
 
 class TestCWSPallas:
     @pytest.mark.parametrize("n,d,k,bn,bk,bd", CWS_SHAPES)
     def test_matches_oracle(self, n, d, k, bn, bk, bd):
         x = rand_nonneg(jax.random.PRNGKey(n * 1000 + d), (n, d))
+        x = with_edge_rows(x, bd)
         p = make_cws_params(jax.random.PRNGKey(d * 7 + k), d, k)
         i_ref, t_ref = cws_hash_ref(x, p.r, p.log_c, p.beta)
         i_pl, t_pl = ops.cws_hash(x, p, bn=bn, bk=bk, bd=bd, interpret=True)
         np.testing.assert_array_equal(np.asarray(i_ref), np.asarray(i_pl))
         np.testing.assert_array_equal(np.asarray(t_ref), np.asarray(t_pl))
+        assert (np.asarray(i_pl[0]) == -1).all()
+        assert (np.asarray(i_pl[1]) >= last_block(d, bd)).all()
 
     def test_matches_core_chunked(self):
         x = rand_nonneg(jax.random.PRNGKey(0), (40, 70))
@@ -69,6 +97,77 @@ class TestCWSPallas:
         i_ref, t_ref = cws_hash_ref(x, p.r, p.log_c, p.beta)
         i_pl, t_pl = ops.cws_hash(x, p, bn=4, bk=4, bd=8, interpret=True)
         np.testing.assert_array_equal(np.asarray(i_ref), np.asarray(i_pl))
+
+
+@pytest.mark.parametrize("d,bd,tail,share", [
+    (784, 512, 272, 0.765625),      # mnist-stored: 784 of 1,024
+    (254, 128, 126, 0.9921875),     # webspam-regen-packed: 254 of 256
+    (256, 256, 256, 1.0),           # minmax_paper: bd divides D
+    (256, 512, 256, 1.0),           # bd clamps to D
+    (33, 16, 1, 33 / 48),
+    (47, 16, 15, 47 / 48),
+    (1, 128, 1, 1.0),
+])
+def test_tail_trip_count_and_loop_share(d, bd, tail, share):
+    assert ch.tail_dims(d, bd) == tail
+    assert ch.loop_share(d, bd) == share
+
+
+def _loop_trips(jaxpr, trips=None, branches=0):
+    """(trip count of every loop, number of branches) in ``jaxpr`` and
+    the jaxprs inside it, the kernel body's included."""
+    trips = [] if trips is None else trips
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            trips.append(eqn.params["length"])
+        assert eqn.primitive.name != "while", "a loop with no static trips"
+        branches += eqn.primitive.name == "cond"
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    _, branches = _loop_trips(sub, trips, branches)
+    return trips, branches
+
+
+def _kernel_call(name, n, d, k, b):
+    """(wrapper, arg shapes) of one CWS kernel with choose_blocks'
+    blocks at (n, D, k)."""
+    fam = {"cws_hash": "cws", "cws_encode": "cws",
+           "cws_encode_packed": "cws_packed", "cws_hash_rng": "cws_rng",
+           "cws_encode_rng": "cws_rng",
+           "cws_encode_rng_packed": "cws_rng_packed"}[name]
+    bn, bk, bd = registry.choose_blocks(n, d, k, op=fam)
+    fn = getattr(ch, name + "_pallas")
+    blocks = dict(bn=bn, bk=bk, bd=bd)
+    if name != "cws_hash" and name != "cws_hash_rng":
+        blocks["b_i"] = b
+    x = jax.ShapeDtypeStruct((n, d), jnp.float32)
+    if "rng" in name:
+        return (lambda x, key: fn(x, key, k, **blocks),
+                [x, jax.ShapeDtypeStruct((2,), jnp.uint32)])
+    p = jax.ShapeDtypeStruct((d, k), jnp.float32)
+    return (lambda x, r, c, be: fn(x, r, c, be, **blocks)), [x, p, p, p]
+
+
+@pytest.mark.parametrize("d,trips", [
+    (CONFIG.dim, [256]),        # minmax_paper: bd = 256 divides D
+    (784, [512, 272]),          # mnist-stored: a tail of 272
+])
+@pytest.mark.parametrize("name", [
+    "cws_hash", "cws_encode", "cws_encode_packed", "cws_hash_rng",
+    "cws_encode_rng", "cws_encode_rng_packed",
+])
+def test_kernel_loops_over_the_real_dimensions(name, d, trips):
+    """Where bd divides D, each D step runs one loop of bd trips and the
+    body branches only for its init and emit, as before the tail; else
+    the last D step's loop runs the tail's trips under a branch of its
+    own."""
+    fn, shapes = _kernel_call(name, 512, d, CONFIG.num_hashes, CONFIG.b_i)
+    jaxpr = jax.make_jaxpr(fn)(*shapes).jaxpr
+    got, branches = _loop_trips(jaxpr)
+    assert got == trips
+    assert branches == 2 * len(trips)
 
 
 GRAM_SHAPES = [

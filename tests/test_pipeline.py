@@ -53,9 +53,19 @@ class TestFusedEncodeBitExact:
         (4, 8, 4, 4, 4, 8),
         (33, 50, 21, 8, 8, 16),     # non-divisible everywhere
         (7, 96, 33, 8, 16, 32),
+        # the last D step's loop: a tail of 1, of bd - 1, bd dividing D
+        # (no tail branch), and D under bd (bd clamps to D)
+        (9, 33, 20, 8, 8, 16),
+        (9, 47, 20, 8, 8, 16),
+        (9, 48, 20, 8, 8, 16),
+        (9, 12, 20, 8, 8, 32),
     ])
     def test_shapes_sweep(self, n, d, k, bn, bk, bd):
         x = rand_nonneg(jax.random.PRNGKey(n * 100 + d), (n, d))
+        # row 0 all zero (the sentinel), row 1 nonzero only in the last
+        # D block
+        last = -(-d // min(bd, d)) * min(bd, d) - min(bd, d)
+        x = x.at[0].set(0.0).at[1, :last].set(0.0).at[1, last:].add(0.5)
         p = make_cws_params(jax.random.PRNGKey(d + k), d, k)
         want = staged_oracle(x, p, b_i=4, b_t=1)
         got = ops.cws_encode(x, p, b_i=4, b_t=1, bn=bn, bk=bk, bd=bd,

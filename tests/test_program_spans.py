@@ -5,7 +5,9 @@ around the call, ``repro.fit.setup`` before the first step (carrying
 ``shards``, the devices each batch is split over) and one
 ``repro.fit.step`` per step (a step annotation carrying ``step_num``);
 ``FeaturePipeline.features`` records one ``repro.featurize.launch`` per
-chunk it launches, with the chunk's ``rows``.  Each test takes a profile
+chunk it launches, with the chunk's ``rows``.  The set-up and launch
+spans also carry ``cws_loop_share``, the real D over the padded D that
+the CWS kernel would otherwise loop over.  Each test takes a profile
 on the CPU with ``jax.profiler.trace`` and reads it back with
 ``jax.profiler.ProfileData``: the spans are there when a profile is being
 taken, and the outputs are the same bits whether one is or not.
@@ -176,6 +178,31 @@ def test_setup_carries_one_shard_without_a_mesh(problem, tmp_path, call):
     _, spans = profiled(tmp_path / "trace", work)
     (setup,) = named(spans, "repro.fit.setup")
     assert setup.args.get("shards") == 1
+
+
+@pytest.mark.parametrize("dim,share", [
+    (32, 1.0),          # choose_blocks' bd = 32 divides D
+    (50, 50 / 64),      # bd = 32: the last D step loops over 18 of 32
+])
+def test_setup_and_launches_carry_the_loop_share(tmp_path, dim, share):
+    pipe = FeaturePipeline.create(jax.random.PRNGKey(7), dim,
+                                  FeatureSpec(num_hashes=24, b_i=4),
+                                  row_chunk=ROW_CHUNK)
+    x = jax.random.uniform(jax.random.PRNGKey(0), (64, dim))
+    y = (x[:, 0] > x[:, 1]).astype(jnp.int32)
+    cfg = TrainCfg(n_classes=2, steps=2, batch_size=16)
+    p0 = init_bag(jax.random.PRNGKey(1), pipe.num_features, 2)
+    assert pipe.loop_share(cfg.batch_size) == share
+
+    def work():
+        return (fit_linear_streamed(p0, pipe, x, y, cfg=cfg),
+                pipe.features(x[:3 * ROW_CHUNK + 5]))
+
+    _, spans = profiled(tmp_path, work)
+    (setup,) = named(spans, "repro.fit.setup")
+    assert setup.args.get("cws_loop_share") == share
+    launches = named(spans, "repro.featurize.launch")
+    assert [s.args.get("cws_loop_share") for s in launches] == [share] * 4
 
 
 FOUR_DEVICE_SETUP = r"""

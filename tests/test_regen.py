@@ -33,6 +33,29 @@ def regen_staged_oracle(x, key, k, b_i, b_t):
 BI_GRID = (0, 1, 2, 4, 8)
 BT_GRID = (0, 1, 2)
 
+# D against bd at the last D step: a tail of 1, a tail of bd - 1, bd
+# dividing D (no tail branch), and D under bd (bd clamps to D)
+TAIL_SHAPES = [
+    # (n, D, k, bn, bk, bd)
+    (9, 33, 20, 8, 8, 16),
+    (9, 47, 20, 8, 8, 16),
+    (9, 48, 20, 8, 8, 16),
+    (9, 12, 20, 8, 8, 32),
+]
+
+
+def last_block(d, bd):
+    """First column of the last D block (``bd`` clamped to D)."""
+    bd = min(bd, d)
+    return -(-d // bd) * bd - bd
+
+
+def with_edge_rows(x, bd):
+    """x with row 0 all zero (the sentinel) and row 1 nonzero only in
+    its last D block, the one whose loop stops at the real D."""
+    last = last_block(x.shape[1], bd)
+    return x.at[0].set(0.0).at[1, :last].set(0.0).at[1, last:].add(0.5)
+
 
 class TestCounterSpec:
     def test_tile_decomposition_invariance(self):
@@ -89,16 +112,18 @@ class TestHashRngBitExact:
         (13, 22, 11, 4, 4, 8),      # non-divisible everywhere
         (33, 50, 21, 8, 8, 16),
         (7, 96, 33, 8, 16, 32),
-    ])
+    ] + TAIL_SHAPES)
     def test_kernel_matches_oracle(self, n, d, k, bn, bk, bd):
         x = rand_nonneg(jax.random.PRNGKey(n * 100 + d), (n, d))
         x = x.at[min(3, n - 1)].set(0.0)               # an all-zero row too
+        x = with_edge_rows(x, bd)
         key = jax.random.PRNGKey(d + k)
         want_i, want_t = cws_hash_regen(x, key, k)
         got_i, got_t = ops.cws_hash_rng(x, key, k, bn=bn, bk=bk, bd=bd,
                                         interpret=True)
         np.testing.assert_array_equal(np.asarray(want_i), np.asarray(got_i))
         np.testing.assert_array_equal(np.asarray(want_t), np.asarray(got_t))
+        assert (np.asarray(got_i[1]) >= last_block(d, bd)).all()
 
     def test_kernel_tile_invariance(self):
         """Different (bn, bk, bd) — different grid iteration order — must
@@ -123,6 +148,15 @@ class TestEncodeRngBitExact:
         want = regen_staged_oracle(x, key, k, b_i, b_t)
         got = ops.cws_encode_rng(x, key, k, b_i=b_i, b_t=b_t, bn=4, bk=4,
                                  bd=8, interpret=True)
+        np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+
+    @pytest.mark.parametrize("n,d,k,bn,bk,bd", TAIL_SHAPES)
+    def test_tail_shapes_match_counter_oracle(self, n, d, k, bn, bk, bd):
+        x = with_edge_rows(rand_nonneg(jax.random.PRNGKey(d), (n, d)), bd)
+        key = jax.random.PRNGKey(3)
+        want = regen_staged_oracle(x, key, k, 4, 1)
+        got = ops.cws_encode_rng(x, key, k, b_i=4, b_t=1, bn=bn, bk=bk,
+                                 bd=bd, interpret=True)
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
     def test_all_zero_rows_bucket0(self):

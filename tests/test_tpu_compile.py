@@ -3,7 +3,8 @@
 Interpret mode runs a kernel body on the CPU but never asks Mosaic (the
 TPU kernel compiler) whether it can lower it.  These tests do: each
 kernel is lowered for a described ``v5e:2x2`` topology (no chip
-attached) at the ``minmax_paper`` widths with ``choose_blocks``' blocks,
+attached) at the ``minmax_paper`` widths with ``choose_blocks``' blocks
+(and at the benchmark cells' D, which ``bd`` does not divide),
 compiled, and must come back holding a Mosaic kernel
 (``tpu_custom_call``).  A kernel Mosaic refuses fails here instead of on
 the chip.
@@ -65,25 +66,26 @@ def _compiled_text(fn, shapes, sharding) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _stored(n, fam):
-    bn, bk, bd = registry.choose_blocks(n, D, K, op=fam)
-    x, p = ((n, D), jnp.float32), ((D, K), jnp.float32)
+def _stored(n, fam, d=D):
+    bn, bk, bd = registry.choose_blocks(n, d, K, op=fam)
+    x, p = ((n, d), jnp.float32), ((d, K), jnp.float32)
     return (bn, bk, bd), [x, p, p, p]
 
 
-def _regen(n, fam):
-    bn, bk, bd = registry.choose_blocks(n, D, K, op=fam)
-    return (bn, bk, bd), [((n, D), jnp.float32), ((2,), jnp.uint32)]
+def _regen(n, fam, d=D):
+    bn, bk, bd = registry.choose_blocks(n, d, K, op=fam)
+    return (bn, bk, bd), [((n, d), jnp.float32), ((2,), jnp.uint32)]
 
 
-def _case(name, b=B_I, n=N):
-    """(fn, arg shapes) for one kernel family at the config widths."""
+def _case(name, b=B_I, n=N, d=D):
+    """(fn, arg shapes) for one kernel family at the config widths (D =
+    ``d`` where given)."""
     if name == "cws_hash":
         (bn, bk, bd), shapes = _stored(n, "cws")
         return (lambda x, r, c, be: ch.cws_hash_pallas(
             x, r, c, be, bn=bn, bk=bk, bd=bd)), shapes
     if name == "cws_encode":
-        (bn, bk, bd), shapes = _stored(n, "cws")
+        (bn, bk, bd), shapes = _stored(n, "cws", d)
         return (lambda x, r, c, be: ch.cws_encode_pallas(
             x, r, c, be, b_i=b, bn=bn, bk=bk, bd=bd)), shapes
     if name == "cws_encode_packed":
@@ -99,7 +101,7 @@ def _case(name, b=B_I, n=N):
         return (lambda x, key: ch.cws_encode_rng_pallas(
             x, key, K, b_i=b, bn=bn, bk=bk, bd=bd)), shapes
     if name == "cws_encode_rng_packed":
-        (bn, bk, bd), shapes = _regen(n, "cws_rng_packed")
+        (bn, bk, bd), shapes = _regen(n, "cws_rng_packed", d)
         return (lambda x, key: ch.cws_encode_rng_packed_pallas(
             x, key, K, b_i=b, bn=bn, bk=bk, bd=bd)), shapes
     if name == "min_sum":
@@ -119,6 +121,19 @@ def _case(name, b=B_I, n=N):
 ])
 def test_kernel_compiles_for_v5e(one_chip, name, b):
     fn, shapes = _case(name, b)
+    assert "tpu_custom_call" in _compiled_text(fn, shapes, one_chip)
+
+
+@pytest.mark.parametrize("name,n,d", [
+    ("cws_encode", N, 784),                 # mnist-stored: bd 512, tail 272
+    ("cws_encode_rng_packed", 8192, 254),   # webspam: bd 128, tail 126
+])
+def test_tail_loop_compiles_for_v5e_at_the_cells_widths(one_chip, name, n,
+                                                        d):
+    """At D that choose_blocks' bd does not divide, the last D step's
+    loop stops at the real D under a branch of its own; Mosaic must
+    lower it."""
+    fn, shapes = _case(name, 8, n=n, d=d)
     assert "tpu_custom_call" in _compiled_text(fn, shapes, one_chip)
 
 
