@@ -2,10 +2,10 @@
 this CPU container — the BlockSpec tiling is what ships to TPU), the
 chunked pure-JAX path, and the naive oracle. Also the regenerated-RNG
 variant (zero-parameter-traffic CWS, DESIGN.md §7) and the FUSED
-featurization pipeline (cws_encode) against its staged composition —
-emitted to BENCH_cws_fused.json, with the stored-vs-regen trajectory
-(wall-clock + modeled bytes moved; parameter input traffic is zero on the
-regen path) in BENCH_cws_regen.json.
+featurization pipeline (``ops.cws``, ``emit="index"``) against its staged
+composition — emitted to BENCH_cws_fused.json, with the stored-vs-regen
+trajectory (wall-clock + modeled bytes moved; parameter input traffic is
+zero on the regen path) in BENCH_cws_regen.json.
 
 Wall-times here are CPU numbers — meaningful relative to each other for
 the JAX paths; the interpret-mode Pallas time measures the interpreter,
@@ -73,8 +73,8 @@ def bench_fused_vs_staged(fast: bool) -> dict:
     n, d, k = 64, 128, 64
     x = rand_nonneg(jax.random.PRNGKey(3), (n, d))
     p = make_cws_params(jax.random.PRNGKey(4), d, k)
-    _, us = timed(lambda: ops.cws_encode(x, p, b_i=b_i, bn=64, bk=64,
-                                         bd=64, interpret=True), repeats=1)
+    _, us = timed(lambda: ops.cws(x, p, emit="index", b_i=b_i, bn=64, bk=64,
+                                  bd=64, interpret=True), repeats=1)
     emit("cws_fused/pallas_interpret(64x128x64)", us,
          "kernel-body correctness path")
     results["interpret_us_64x128x64"] = round(us, 1)
@@ -147,9 +147,11 @@ def bench_stored_vs_regen(fast: bool) -> dict:
     n, d, k = 64, 128, 64
     x = rand_nonneg(jax.random.PRNGKey(3), (n, d))
     key = jax.random.PRNGKey(12)
-    out_ref = ops.cws_encode_rng(x, key, k, b_i=b_i, impl="reference")
-    out_int, us = timed(lambda: ops.cws_encode_rng(
-        x, key, k, b_i=b_i, bn=64, bk=64, bd=64, interpret=True), repeats=1)
+    out_ref = ops.cws(x, key, emit="index", num_hashes=k, b_i=b_i,
+                      impl="reference")
+    out_int, us = timed(lambda: ops.cws(
+        x, key, emit="index", num_hashes=k, b_i=b_i, bn=64, bk=64, bd=64,
+        interpret=True), repeats=1)
     assert (out_int == out_ref).all(), "regen kernel != counter oracle"
     emit("cws_regen/pallas_interpret(64x128x64)", us,
          "kernel-body correctness path, bit-exact vs oracle")
@@ -177,8 +179,8 @@ def run(fast: bool = False):
     small = (64, 128, 64)
     xs = rand_nonneg(jax.random.PRNGKey(3), small[:2])
     ps = make_cws_params(jax.random.PRNGKey(4), small[1], small[2])
-    _, us = timed(lambda: ops.cws_hash(xs, ps, bn=64, bk=64, bd=64,
-                                       interpret=True), repeats=1)
+    _, us = timed(lambda: ops.cws(xs, ps, emit="hash", bn=64, bk=64, bd=64,
+                                  interpret=True), repeats=1)
     emit("cws/pallas_interpret(64x128x64)", us, "correctness-path only")
 
     bench_fused_vs_staged(fast)

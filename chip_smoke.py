@@ -73,8 +73,7 @@ GRAM_ROWS = 512
 FEATURE_MISMATCH_LIMIT = 1e-3         # share of (row, hash) entries
 ACCURACY_GAP_PP = 0.5
 KERNEL_MARKER = "tpu_custom_call"
-PALLAS_OPS = ("cws_encode", "cws_encode_rng", "cws_encode_packed",
-              "minmax_gram")
+PALLAS_OPS = ("cws", "minmax_gram")
 
 
 class SmokeFailure(RuntimeError):

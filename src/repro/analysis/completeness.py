@@ -3,10 +3,10 @@
 For every registered op:
 
   * an op with a ``pallas`` impl must also register ``pallas-interpret``
-    and ``reference`` (the correctness ladder the tests climb), carry a
-    `_FAMILY_ALIASES` entry resolving to a `_VMEM_MODELS` family, and
-    that family must register at least one LaunchProbe so the VMEM/
-    coverage checks can actually see its BlockSpecs;
+    and ``reference`` (the correctness ladder the tests climb), and every
+    block family it launches (``registry.launch_families``) must have a
+    `_VMEM_MODELS` entry and register at least one LaunchProbe so the
+    VMEM/coverage checks can actually see its BlockSpecs;
   * schedule families (ops dispatched by named schedule rather than
     backend, e.g. ``attention``) must register ``reference`` plus their
     expected schedule set;
@@ -38,12 +38,33 @@ def _signature_params(fn):
     return tuple((p.name, p.kind) for p in sig.parameters.values())
 
 
+def _family_findings(op: str, fam: str) -> List[Finding]:
+    """One block family that ``op`` launches: it needs a VMEM model and a
+    LaunchProbe."""
+    if not registry.has_vmem_model(fam):
+        return [Finding(
+            check="completeness", target=op,
+            message=(f"op {op!r} (family {fam!r}) has no "
+                     f"_VMEM_MODELS entry — choose_blocks/"
+                     f"block_candidates cannot budget its tiles; "
+                     f"add the model (and, for an op named apart "
+                     f"from its family, a _FAMILY_ALIASES entry) "
+                     f"in kernels/registry.py"))]
+    if not registry.family_probes(fam):
+        return [Finding(
+            check="completeness", target=op,
+            message=(f"family {fam!r} registers no LaunchProbe — "
+                     f"the VMEM/coverage audits cannot inspect its "
+                     f"BlockSpecs; add registry.register_probe"
+                     f"({fam!r}, op=...) in kernels/ops.py"))]
+    return []
+
+
 def audit_completeness(ops: Optional[Iterable[str]] = None
                        ) -> List[Finding]:
     findings: List[Finding] = []
     for op in (ops or registry.registered_ops()):
         impls = set(registry.impl_names(op))
-        fam = registry.family(op)
         if op in EXPECTED_SCHEDULES:
             missing = EXPECTED_SCHEDULES[op] - impls
             if missing:
@@ -62,21 +83,8 @@ def audit_completeness(ops: Optional[Iterable[str]] = None
                              f"{sorted(missing)} — every kernel op needs "
                              f"the interpret twin (kernel-parity tests) "
                              f"and the pure-JAX reference (the oracle)")))
-            if not registry.has_vmem_model(op):
-                findings.append(Finding(
-                    check="completeness", target=op,
-                    message=(f"op {op!r} (family {fam!r}) has no "
-                             f"_VMEM_MODELS entry — choose_blocks/"
-                             f"block_candidates cannot budget its tiles; "
-                             f"add the model and a _FAMILY_ALIASES entry "
-                             f"in kernels/registry.py")))
-            elif not registry.family_probes(fam):
-                findings.append(Finding(
-                    check="completeness", target=op,
-                    message=(f"family {fam!r} registers no LaunchProbe — "
-                             f"the VMEM/coverage audits cannot inspect its "
-                             f"BlockSpecs; add registry.register_probe"
-                             f"({fam!r}, op=...) in kernels/ops.py")))
+            for fam in registry.launch_families(op):
+                findings.extend(_family_findings(op, fam))
         elif "reference" not in impls:
             findings.append(Finding(
                 check="completeness", target=op,

@@ -138,7 +138,10 @@ def _launch_of_eqn(eqn) -> PallasLaunch:
             memory_space=_memory_space(getattr(aval, "memory_space", "vmem")),
             index_map=None,
         ))
-    name = str(eqn.params.get("name_and_src_info", "")) or "pallas_call"
+    # the kernel's name (``name`` in this JAX, ``name_and_src_info`` in
+    # older ones)
+    name = str(eqn.params.get("name") or
+               eqn.params.get("name_and_src_info", "")) or "pallas_call"
     return PallasLaunch(name=name.split(" ")[0], grid=grid,
                         inputs=inputs, outputs=outputs,
                         scratch=tuple(scratch))

@@ -145,6 +145,55 @@ def _sig_of(tree) -> list:
         for leaf in jax.tree_util.tree_leaves(tree)]
 
 
+def _trio_case(probe, case: tuple) -> List[Finding]:
+    """One case of a trio probe: every impl traced on ``build(*case)``,
+    their output signatures compared."""
+    findings: List[Finding] = []
+    args, kwargs = probe.build(*case)
+    where = f" (case {case})" if case else ""
+    sigs = {}
+    for impl_name in probe.impls:
+        try:
+            impl = registry.lookup(probe.op, impl_name)
+        except KeyError:
+            findings.append(Finding(
+                check="determinism", target=probe.op,
+                message=f"trio probe names impl {impl_name!r} but "
+                        f"the registry has no such impl for "
+                        f"{probe.op!r} — register it or fix the "
+                        f"probe's impls tuple",
+                details={"impl": impl_name}))
+            continue
+        try:
+            out = jax.eval_shape(
+                functools.partial(impl.fn, **kwargs), *args)
+        except Exception as e:  # trace failure is itself a finding
+            findings.append(Finding(
+                check="determinism", target=probe.op,
+                message=f"impl {impl_name!r} failed to trace on the "
+                        f"trio probe args{where}: {type(e).__name__}: {e}",
+                details={"impl": impl_name}))
+            continue
+        sigs[impl_name] = _sig_of(out)
+    if len(sigs) >= 2:
+        ref_name = probe.impls[0] if probe.impls[0] in sigs \
+            else sorted(sigs)[0]
+        ref = sigs[ref_name]
+        for impl_name, sig in sigs.items():
+            if sig != ref:
+                findings.append(Finding(
+                    check="determinism", target=probe.op,
+                    message=f"impl {impl_name!r} output signature "
+                            f"{sig} disagrees with {ref_name!r} "
+                            f"{ref}{where} — the trio must agree on "
+                            f"shape/dtype at the jaxpr level for "
+                            f"bit-identical parity to be possible",
+                    details={"impl": impl_name,
+                             "sig": [list(s) for s in sig],
+                             "ref": [list(s) for s in ref]}))
+    return findings
+
+
 def audit_trio_signatures(
         families: Optional[Iterable[str]] = None) -> List[Finding]:
     """Signature-agreement check across each registered impl trio."""
@@ -154,54 +203,16 @@ def audit_trio_signatures(
     def in_scope(op: str) -> bool:
         if fams is None:
             return True
-        return registry.family(op) in fams or op in fams
+        return bool(set(registry.launch_families(op)) & set(fams)) \
+            or op in fams
 
     probed = set()
     for probe in registry.trio_probes():
         probed.add(probe.op)
         if not in_scope(probe.op):
             continue
-        args, kwargs = probe.build()
-        sigs = {}
-        for impl_name in probe.impls:
-            try:
-                impl = registry.lookup(probe.op, impl_name)
-            except KeyError:
-                findings.append(Finding(
-                    check="determinism", target=probe.op,
-                    message=f"trio probe names impl {impl_name!r} but "
-                            f"the registry has no such impl for "
-                            f"{probe.op!r} — register it or fix the "
-                            f"probe's impls tuple",
-                    details={"impl": impl_name}))
-                continue
-            try:
-                out = jax.eval_shape(
-                    functools.partial(impl.fn, **kwargs), *args)
-            except Exception as e:  # trace failure is itself a finding
-                findings.append(Finding(
-                    check="determinism", target=probe.op,
-                    message=f"impl {impl_name!r} failed to trace on the "
-                            f"trio probe args: {type(e).__name__}: {e}",
-                    details={"impl": impl_name}))
-                continue
-            sigs[impl_name] = _sig_of(out)
-        if len(sigs) >= 2:
-            ref_name = probe.impls[0] if probe.impls[0] in sigs \
-                else sorted(sigs)[0]
-            ref = sigs[ref_name]
-            for impl_name, sig in sigs.items():
-                if sig != ref:
-                    findings.append(Finding(
-                        check="determinism", target=probe.op,
-                        message=f"impl {impl_name!r} output signature "
-                                f"{sig} disagrees with {ref_name!r} "
-                                f"{ref} — the trio must agree on "
-                                f"shape/dtype at the jaxpr level for "
-                                f"bit-identical parity to be possible",
-                        details={"impl": impl_name,
-                                 "sig": [list(s) for s in sig],
-                                 "ref": [list(s) for s in ref]}))
+        for case in probe.cases:
+            findings.extend(_trio_case(probe, case))
 
     for op in registry.registered_ops():
         if not in_scope(op):

@@ -60,6 +60,11 @@ def register_builtin_sites() -> None:
         importlib.import_module(mod)
 
 
+def _in_scope(op: str, fams) -> bool:
+    """Whether ``op`` is named or launches one of the families ``fams``."""
+    return op in fams or bool(set(registry.launch_families(op)) & set(fams))
+
+
 def _coverage_blocks(fam: str):
     """One ragged-tail block choice per family: the heuristic pick at the
     representative shape — small grids, so coverage enumerates fully."""
@@ -78,8 +83,7 @@ def run_suite(families: Optional[Iterable[str]] = None, *,
         found = audit_completeness()
         rep.extend(found)
         for op in registry.registered_ops():
-            if families and registry.family(op) not in fams \
-                    and op not in fams:
+            if families and not _in_scope(op, fams):
                 continue
             rep.mark(op, "completeness", found)
 
@@ -159,8 +163,7 @@ def run_suite(families: Optional[Iterable[str]] = None, *,
         found = audit_trio_signatures(families)
         rep.extend(found)
         for op in registry.registered_ops():
-            if families and registry.family(op) not in fams \
-                    and op not in fams:
+            if families and not _in_scope(op, fams):
                 continue
             rep.mark(op, "determinism", found)
 

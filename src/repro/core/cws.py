@@ -169,8 +169,8 @@ def cws_hash_regen(x: Array, key: Array, num_hashes: int, *,
     fly from the counter-based spec in :mod:`repro.core.regen` — the
     parameter working set is O(D * hash_block) and never round-trips HBM.
 
-    This is the ORACLE for the rng Pallas kernels
-    (``cws_hash_rng_pallas`` / ``cws_encode_rng_pallas``): both evaluate
+    This is the ORACLE for the CWS kernel on regenerated parameters
+    (``ops.cws`` on key words): both evaluate
     the same elementwise (key, d, k) -> params map, so (i*, t*) are
     bit-identical per the §3 contract, and the result is independent of
     ``hash_block``/``row_block`` (tile-order independence of the counter

@@ -298,7 +298,7 @@ def bag_logits_packed(params: LinearParams, packed: Array, *,
     """Embedding-bag logits straight from bit-packed features.
 
     packed: (n, ceil(num_hashes*b/32)) uint32 words as emitted by
-    FeaturePipeline(packed=True) / cws_encode_packed.  Unpacks in
+    FeaturePipeline(packed=True) / ops.cws(emit="packed").  Unpacks in
     registers (shift/mask — the packed words never round-trip through an
     int32 feature matrix), rebuilds the global indices
     ``j * 2^b + code_j``, and gathers the flat (num_hashes * 2^b, C)

@@ -7,7 +7,7 @@ ONE deterministic function
     (key, d, k)  ->  (r[d,k], log_c[d,k], beta[d,k])
 
 that every regenerated-parameter implementation — the Pallas kernel body
-(`kernels/cws_hash.py:cws_*_rng_pallas`), its interpret-mode run, and the
+(`kernels/cws_hash.py`, on key words), its interpret-mode run, and the
 pure-JAX oracle (`core/cws.py:cws_hash_regen`) — evaluates elementwise, so
 all three are bit-identical by construction and any tile decomposition of
 the (D, k) grid yields the same parameters (tile-order independence).

@@ -1,4 +1,4 @@
-"""Pallas TPU kernels for 0-bit/full CWS hashing and fused featurization.
+"""Pallas TPU kernel for 0-bit/full CWS hashing and fused featurization.
 
 Computes, for every (row, hash) pair, the argmin over dimensions of
 
@@ -19,33 +19,31 @@ TPU adaptation (vs the paper's per-vector CPU loop):
   * the kernel is VPU-bound (log/floor/mul on 8x128 lanes) and
     HBM-traffic-dominated by the 3 parameter matrices (DESIGN.md §2).
 
-Two emit variants share the accumulation loop:
-  * ``cws_hash_pallas``   — writes raw (i*, t*), two (n, k) int32 arrays;
-  * ``cws_encode_pallas`` — the FUSED featurization kernel: applies b_i/b_t
-    bit-masking, sentinel handling and the per-hash feature offset inside
-    the emit step and writes final embedding-bag indices, ONE (n, k) int32
-    array.  For the paper's 0-bit scheme (b_t = 0) this halves output
-    traffic (t* is never materialized — it is not even tracked in scratch)
-    and eliminates the separate encode + feature_indices passes.
+One kernel body (``_cws_kernel``) behind one wrapper (``cws_pallas``),
+with two static choices:
+  * the PARAMETER SOURCE, from the type of the launch state: stored
+    ``CWSParams`` matrices read from HBM, or two PRNG key words from which
+    each grid step regenerates its parameter tile in VMEM (DESIGN.md §7);
+  * the EMIT: ``"hash"`` writes raw (i*, t*), two (n, k) int32 arrays;
+    ``"index"`` applies b_i/b_t bit-masking, sentinel handling and the
+    per-hash feature offset inside the emit step and writes final
+    embedding-bag indices, ONE (n, k) int32 array — for the paper's 0-bit
+    scheme (b_t = 0) t* is not even tracked in scratch; ``"packed"`` packs
+    the b = b_i + b_t bit codes of each grid step's BK hashes into uint32
+    words in VMEM (b in {1, 2, 4, 8}, word-aligned per row, by exact
+    0/1-selection matmuls — no gathers) in the ``core.hashing.pack_codes``
+    layout, so output traffic drops from 4·BN·BK bytes per tile to
+    b/8·BN·BK.
 
 Zero entries (log u = -inf) never win the argmin; all-zero rows return the
-sentinel i* = -1 (matching repro.core.cws semantics), which the fused
-kernel maps to bucket 0 of its hash (matching core.hashing.feature_indices).
+sentinel i* = -1 (matching repro.core.cws semantics), which the encoding
+emits map to bucket 0 of their hash (matching
+core.hashing.feature_indices).
 
-PACKED emit variants (``cws_encode_packed_pallas`` /
-``cws_encode_rng_packed_pallas``) share the same accumulation loop and
-``_encode_emit`` body but pack the b = b_i + b_t bit codes of each grid
-step's BK hashes into uint32 words in VMEM (b in {1, 2, 4, 8},
-word-aligned per row, by exact 0/1-selection matmuls — no gathers):
-output traffic drops from 4·BN·BK bytes per tile to b/8·BN·BK.  Hash
-columns past the real k are zeroed before packing so pad bits are
-deterministic zeros, and the word layout matches
-``core.hashing.pack_codes`` bit-for-bit.
-
-Every ``pallas_call`` names its Mosaic kernel after the wrapper that
-launches it (``name="cws_encode_pallas"``, ...), not after the kernel
-body's Python function, so the kernel keeps its name in a profile while
-the bodies are refactored.
+Each (source, emit) pair names its Mosaic kernel from ``KERNEL_NAMES``
+(``cws_encode_pallas``, ``cws_encode_rng_packed_pallas``, ...), not after
+the kernel body's Python function, so a variant keeps its name in a
+profile while the body is refactored.
 """
 from __future__ import annotations
 
@@ -56,6 +54,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.cws import CWSParams
 from repro.core.hashing import check_packed_bits, packed_width
 from repro.core.regen import key_words, regen_tile
 
@@ -148,64 +147,6 @@ def _accumulate(lut_ref, r_ref, logc_ref, beta_ref, best, *, bd: int,
     pl.when(d_step == n_d_steps - 1)(lambda: run(tail))
 
 
-def _logu_scratch(bn, bd):
-    return pltpu.VMEM((bd, bn), jnp.float32)    # transposed log u tile
-
-
-def _cws_kernel(x_ref, r_ref, logc_ref, beta_ref, istar_ref, tstar_ref,
-                lut, best_a, best_i, best_t, *, bd: int, n_d_steps: int,
-                tail: int):
-    d_step = pl.program_id(2)
-
-    @pl.when(d_step == 0)
-    def _init():
-        best_a[...] = jnp.full_like(best_a[...], jnp.inf)
-        best_i[...] = jnp.full_like(best_i[...], NEG_SENTINEL)
-        best_t[...] = jnp.zeros_like(best_t[...])
-
-    _stage_logu(x_ref, lut)
-    _accumulate(lut, r_ref, logc_ref, beta_ref, (best_a, best_i, best_t),
-                bd=bd, n_d_steps=n_d_steps, tail=tail)
-
-    @pl.when(d_step == n_d_steps - 1)
-    def _emit():
-        istar_ref[...] = best_i[...]
-        tstar_ref[...] = jnp.clip(best_t[...], -2 ** 30, 2 ** 30).astype(jnp.int32)
-
-
-def _cws_encode_kernel(x_ref, r_ref, logc_ref, beta_ref, idx_ref, lut,
-                       *scratch,
-                       bd: int, n_d_steps: int, tail: int, b_i: int,
-                       b_t: int, bk: int, packed: bool = False,
-                       num_hashes: int = 0):
-    """Fused CWS -> b-bit code -> embedding-bag index.  ``scratch`` is
-    (best_a, best_i) for the 0-bit scheme (b_t == 0) and
-    (best_a, best_i, best_t) when t* bits are kept.  ``packed=True``
-    emits bit-packed uint32 words instead of int32 indices."""
-    d_step = pl.program_id(2)
-    hash_block = pl.program_id(1)
-    best_a, best_i = scratch[0], scratch[1]
-    best_t = scratch[2] if b_t else None
-
-    @pl.when(d_step == 0)
-    def _init():
-        best_a[...] = jnp.full_like(best_a[...], jnp.inf)
-        best_i[...] = jnp.full_like(best_i[...], NEG_SENTINEL)
-        if b_t:
-            best_t[...] = jnp.zeros_like(best_t[...])
-
-    _stage_logu(x_ref, lut)
-    _accumulate(lut, r_ref, logc_ref, beta_ref, scratch, bd=bd,
-                n_d_steps=n_d_steps, tail=tail)
-
-    @pl.when(d_step == n_d_steps - 1)
-    def _emit():
-        emitted = _encode_emit(best_i[...], best_t[...] if b_t else None,
-                               hash_block, bk, b_i, b_t,
-                               packed=packed, num_hashes=num_hashes)
-        idx_ref[...] = emitted.reshape(idx_ref.shape)
-
-
 def _pack_words(code, b):
     """(BN, BK) b-bit codes -> (BN, BK*b/32) uint32 words in the
     core.hashing.pack_codes layout: code column w*(32/b)+m lands in word
@@ -258,123 +199,6 @@ def _encode_emit(i, best_t, hash_block, bk, b_i, b_t, *, packed=False,
     return hash_id * width + code
 
 
-def _pad_operands(x, r, log_c, beta, bn, bk, bd):
-    n, d = x.shape
-    k = r.shape[1]
-    pad_n, pad_d, pad_k = (-n) % bn, (-d) % bd, (-k) % bk
-    # zero-padded x columns are masked by construction (log 0 = -inf);
-    # padded params are never selected for real columns, r=1 avoids div-0.
-    xp = jnp.pad(x.astype(jnp.float32), ((0, pad_n), (0, pad_d)))
-    rp = jnp.pad(r, ((0, pad_d), (0, pad_k)), constant_values=1.0)
-    lcp = jnp.pad(log_c, ((0, pad_d), (0, pad_k)))
-    bep = jnp.pad(beta, ((0, pad_d), (0, pad_k)))
-    return xp, rp, lcp, bep
-
-
-def _cws_specs(bn, bk, bd):
-    in_specs = [
-        pl.BlockSpec((bn, bd), lambda i, j, s: (i, s)),
-        pl.BlockSpec((bd, bk), lambda i, j, s: (s, j)),
-        pl.BlockSpec((bd, bk), lambda i, j, s: (s, j)),
-        pl.BlockSpec((bd, bk), lambda i, j, s: (s, j)),
-    ]
-    out_spec = pl.BlockSpec((bn, bk), lambda i, j, s: (i, j))
-    return in_specs, out_spec
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("bn", "bk", "bd", "interpret"))
-def cws_hash_pallas(x: jax.Array, r: jax.Array, log_c: jax.Array,
-                    beta: jax.Array, *, bn: int = 128, bk: int = 128,
-                    bd: int = 256, interpret: bool = False):
-    """x: (n, D) nonneg fp32; params (D, k) fp32 -> (i*, t*) each (n, k) i32."""
-    n, d = x.shape
-    k = r.shape[1]
-    bn, bk, bd = min(bn, n), min(bk, k), min(bd, d)
-    xp, rp, lcp, bep = _pad_operands(x, r, log_c, beta, bn, bk, bd)
-    np_, dp_, kp_ = xp.shape[0], xp.shape[1], rp.shape[1]
-    n_d_steps = dp_ // bd
-
-    in_specs, out_spec = _cws_specs(bn, bk, bd)
-    kernel = functools.partial(_cws_kernel, bd=bd, n_d_steps=n_d_steps,
-                               tail=tail_dims(d, bd))
-    i_star, t_star = pl.pallas_call(
-        kernel,
-        name="cws_hash_pallas",
-        grid=(np_ // bn, kp_ // bk, n_d_steps),
-        in_specs=in_specs,
-        out_specs=[out_spec, out_spec],
-        out_shape=[jax.ShapeDtypeStruct((np_, kp_), jnp.int32),
-                   jax.ShapeDtypeStruct((np_, kp_), jnp.int32)],
-        scratch_shapes=[
-            _logu_scratch(bn, bd),
-            pltpu.VMEM((bn, bk), jnp.float32),   # best log_a
-            pltpu.VMEM((bn, bk), jnp.int32),     # best index
-            pltpu.VMEM((bn, bk), jnp.float32),   # best t (cast on emit)
-        ],
-        interpret=interpret,
-    )(xp, rp, lcp, bep)
-    return i_star[:n, :k], t_star[:n, :k]
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("b_i", "b_t", "bn", "bk", "bd",
-                                    "interpret"))
-def cws_encode_pallas(x: jax.Array, r: jax.Array, log_c: jax.Array,
-                      beta: jax.Array, *, b_i: int, b_t: int = 0,
-                      bn: int = 128, bk: int = 128, bd: int = 256,
-                      interpret: bool = False) -> jax.Array:
-    """Fused featurization: x (n, D) nonneg -> embedding-bag indices
-    (n, k) int32 into the k * 2^{b_i+b_t} feature space.
-
-    Bit-exact vs ``feature_indices(encode(cws_hash(...)))`` but with a
-    single HBM output array and no (i*, t*) intermediates.
-    """
-    n, d = x.shape
-    k = r.shape[1]
-    bn, bk, bd = min(bn, n), min(bk, k), min(bd, d)
-    xp, rp, lcp, bep = _pad_operands(x, r, log_c, beta, bn, bk, bd)
-    np_, dp_, kp_ = xp.shape[0], xp.shape[1], rp.shape[1]
-    n_d_steps = dp_ // bd
-
-    scratch = [_logu_scratch(bn, bd),
-               pltpu.VMEM((bn, bk), jnp.float32),    # best log_a
-               pltpu.VMEM((bn, bk), jnp.int32)]      # best index
-    if b_t:
-        scratch.append(pltpu.VMEM((bn, bk), jnp.float32))   # best t
-
-    in_specs, out_spec = _cws_specs(bn, bk, bd)
-    kernel = functools.partial(_cws_encode_kernel, bd=bd,
-                               n_d_steps=n_d_steps, tail=tail_dims(d, bd),
-                               b_i=b_i, b_t=b_t, bk=bk)
-    idx = pl.pallas_call(
-        kernel,
-        name="cws_encode_pallas",
-        grid=(np_ // bn, kp_ // bk, n_d_steps),
-        in_specs=in_specs,
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((np_, kp_), jnp.int32),
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(xp, rp, lcp, bep)
-    return idx[:n, :k]
-
-
-# ---------------------------------------------------------------------------
-# zero-parameter-traffic variants: (r, log_c, beta) regenerated in-kernel
-# ---------------------------------------------------------------------------
-#
-# The three (D, k) parameter operands disappear; each grid step derives its
-# (BD, BK) parameter tile from the counter-based threefry spec
-# (repro.core.regen) keyed on the GLOBAL (d, hash) coordinates — so tiles
-# are order-independent and bit-identical to the `cws_hash_regen` oracle.
-# Input traffic per (row, hash) tile drops from 4·BN·BD + 12·BD·BK bytes
-# to 4·BN·BD (DESIGN.md §7); the price is ~3 threefry evaluations per
-# (d, hash) element per row-block sweep, regenerated into VMEM scratch at
-# every grid step (the scratch tile is reused as the accumulation loop's
-# parameter refs, so the VPU loop itself is unchanged).
-
-
 def _regen_step(key_ref, d_step, bd, bk, r_s, c_s, b_s):
     """Fill the (BD, BK) parameter scratch for this grid step from the
     counter stream at global offsets (d_step*BD, hash_block*BK)."""
@@ -385,180 +209,105 @@ def _regen_step(key_ref, d_step, bd, bk, r_s, c_s, b_s):
     b_s[...] = be
 
 
-def _cws_hash_rng_kernel(x_ref, key_ref, istar_ref, tstar_ref,
-                         lut, r_s, c_s, b_s, best_a, best_i, best_t,
-                         *, bd: int, n_d_steps: int, tail: int, bk: int):
+def _cws_kernel(*refs, source: str, emit: str, bd: int, bk: int,
+                n_d_steps: int, tail: int, b_i: int, b_t: int,
+                num_hashes: int):
+    """The one CWS kernel body.  ``refs`` are the inputs (x and the three
+    (BD, BK) parameter tiles for stored parameters; x and the SMEM key
+    words for regenerated ones), the outputs ((i*, t*) for ``"hash"``,
+    one array otherwise), then the scratch: the transposed log u tile,
+    the three regenerated parameter tiles (regen only) and the
+    accumulators (best_a, best_i[, best_t]).  best_t exists for
+    ``"hash"`` and wherever b_t keeps t* bits."""
+    n_in = 4 if source == "stored" else 2
+    n_out = 2 if emit == "hash" else 1
+    x_ref, outs = refs[0], refs[n_in:n_in + n_out]
+    lut, *scratch = refs[n_in + n_out:]
+    if source == "stored":
+        params, best = refs[1:4], tuple(scratch)
+    else:
+        params, best = tuple(scratch[:3]), tuple(scratch[3:])
     d_step = pl.program_id(2)
+    hash_block = None if emit == "hash" else pl.program_id(1)
 
     @pl.when(d_step == 0)
     def _init():
-        best_a[...] = jnp.full_like(best_a[...], jnp.inf)
-        best_i[...] = jnp.full_like(best_i[...], NEG_SENTINEL)
-        best_t[...] = jnp.zeros_like(best_t[...])
+        best[0][...] = jnp.full_like(best[0][...], jnp.inf)
+        best[1][...] = jnp.full_like(best[1][...], NEG_SENTINEL)
+        if len(best) == 3:
+            best[2][...] = jnp.zeros_like(best[2][...])
 
-    _regen_step(key_ref, d_step, bd, bk, r_s, c_s, b_s)
+    if source == "regen":
+        _regen_step(refs[1], d_step, bd, bk, *params)
     _stage_logu(x_ref, lut)
-    _accumulate(lut, r_s, c_s, b_s, (best_a, best_i, best_t),
-                bd=bd, n_d_steps=n_d_steps, tail=tail)
+    _accumulate(lut, *params, best, bd=bd, n_d_steps=n_d_steps, tail=tail)
 
     @pl.when(d_step == n_d_steps - 1)
     def _emit():
-        istar_ref[...] = best_i[...]
-        tstar_ref[...] = jnp.clip(best_t[...], -2 ** 30, 2 ** 30).astype(jnp.int32)
-
-
-def _cws_encode_rng_kernel(x_ref, key_ref, idx_ref, lut, r_s, c_s, b_s,
-                           *scratch,
-                           bd: int, n_d_steps: int, tail: int, b_i: int,
-                           b_t: int, bk: int, packed: bool = False,
-                           num_hashes: int = 0):
-    d_step = pl.program_id(2)
-    hash_block = pl.program_id(1)
-    best_a, best_i = scratch[0], scratch[1]
-    best_t = scratch[2] if b_t else None
-
-    @pl.when(d_step == 0)
-    def _init():
-        best_a[...] = jnp.full_like(best_a[...], jnp.inf)
-        best_i[...] = jnp.full_like(best_i[...], NEG_SENTINEL)
-        if b_t:
-            best_t[...] = jnp.zeros_like(best_t[...])
-
-    _regen_step(key_ref, d_step, bd, bk, r_s, c_s, b_s)
-    _stage_logu(x_ref, lut)
-    _accumulate(lut, r_s, c_s, b_s, scratch, bd=bd, n_d_steps=n_d_steps,
-                tail=tail)
-
-    @pl.when(d_step == n_d_steps - 1)
-    def _emit():
-        emitted = _encode_emit(best_i[...], best_t[...] if b_t else None,
+        if emit == "hash":
+            outs[0][...] = best[1][...]
+            outs[1][...] = jnp.clip(best[2][...], -2 ** 30,
+                                    2 ** 30).astype(jnp.int32)
+            return
+        emitted = _encode_emit(best[1][...], best[2][...] if b_t else None,
                                hash_block, bk, b_i, b_t,
-                               packed=packed, num_hashes=num_hashes)
-        idx_ref[...] = emitted.reshape(idx_ref.shape)
-
-
-def _rng_setup(x, num_hashes, bn, bk, bd):
-    """Pad x, size the padded (n, k) output grid, build the rng in_specs
-    (x tile + whole-key in SMEM)."""
-    n, d = x.shape
-    bn, bk, bd = min(bn, n), min(bk, num_hashes), min(bd, d)
-    pad_n, pad_d = (-n) % bn, (-d) % bd
-    xp = jnp.pad(x.astype(jnp.float32), ((0, pad_n), (0, pad_d)))
-    kp_ = num_hashes + ((-num_hashes) % bk)
-    in_specs = [
-        pl.BlockSpec((bn, bd), lambda i, j, s: (i, s)),
-        pl.BlockSpec(memory_space=pltpu.SMEM),
-    ]
-    out_spec = pl.BlockSpec((bn, bk), lambda i, j, s: (i, j))
-    return xp, kp_, bn, bk, bd, in_specs, out_spec
-
-
-def _param_scratch(bd, bk):
-    return [pltpu.VMEM((bd, bk), jnp.float32),   # regenerated r
-            pltpu.VMEM((bd, bk), jnp.float32),   # regenerated log_c
-            pltpu.VMEM((bd, bk), jnp.float32)]   # regenerated beta
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("num_hashes", "bn", "bk", "bd",
-                                    "interpret"))
-def cws_hash_rng_pallas(x: jax.Array, key: jax.Array, num_hashes: int, *,
-                        bn: int = 128, bk: int = 128, bd: int = 256,
-                        interpret: bool = False):
-    """Zero-parameter-traffic CWS: x (n, D) nonneg + PRNG key ->
-    (i*, t*) each (n, num_hashes) int32.  Bit-identical to
-    ``cws_hash_regen(x, key, num_hashes)``."""
-    n, d = x.shape
-    k0, k1 = key_words(key)
-    kw = jnp.stack([k0, k1])
-    xp, kp_, bn, bk, bd, in_specs, out_spec = _rng_setup(
-        x, num_hashes, bn, bk, bd)
-    np_, dp_ = xp.shape
-    n_d_steps = dp_ // bd
-
-    kernel = functools.partial(_cws_hash_rng_kernel, bd=bd,
-                               n_d_steps=n_d_steps, tail=tail_dims(d, bd),
-                               bk=bk)
-    i_star, t_star = pl.pallas_call(
-        kernel,
-        name="cws_hash_rng_pallas",
-        grid=(np_ // bn, kp_ // bk, n_d_steps),
-        in_specs=in_specs,
-        out_specs=[out_spec, out_spec],
-        out_shape=[jax.ShapeDtypeStruct((np_, kp_), jnp.int32),
-                   jax.ShapeDtypeStruct((np_, kp_), jnp.int32)],
-        scratch_shapes=[_logu_scratch(bn, bd)] + _param_scratch(bd, bk) + [
-            pltpu.VMEM((bn, bk), jnp.float32),   # best log_a
-            pltpu.VMEM((bn, bk), jnp.int32),     # best index
-            pltpu.VMEM((bn, bk), jnp.float32),   # best t (cast on emit)
-        ],
-        interpret=interpret,
-    )(xp, kw)
-    return i_star[:n, :num_hashes], t_star[:n, :num_hashes]
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("num_hashes", "b_i", "b_t", "bn", "bk",
-                                    "bd", "interpret"))
-def cws_encode_rng_pallas(x: jax.Array, key: jax.Array, num_hashes: int, *,
-                          b_i: int, b_t: int = 0, bn: int = 128,
-                          bk: int = 128, bd: int = 256,
-                          interpret: bool = False) -> jax.Array:
-    """Fused zero-parameter-traffic featurization: x (n, D) nonneg + PRNG
-    key -> embedding-bag indices (n, num_hashes) int32 into the
-    num_hashes * 2^{b_i+b_t} feature space.
-
-    Bit-exact vs ``feature_indices(encode(cws_hash_regen(...)))`` with a
-    single HBM output array, no (i*, t*) intermediates, and NO parameter
-    operands at all — the only HBM input is x.
-    """
-    n, d = x.shape
-    k0, k1 = key_words(key)
-    kw = jnp.stack([k0, k1])
-    xp, kp_, bn, bk, bd, in_specs, out_spec = _rng_setup(
-        x, num_hashes, bn, bk, bd)
-    np_, dp_ = xp.shape
-    n_d_steps = dp_ // bd
-
-    scratch = [_logu_scratch(bn, bd)] + _param_scratch(bd, bk) + [
-        pltpu.VMEM((bn, bk), jnp.float32),       # best log_a
-        pltpu.VMEM((bn, bk), jnp.int32)]         # best index
-    if b_t:
-        scratch.append(pltpu.VMEM((bn, bk), jnp.float32))    # best t
-
-    kernel = functools.partial(_cws_encode_rng_kernel, bd=bd,
-                               n_d_steps=n_d_steps, tail=tail_dims(d, bd),
-                               b_i=b_i, b_t=b_t, bk=bk)
-    idx = pl.pallas_call(
-        kernel,
-        name="cws_encode_rng_pallas",
-        grid=(np_ // bn, kp_ // bk, n_d_steps),
-        in_specs=in_specs,
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((np_, kp_), jnp.int32),
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(xp, kw)
-    return idx[:n, :num_hashes]
+                               packed=emit == "packed",
+                               num_hashes=num_hashes)
+        outs[0][...] = emitted.reshape(outs[0].shape)
 
 
 # ---------------------------------------------------------------------------
-# bit-packed emit variants: b = b_i + b_t bit codes -> uint32 words
+# the one wrapper: pad, grid, blocks, scratch, output, slice
 # ---------------------------------------------------------------------------
 #
-# Same grid, same accumulation loop, same scratch as the unpacked encode
-# kernels — only the emit differs: per (BN, BK) tile the codes pack into
-# (BN, BK·b/32) uint32 words in VMEM before the single HBM write, so
-# output traffic drops 32/b x.  BK is legalized to a multiple of the
-# 32/b codes-per-word so every grid step owns whole words, and pad hash
-# columns (>= num_hashes) zero their bits (they share words with real
-# codes at ragged k·b).  The row dimension needs no care: rows pack
-# independently (word-aligned), pad rows slice off as usual.
+# Stored parameters arrive as three (D, k) operands tiled (BD, BK) next to
+# the (BN, BD) x tile.  Regenerated ones arrive as two uint32 key words in
+# SMEM; each grid step derives its (BD, BK) parameter tile from the
+# counter-based threefry spec (repro.core.regen) keyed on the GLOBAL
+# (d, hash) coordinates — so tiles are order-independent and bit-identical
+# to the `cws_hash_regen` oracle.  Input traffic per (row, hash) tile
+# drops from 4·BN·BD + 12·BD·BK bytes to 4·BN·BD (DESIGN.md §7); the price
+# is ~3 threefry evaluations per (d, hash) element per row-block sweep.
 #
-# The output is 3-D, (hash blocks, rows, BK·b/32), with block
-# (1, BN, BK·b/32): Mosaic needs a block's last dim to be a multiple of
-# 128 or the whole array dim, and BK·b/32 words is neither in 2-D.  The
-# wrapper puts each row's hash blocks back side by side.
+# The packed emit writes, per (BN, BK) tile, (BN, BK·b/32) uint32 words:
+# output traffic drops 32/b x.  BK is legalized to a multiple of the 32/b
+# codes-per-word so every grid step owns whole words, and pad hash columns
+# (>= k) zero their bits (they share words with real codes at ragged k·b).
+# Its output is 3-D, (hash blocks, rows, BK·b/32), with block
+# (1, BN, BK·b/32): Mosaic needs a block's last dim to be a multiple of 128
+# or the whole array dim, and BK·b/32 words is neither in 2-D.  The wrapper
+# puts each row's hash blocks back side by side.
+
+# (parameter source, emit) -> the Mosaic kernel's name.  Profiles and
+# their readers match these names; a new variant adds its rows here, one
+# branch in _cws_kernel and one block family (registry.cws_family).
+KERNEL_NAMES = {
+    ("stored", "hash"): "cws_hash_pallas",
+    ("stored", "index"): "cws_encode_pallas",
+    ("stored", "packed"): "cws_encode_packed_pallas",
+    ("regen", "hash"): "cws_hash_rng_pallas",
+    ("regen", "index"): "cws_encode_rng_pallas",
+    ("regen", "packed"): "cws_encode_rng_packed_pallas",
+}
+
+
+def param_source(state) -> str:
+    """Where a launch's parameters come from, by the type of its state:
+    ``"stored"`` for ``CWSParams``, ``"regen"`` for PRNG key words."""
+    return "stored" if isinstance(state, CWSParams) else "regen"
+
+
+def hash_count(state, num_hashes: int | None) -> int:
+    """k of a launch: stored parameters carry theirs; regenerated ones
+    take ``num_hashes``."""
+    if isinstance(state, CWSParams):
+        if num_hashes not in (None, state.num_hashes):
+            raise ValueError(f"num_hashes={num_hashes} but the stored "
+                             f"parameters carry {state.num_hashes} hashes")
+        return state.num_hashes
+    if num_hashes is None:
+        raise ValueError("regenerated parameters need num_hashes")
+    return num_hashes
 
 
 def _packed_out(np_, kp_, bn, bk, b):
@@ -577,95 +326,87 @@ def _row_words(blocks, n, k, b):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("b_i", "b_t", "bn", "bk", "bd",
-                                    "interpret"))
-def cws_encode_packed_pallas(x: jax.Array, r: jax.Array, log_c: jax.Array,
-                             beta: jax.Array, *, b_i: int, b_t: int = 0,
-                             bn: int = 128, bk: int = 128, bd: int = 256,
-                             interpret: bool = False) -> jax.Array:
-    """Fused featurization with bit-packed output: x (n, D) nonneg ->
-    (n, ceil(k·b/32)) uint32 words, b = b_i + b_t in {1, 2, 4, 8}.
+                   static_argnames=("num_hashes", "emit", "b_i", "b_t", "bn",
+                                    "bk", "bd", "interpret"))
+def cws_pallas(x: jax.Array, state, num_hashes: int | None = None, *,
+               emit: str, b_i: int = 0, b_t: int = 0, bn: int = 128,
+               bk: int = 128, bd: int = 256, interpret: bool = False):
+    """CWS of x (n, D) nonneg, one kernel launch.
 
-    Bit-exact vs ``pack_codes(encode(cws_hash(...)))``: word w of a row
-    holds codes [w·32/b, (w+1)·32/b) at bit offsets (j mod 32/b)·b, and
-    ``core.hashing.unpack_codes`` recovers the unpacked codes exactly.
+    ``state`` is stored ``CWSParams`` ((D, k) matrices) or regenerated
+    PRNG key words (then ``num_hashes`` gives k).  ``emit`` says what
+    comes back:
+
+      * ``"hash"``   — (i*, t*), each (n, k) int32; bit-identical to
+        ``cws_hash`` / ``cws_hash_regen``;
+      * ``"index"``  — embedding-bag indices (n, k) int32 into the
+        k·2^{b_i+b_t} feature space: ``feature_indices(encode(...))``
+        with one HBM output and no (i*, t*) intermediates;
+      * ``"packed"`` — (n, ceil(k·b/32)) uint32 words, b = b_i + b_t in
+        {1, 2, 4, 8}: ``pack_codes(encode(...))`` bit for bit.
     """
+    source = param_source(state)
+    name = KERNEL_NAMES[(source, emit)]
     n, d = x.shape
-    k = r.shape[1]
+    k = hash_count(state, num_hashes)
     b = b_i + b_t
     bn, bd = min(bn, n), min(bd, d)
-    bk = _packed_bk(bk, k, b)
-    xp, rp, lcp, bep = _pad_operands(x, r, log_c, beta, bn, bk, bd)
-    np_, dp_, kp_ = xp.shape[0], xp.shape[1], rp.shape[1]
+    bk = _packed_bk(bk, k, b) if emit == "packed" else min(bk, k)
+    pad_n, pad_d, pad_k = (-n) % bn, (-d) % bd, (-k) % bk
+    np_, dp_, kp_ = n + pad_n, d + pad_d, k + pad_k
     n_d_steps = dp_ // bd
 
-    scratch = [_logu_scratch(bn, bd),
-               pltpu.VMEM((bn, bk), jnp.float32),    # best log_a
-               pltpu.VMEM((bn, bk), jnp.int32)]      # best index
-    if b_t:
+    x_spec = pl.BlockSpec((bn, bd), lambda i, j, s: (i, s))
+    scratch = [pltpu.VMEM((bd, bn), jnp.float32)]   # transposed log u
+    if source == "regen":
+        k0, k1 = key_words(state)
+        operands = [jnp.stack([k0, k1])]
+        in_specs = [x_spec, pl.BlockSpec(memory_space=pltpu.SMEM)]
+        scratch += [pltpu.VMEM((bd, bk), jnp.float32)] * 3  # r, log_c, beta
+    # zero-padded x columns are masked by construction (log 0 = -inf);
+    # padded params are never selected for real columns, r=1 avoids div-0.
+    xp = jnp.pad(x.astype(jnp.float32), ((0, pad_n), (0, pad_d)))
+    if source == "stored":
+        operands = [
+            jnp.pad(state.r, ((0, pad_d), (0, pad_k)), constant_values=1.0),
+            jnp.pad(state.log_c, ((0, pad_d), (0, pad_k))),
+            jnp.pad(state.beta, ((0, pad_d), (0, pad_k)))]
+        in_specs = [x_spec] + [pl.BlockSpec((bd, bk), lambda i, j, s: (s, j))
+                               ] * 3
+    scratch += [pltpu.VMEM((bn, bk), jnp.float32),     # best log_a
+                pltpu.VMEM((bn, bk), jnp.int32)]       # best index
+    if emit == "hash" or b_t:
         scratch.append(pltpu.VMEM((bn, bk), jnp.float32))   # best t
 
-    in_specs, _ = _cws_specs(bn, bk, bd)
-    out_spec, out_shape = _packed_out(np_, kp_, bn, bk, b)
-    kernel = functools.partial(_cws_encode_kernel, bd=bd,
-                               n_d_steps=n_d_steps, tail=tail_dims(d, bd),
-                               b_i=b_i, b_t=b_t, bk=bk, packed=True,
+    tile = pl.BlockSpec((bn, bk), lambda i, j, s: (i, j))
+    if emit == "hash":
+        out_specs = [tile, tile]
+        out_shape = [jax.ShapeDtypeStruct((np_, kp_), jnp.int32)] * 2
+    elif emit == "index":
+        out_specs, out_shape = tile, jax.ShapeDtypeStruct((np_, kp_),
+                                                          jnp.int32)
+    else:
+        out_specs, out_shape = _packed_out(np_, kp_, bn, bk, b)
+
+    kernel = functools.partial(_cws_kernel, source=source, emit=emit, bd=bd,
+                               bk=bk, n_d_steps=n_d_steps,
+                               tail=tail_dims(d, bd), b_i=b_i, b_t=b_t,
                                num_hashes=k)
-    words = pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
-        name="cws_encode_packed_pallas",
+        name=name,
         grid=(np_ // bn, kp_ // bk, n_d_steps),
         in_specs=in_specs,
-        out_specs=out_spec,
+        out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
-    )(xp, rp, lcp, bep)
-    return _row_words(words, n, k, b)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("num_hashes", "b_i", "b_t", "bn", "bk",
-                                    "bd", "interpret"))
-def cws_encode_rng_packed_pallas(x: jax.Array, key: jax.Array,
-                                 num_hashes: int, *, b_i: int, b_t: int = 0,
-                                 bn: int = 128, bk: int = 128, bd: int = 256,
-                                 interpret: bool = False) -> jax.Array:
-    """Zero-parameter-traffic fused featurization with bit-packed output:
-    x (n, D) nonneg + PRNG key -> (n, ceil(num_hashes·b/32)) uint32.  The
-    only HBM input is x and the only HBM output is the packed words."""
-    n, d = x.shape
-    b = b_i + b_t
-    k0, k1 = key_words(key)
-    kw = jnp.stack([k0, k1])
-    bk = _packed_bk(bk, num_hashes, b)
-    xp, kp_, bn, bk, bd, in_specs, _ = _rng_setup(
-        x, num_hashes + ((-num_hashes) % bk), bn, bk, bd)
-    np_, dp_ = xp.shape
-    n_d_steps = dp_ // bd
-
-    scratch = [_logu_scratch(bn, bd)] + _param_scratch(bd, bk) + [
-        pltpu.VMEM((bn, bk), jnp.float32),       # best log_a
-        pltpu.VMEM((bn, bk), jnp.int32)]         # best index
-    if b_t:
-        scratch.append(pltpu.VMEM((bn, bk), jnp.float32))    # best t
-
-    out_spec, out_shape = _packed_out(np_, kp_, bn, bk, b)
-    kernel = functools.partial(_cws_encode_rng_kernel, bd=bd,
-                               n_d_steps=n_d_steps, tail=tail_dims(d, bd),
-                               b_i=b_i, b_t=b_t, bk=bk, packed=True,
-                               num_hashes=num_hashes)
-    words = pl.pallas_call(
-        kernel,
-        name="cws_encode_rng_packed_pallas",
-        grid=(np_ // bn, kp_ // bk, n_d_steps),
-        in_specs=in_specs,
-        out_specs=out_spec,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(xp, kw)
-    return _row_words(words, n, num_hashes, b)
+    )(xp, *operands)
+    if emit == "hash":
+        return out[0][:n, :k], out[1][:n, :k]
+    if emit == "index":
+        return out[:n, :k]
+    return _row_words(out, n, k, b)
 
 
 # ---------------------------------------------------------------------------

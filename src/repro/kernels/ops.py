@@ -12,6 +12,8 @@ explicit ``bn/bk/bd`` kwargs still pin them for tests and sweeps.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -20,16 +22,12 @@ from repro.core import cws as core_cws
 from repro.core import hashing as core_hashing
 from repro.kernels import ref
 from repro.kernels import registry
-from repro.kernels.cws_hash import (cws_hash_pallas, cws_encode_pallas,
-                                    cws_hash_rng_pallas,
-                                    cws_encode_rng_pallas,
-                                    cws_encode_packed_pallas,
-                                    cws_encode_rng_packed_pallas,
+from repro.kernels.cws_hash import (cws_pallas, hash_count, param_source,
                                     _packed_bk)
 from repro.kernels.minmax_gram import minmax_gram_pallas, min_sum_pallas
 
 
-def _blocks(n: int, d: int, k: int, bn, bk, bd, op: str = "cws"):
+def _blocks(n: int, d: int, k: int, bn, bk, bd, *, op: str):
     hn, hk, hd = registry.choose_blocks(n, d, k, op=op)
     return (bn or hn, bk or hk, bd or hd)
 
@@ -38,141 +36,37 @@ def _blocks(n: int, d: int, k: int, bn, bk, bd, op: str = "cws"):
 # implementation registration
 # ---------------------------------------------------------------------------
 
-@registry.register("cws_hash", "pallas", requires=("tpu",))
-def _cws_hash_tpu(x, params: CWSParams, *, bn, bk, bd):
-    return cws_hash_pallas(x, params.r, params.log_c, params.beta,
-                           bn=bn, bk=bk, bd=bd, interpret=False)
+@registry.register("cws", "pallas", requires=("tpu",))
+def _cws_tpu(x, state, *, emit, num_hashes, b_i, b_t, bn, bk, bd):
+    return cws_pallas(x, state, num_hashes, emit=emit, b_i=b_i, b_t=b_t,
+                      bn=bn, bk=bk, bd=bd, interpret=False)
 
 
-@registry.register("cws_hash", "pallas-interpret")
-def _cws_hash_interp(x, params: CWSParams, *, bn, bk, bd):
-    return cws_hash_pallas(x, params.r, params.log_c, params.beta,
-                           bn=bn, bk=bk, bd=bd, interpret=True)
+@registry.register("cws", "pallas-interpret")
+def _cws_interp(x, state, *, emit, num_hashes, b_i, b_t, bn, bk, bd):
+    return cws_pallas(x, state, num_hashes, emit=emit, b_i=b_i, b_t=b_t,
+                      bn=bn, bk=bk, bd=bd, interpret=True)
 
 
-@registry.register("cws_hash", "reference")
-def _cws_hash_ref(x, params: CWSParams, *, bn, bk, bd):
-    # chunked pure-JAX path; block kwargs map onto its chunk sizes
-    return core_cws.cws_hash(x, params, row_block=max(bn, 8),
-                             hash_block=max(bk, 8))
-
-
-@registry.register("cws_encode", "pallas", requires=("tpu",))
-def _cws_encode_tpu(x, params: CWSParams, *, b_i, b_t, bn, bk, bd):
-    return cws_encode_pallas(x, params.r, params.log_c, params.beta,
-                             b_i=b_i, b_t=b_t, bn=bn, bk=bk, bd=bd,
-                             interpret=False)
-
-
-@registry.register("cws_encode", "pallas-interpret")
-def _cws_encode_interp(x, params: CWSParams, *, b_i, b_t, bn, bk, bd):
-    return cws_encode_pallas(x, params.r, params.log_c, params.beta,
-                             b_i=b_i, b_t=b_t, bn=bn, bk=bk, bd=bd,
-                             interpret=True)
-
-
-@registry.register("cws_encode", "reference")
-def _cws_encode_ref(x, params: CWSParams, *, b_i, b_t, bn, bk, bd):
-    # the staged composition, kept in ONE place as the semantic definition
-    i_star, t_star = _cws_hash_ref(x, params, bn=bn, bk=bk, bd=bd)
+@registry.register("cws", "reference")
+def _cws_ref(x, state, *, emit, num_hashes, b_i, b_t, bn, bk, bd):
+    # the staged composition, kept in ONE place as the semantic
+    # definition: the chunked pure-JAX hash (block kwargs map onto its
+    # chunk sizes; regenerated parameters follow the counter spec of
+    # repro.core.regen, bit-identical to the kernel's, DESIGN.md §7),
+    # then the b-bit encode, then offsets or packing
+    chunks = dict(row_block=max(bn, 8), hash_block=max(bk, 8))
+    if param_source(state) == "stored":
+        i_star, t_star = core_cws.cws_hash(x, state, **chunks)
+    else:
+        i_star, t_star = core_cws.cws_hash_regen(x, state, num_hashes,
+                                                 **chunks)
+    if emit == "hash":
+        return i_star, t_star
     codes = core_hashing.encode(i_star, t_star, b_i=b_i, b_t=b_t)
+    if emit == "packed":
+        return core_hashing.pack_codes(codes, b=b_i + b_t)
     return core_hashing.feature_indices(codes, b_i=b_i, b_t=b_t)
-
-
-# --- zero-parameter-traffic (regenerated-RNG) featurization family -------
-#
-# State is a PRNG key instead of CWSParams: every impl derives (r, log_c,
-# beta) from the counter spec in repro.core.regen, so all three are
-# bit-identical (DESIGN.md §7).
-
-@registry.register("cws_hash_rng", "pallas", requires=("tpu",))
-def _cws_hash_rng_tpu(x, key, num_hashes, *, bn, bk, bd):
-    return cws_hash_rng_pallas(x, key, num_hashes, bn=bn, bk=bk, bd=bd,
-                               interpret=False)
-
-
-@registry.register("cws_hash_rng", "pallas-interpret")
-def _cws_hash_rng_interp(x, key, num_hashes, *, bn, bk, bd):
-    return cws_hash_rng_pallas(x, key, num_hashes, bn=bn, bk=bk, bd=bd,
-                               interpret=True)
-
-
-@registry.register("cws_hash_rng", "reference")
-def _cws_hash_rng_ref(x, key, num_hashes, *, bn, bk, bd):
-    return core_cws.cws_hash_regen(x, key, num_hashes, row_block=max(bn, 8),
-                                   hash_block=max(bk, 8))
-
-
-@registry.register("cws_encode_rng", "pallas", requires=("tpu",))
-def _cws_encode_rng_tpu(x, key, num_hashes, *, b_i, b_t, bn, bk, bd):
-    return cws_encode_rng_pallas(x, key, num_hashes, b_i=b_i, b_t=b_t,
-                                 bn=bn, bk=bk, bd=bd, interpret=False)
-
-
-@registry.register("cws_encode_rng", "pallas-interpret")
-def _cws_encode_rng_interp(x, key, num_hashes, *, b_i, b_t, bn, bk, bd):
-    return cws_encode_rng_pallas(x, key, num_hashes, b_i=b_i, b_t=b_t,
-                                 bn=bn, bk=bk, bd=bd, interpret=True)
-
-
-@registry.register("cws_encode_rng", "reference")
-def _cws_encode_rng_ref(x, key, num_hashes, *, b_i, b_t, bn, bk, bd):
-    i_star, t_star = _cws_hash_rng_ref(x, key, num_hashes, bn=bn, bk=bk,
-                                       bd=bd)
-    codes = core_hashing.encode(i_star, t_star, b_i=b_i, b_t=b_t)
-    return core_hashing.feature_indices(codes, b_i=b_i, b_t=b_t)
-
-
-# --- bit-packed emit featurization families -------------------------------
-#
-# Same CWS + b-bit encode semantics as cws_encode / cws_encode_rng, but
-# the output is ceil(k*b/32) uint32 words per row (b = b_i + b_t in
-# {1, 2, 4, 8}) instead of k int32 indices: feature output traffic
-# shrinks 32/b x.  All impls agree bit-for-bit with
-# ``pack_codes(encode(<hash variant>))``.
-
-@registry.register("cws_encode_packed", "pallas", requires=("tpu",))
-def _cws_encode_packed_tpu(x, params: CWSParams, *, b_i, b_t, bn, bk, bd):
-    return cws_encode_packed_pallas(x, params.r, params.log_c, params.beta,
-                                    b_i=b_i, b_t=b_t, bn=bn, bk=bk, bd=bd,
-                                    interpret=False)
-
-
-@registry.register("cws_encode_packed", "pallas-interpret")
-def _cws_encode_packed_interp(x, params: CWSParams, *, b_i, b_t, bn, bk, bd):
-    return cws_encode_packed_pallas(x, params.r, params.log_c, params.beta,
-                                    b_i=b_i, b_t=b_t, bn=bn, bk=bk, bd=bd,
-                                    interpret=True)
-
-
-@registry.register("cws_encode_packed", "reference")
-def _cws_encode_packed_ref(x, params: CWSParams, *, b_i, b_t, bn, bk, bd):
-    i_star, t_star = _cws_hash_ref(x, params, bn=bn, bk=bk, bd=bd)
-    codes = core_hashing.encode(i_star, t_star, b_i=b_i, b_t=b_t)
-    return core_hashing.pack_codes(codes, b=b_i + b_t)
-
-
-@registry.register("cws_encode_rng_packed", "pallas", requires=("tpu",))
-def _cws_encode_rng_packed_tpu(x, key, num_hashes, *, b_i, b_t, bn, bk, bd):
-    return cws_encode_rng_packed_pallas(x, key, num_hashes, b_i=b_i,
-                                        b_t=b_t, bn=bn, bk=bk, bd=bd,
-                                        interpret=False)
-
-
-@registry.register("cws_encode_rng_packed", "pallas-interpret")
-def _cws_encode_rng_packed_interp(x, key, num_hashes, *, b_i, b_t, bn, bk,
-                                  bd):
-    return cws_encode_rng_packed_pallas(x, key, num_hashes, b_i=b_i,
-                                        b_t=b_t, bn=bn, bk=bk, bd=bd,
-                                        interpret=True)
-
-
-@registry.register("cws_encode_rng_packed", "reference")
-def _cws_encode_rng_packed_ref(x, key, num_hashes, *, b_i, b_t, bn, bk, bd):
-    i_star, t_star = _cws_hash_rng_ref(x, key, num_hashes, bn=bn, bk=bk,
-                                       bd=bd)
-    codes = core_hashing.encode(i_star, t_star, b_i=b_i, b_t=b_t)
-    return core_hashing.pack_codes(codes, b=b_i + b_t)
 
 
 @registry.register("minmax_gram", "pallas", requires=("tpu",))
@@ -266,79 +160,25 @@ def _impl_name(interpret: bool | None, impl: str | None) -> str | None:
     return "pallas-interpret" if interpret else "pallas"
 
 
-def cws_hash(x: jax.Array, params: CWSParams, *, bn: int | None = None,
-             bk: int | None = None, bd: int | None = None,
-             interpret: bool | None = None, impl: str | None = None):
-    """Pallas CWS: x (n, D) nonneg -> (i*, t*) each (n, k) int32."""
-    bn, bk, bd = _blocks(x.shape[0], x.shape[1], params.num_hashes,
-                         bn, bk, bd)
-    fn = registry.resolve("cws_hash", _impl_name(interpret, impl)).fn
-    return fn(x, params, bn=bn, bk=bk, bd=bd)
+def cws(x: jax.Array, state, *, emit: str, num_hashes: int | None = None,
+        b_i: int = 0, b_t: int = 0, bn: int | None = None,
+        bk: int | None = None, bd: int | None = None,
+        interpret: bool | None = None, impl: str | None = None):
+    """CWS of x (n, D) nonneg (DESIGN.md §6–§7).
 
-
-def cws_encode(x: jax.Array, params: CWSParams, *, b_i: int, b_t: int = 0,
-               bn: int | None = None, bk: int | None = None,
-               bd: int | None = None, interpret: bool | None = None,
-               impl: str | None = None) -> jax.Array:
-    """Fused featurization: x (n, D) nonneg -> embedding-bag indices
-    (n, k) int32 into k * 2^{b_i+b_t} features (DESIGN.md §6)."""
-    bn, bk, bd = _blocks(x.shape[0], x.shape[1], params.num_hashes,
-                         bn, bk, bd)
-    fn = registry.resolve("cws_encode", _impl_name(interpret, impl)).fn
-    return fn(x, params, b_i=b_i, b_t=b_t, bn=bn, bk=bk, bd=bd)
-
-
-def cws_hash_rng(x: jax.Array, key: jax.Array, num_hashes: int, *,
-                 bn: int | None = None, bk: int | None = None,
-                 bd: int | None = None, interpret: bool | None = None,
-                 impl: str | None = None):
-    """Zero-parameter-traffic CWS: x (n, D) nonneg + PRNG key ->
-    (i*, t*) each (n, num_hashes) int32; params regenerated in-kernel."""
-    bn, bk, bd = _blocks(x.shape[0], x.shape[1], num_hashes,
-                         bn, bk, bd, op="cws_rng")
-    fn = registry.resolve("cws_hash_rng", _impl_name(interpret, impl)).fn
-    return fn(x, key, num_hashes, bn=bn, bk=bk, bd=bd)
-
-
-def cws_encode_rng(x: jax.Array, key: jax.Array, num_hashes: int, *,
-                   b_i: int, b_t: int = 0, bn: int | None = None,
-                   bk: int | None = None, bd: int | None = None,
-                   interpret: bool | None = None,
-                   impl: str | None = None) -> jax.Array:
-    """Fused zero-parameter-traffic featurization: x (n, D) nonneg + PRNG
-    key -> embedding-bag indices (n, num_hashes) int32 (DESIGN.md §7)."""
-    bn, bk, bd = _blocks(x.shape[0], x.shape[1], num_hashes,
-                         bn, bk, bd, op="cws_rng")
-    fn = registry.resolve("cws_encode_rng", _impl_name(interpret, impl)).fn
-    return fn(x, key, num_hashes, b_i=b_i, b_t=b_t, bn=bn, bk=bk, bd=bd)
-
-
-def cws_encode_packed(x: jax.Array, params: CWSParams, *, b_i: int,
-                      b_t: int = 0, bn: int | None = None,
-                      bk: int | None = None, bd: int | None = None,
-                      interpret: bool | None = None,
-                      impl: str | None = None) -> jax.Array:
-    """Fused featurization, bit-packed output: x (n, D) nonneg ->
-    (n, ceil(k·b/32)) uint32 words, b = b_i + b_t in {1, 2, 4, 8}."""
-    bn, bk, bd = _blocks(x.shape[0], x.shape[1], params.num_hashes,
-                         bn, bk, bd, op="cws_packed")
-    fn = registry.resolve("cws_encode_packed",
-                          _impl_name(interpret, impl)).fn
-    return fn(x, params, b_i=b_i, b_t=b_t, bn=bn, bk=bk, bd=bd)
-
-
-def cws_encode_rng_packed(x: jax.Array, key: jax.Array, num_hashes: int, *,
-                          b_i: int, b_t: int = 0, bn: int | None = None,
-                          bk: int | None = None, bd: int | None = None,
-                          interpret: bool | None = None,
-                          impl: str | None = None) -> jax.Array:
-    """Zero-parameter-traffic fused featurization, bit-packed output:
-    x (n, D) nonneg + PRNG key -> (n, ceil(num_hashes·b/32)) uint32."""
-    bn, bk, bd = _blocks(x.shape[0], x.shape[1], num_hashes,
-                         bn, bk, bd, op="cws_rng_packed")
-    fn = registry.resolve("cws_encode_rng_packed",
-                          _impl_name(interpret, impl)).fn
-    return fn(x, key, num_hashes, b_i=b_i, b_t=b_t, bn=bn, bk=bk, bd=bd)
+    ``state`` picks the parameter source: stored ``CWSParams``, or PRNG
+    key words whose parameters every impl regenerates from the counter
+    spec (then ``num_hashes`` gives k).  ``emit`` picks what comes back:
+    ``"hash"`` -> (i*, t*) each (n, k) int32; ``"index"`` -> embedding-bag
+    indices (n, k) int32 into k * 2^{b_i+b_t} features; ``"packed"`` ->
+    (n, ceil(k·b/32)) uint32 words, b = b_i + b_t in {1, 2, 4, 8}.
+    Unset blocks come from ``choose_blocks`` for the launch's family."""
+    k = hash_count(state, num_hashes)
+    bn, bk, bd = _blocks(x.shape[0], x.shape[1], k, bn, bk, bd,
+                         op=registry.cws_family(param_source(state), emit))
+    fn = registry.resolve("cws", _impl_name(interpret, impl)).fn
+    return fn(x, state, emit=emit, num_hashes=k, b_i=b_i, b_t=b_t,
+              bn=bn, bk=bk, bd=bd)
 
 
 def minmax_gram(x: jax.Array, y: jax.Array, *, bm: int | None = None,
@@ -408,74 +248,27 @@ def _probe_shape(b1, b2, bd):
     return 2 * b1 + 3, 2 * bd + 5, 2 * b2 + 3
 
 
-@registry.register_probe("cws", op="cws_hash")
-def _probe_cws_hash(b1, b2, bd):
+def _probe_cws(source, emit, b1, b2, bd):
     n, d, k = _probe_shape(b1, b2, bd)
-    x = _probe_sds((n, d))
     p = _probe_sds((d, k))
+    state = (CWSParams(p, p, p) if source == "stored"
+             else jax.random.PRNGKey(0))
+    # b_i + b_t = 8 packed: the widest packed b, the footprint the model
+    # covers; b_t > 0 unpacked keeps best_t in scratch, as "hash" does
+    b_i = 4 if emit == "packed" else 2
+    legal = (b1, _packed_bk(b2, k, 8) if emit == "packed" else b2, bd)
 
-    def fn(x, r, log_c, beta):
-        return cws_hash_pallas(x, r, log_c, beta, bn=b1, bk=b2, bd=bd,
-                               interpret=True)
-    return fn, (x, p, p, p), (b1, b2, bd)
-
-
-@registry.register_probe("cws", op="cws_encode")
-def _probe_cws_encode(b1, b2, bd):
-    n, d, k = _probe_shape(b1, b2, bd)
-    x = _probe_sds((n, d))
-    p = _probe_sds((d, k))
-
-    def fn(x, r, log_c, beta):
-        return cws_encode_pallas(x, r, log_c, beta, b_i=2, b_t=2,
-                                 bn=b1, bk=b2, bd=bd, interpret=True)
-    return fn, (x, p, p, p), (b1, b2, bd)
+    def fn(x, state):
+        return cws_pallas(x, state, k, emit=emit, b_i=b_i, b_t=b_i, bn=b1,
+                          bk=b2, bd=bd, interpret=True)
+    return fn, (_probe_sds((n, d)), state), legal
 
 
-@registry.register_probe("cws_rng", op="cws_hash_rng")
-def _probe_cws_hash_rng(b1, b2, bd):
-    n, d, k = _probe_shape(b1, b2, bd)
-
-    def fn(x, key):
-        return cws_hash_rng_pallas(x, key, k, bn=b1, bk=b2, bd=bd,
-                                   interpret=True)
-    return fn, (_probe_sds((n, d)), jax.random.PRNGKey(0)), (b1, b2, bd)
-
-
-@registry.register_probe("cws_rng", op="cws_encode_rng")
-def _probe_cws_encode_rng(b1, b2, bd):
-    n, d, k = _probe_shape(b1, b2, bd)
-
-    def fn(x, key):
-        return cws_encode_rng_pallas(x, key, k, b_i=2, b_t=2,
-                                     bn=b1, bk=b2, bd=bd, interpret=True)
-    return fn, (_probe_sds((n, d)), jax.random.PRNGKey(0)), (b1, b2, bd)
-
-
-@registry.register_probe("cws_packed", op="cws_encode_packed")
-def _probe_cws_encode_packed(b1, b2, bd):
-    # b_i + b_t = 8: the widest packed b, the footprint the model covers
-    n, d, k = _probe_shape(b1, b2, bd)
-    x = _probe_sds((n, d))
-    p = _probe_sds((d, k))
-    legal = (b1, _packed_bk(b2, k, 8), bd)
-
-    def fn(x, r, log_c, beta):
-        return cws_encode_packed_pallas(x, r, log_c, beta, b_i=4, b_t=4,
-                                        bn=b1, bk=b2, bd=bd, interpret=True)
-    return fn, (x, p, p, p), legal
-
-
-@registry.register_probe("cws_rng_packed", op="cws_encode_rng_packed")
-def _probe_cws_encode_rng_packed(b1, b2, bd):
-    n, d, k = _probe_shape(b1, b2, bd)
-    legal = (b1, _packed_bk(b2, k, 8), bd)
-
-    def fn(x, key):
-        return cws_encode_rng_packed_pallas(x, key, k, b_i=4, b_t=4,
-                                            bn=b1, bk=b2, bd=bd,
-                                            interpret=True)
-    return fn, (_probe_sds((n, d)), jax.random.PRNGKey(0)), legal
+for _source in registry.CWS_SOURCES:
+    for _emit in registry.CWS_EMITS:
+        registry.register_probe(registry.cws_family(_source, _emit),
+                                op="cws")(
+            functools.partial(_probe_cws, _source, _emit))
 
 
 @registry.register_probe("min_sum", op="min_sum")
@@ -503,38 +296,14 @@ _TRIO_KW = dict(bn=8, bk=8, bd=16)
 _TRIO_KEY = jax.random.PRNGKey(0)
 
 
-@registry.register_trio("cws_hash")
-def _trio_cws_hash():
-    return (_TRIO_X, CWSParams(_TRIO_P, _TRIO_P, _TRIO_P)), dict(_TRIO_KW)
-
-
-@registry.register_trio("cws_encode")
-def _trio_cws_encode():
-    return ((_TRIO_X, CWSParams(_TRIO_P, _TRIO_P, _TRIO_P)),
-            dict(b_i=2, b_t=2, **_TRIO_KW))
-
-
-@registry.register_trio("cws_hash_rng")
-def _trio_cws_hash_rng():
-    return (_TRIO_X, _TRIO_KEY), dict(num_hashes=17, **_TRIO_KW)
-
-
-@registry.register_trio("cws_encode_rng")
-def _trio_cws_encode_rng():
-    return (_TRIO_X, _TRIO_KEY), dict(num_hashes=17, b_i=2, b_t=2,
-                                      **_TRIO_KW)
-
-
-@registry.register_trio("cws_encode_packed")
-def _trio_cws_encode_packed():
-    return ((_TRIO_X, CWSParams(_TRIO_P, _TRIO_P, _TRIO_P)),
-            dict(b_i=4, b_t=4, **_TRIO_KW))
-
-
-@registry.register_trio("cws_encode_rng_packed")
-def _trio_cws_encode_rng_packed():
-    return (_TRIO_X, _TRIO_KEY), dict(num_hashes=17, b_i=4, b_t=4,
-                                      **_TRIO_KW)
+@registry.register_trio("cws", cases=tuple(
+    (s, e) for s in registry.CWS_SOURCES for e in registry.CWS_EMITS))
+def _trio_cws(source, emit):
+    state = (CWSParams(_TRIO_P, _TRIO_P, _TRIO_P) if source == "stored"
+             else _TRIO_KEY)
+    b_i = 4 if emit == "packed" else 2
+    return (_TRIO_X, state), dict(emit=emit, num_hashes=17, b_i=b_i,
+                                  b_t=b_i, **_TRIO_KW)
 
 
 @registry.register_trio("minmax_gram")

@@ -1,7 +1,6 @@
 """Kernel implementation registry with backend-capability dispatch.
 
-Every logical op (``cws_hash``, ``cws_encode``, ``cws_hash_rng``,
-``cws_encode_rng``, ``minmax_gram``, ``min_sum``) has named
+Every logical op (``cws``, ``minmax_gram``, ``min_sum``) has named
 implementations:
 
   * ``pallas``            — the Mosaic kernel, requires a TPU backend;
@@ -21,7 +20,9 @@ consults a small autotune table keyed on pow2-bucketed (n, D, k) and falls
 back to a VMEM-budget heuristic (see DESIGN.md §2 for the roofline that
 motivates the defaults).  The table is process-global and extendable via
 ``update_block_table`` so future TPU sweeps can refine it without touching
-call sites.
+call sites.  Tables and models are keyed by block FAMILY; the one ``cws``
+op launches four of them, one per (parameter source, emit format) pair
+(``cws_family``).
 """
 from __future__ import annotations
 
@@ -39,10 +40,11 @@ __all__ = [
     "choose_blocks",
     "update_block_table", "save_block_table", "load_block_table",
     "block_candidates", "vmem_bytes", "table_key", "BLOCK_TABLE",
+    "CWS_SOURCES", "CWS_EMITS", "cws_family", "launch_families",
     "serve_buckets", "update_serve_buckets", "save_serve_buckets",
     "load_serve_buckets", "SERVE_BUCKET_TABLE", "DEFAULT_SERVE_BUCKETS",
     # introspection surface consumed by repro.analysis / tools/kernel_lint
-    "registered_ops", "family", "model_families", "vmem_budget",
+    "registered_ops", "model_families", "vmem_budget",
     "has_vmem_model", "LaunchProbe", "register_probe", "family_probes",
     "probe_families", "force_donation", "register_donation_site",
     "donation_sites", "register_collective_site", "collective_sites",
@@ -157,7 +159,9 @@ def resolve(op: str, impl: str | None = None) -> KernelImpl:
 #               and cost VPU work instead of HBM reads, so the measured
 #               optimum can differ — typically larger bn, since the
 #               regeneration cost amortizes over the row block);
+#   "cws_packed", "cws_rng_packed" — the same two with the packed emit;
 #   "min_sum" — the gram kernels (rows x dims x cols).
+# cws_family maps a CWS launch's (source, emit) to its family.
 # Seeded from the VMEM model below at the shapes the benchmarks exercise;
 # autotune sweeps (tools/autotune_blocks.py) replace entries with measured
 # winners via update_block_table / load_block_table.
@@ -170,37 +174,59 @@ BLOCK_TABLE: Dict[Tuple[str, int, int, int], Tuple[int, int, int]] = {
 
 _VMEM_BUDGET = 8 * 2 ** 20   # conservative half of ~16MB/core
 
+# The one CWS op's static choices and the block family of each pair: the
+# parameter source (stored matrices or regenerated in the kernel) and the
+# emit format (raw (i*, t*), int32 bag indices, or packed words).  "hash"
+# and "index" share a family: their tiles and scratch differ only in the
+# output, which the unpacked model covers at its widest.
+CWS_SOURCES = ("stored", "regen")
+CWS_EMITS = ("hash", "index", "packed")
+
+
+def cws_family(source: str, emit: str) -> str:
+    """The block family of a CWS launch: ``cws`` / ``cws_rng`` for stored
+    / regenerated parameters, with ``_packed`` for the packed emit."""
+    if source not in CWS_SOURCES or emit not in CWS_EMITS:
+        raise ValueError(f"no CWS variant ({source!r}, {emit!r}); sources "
+                         f"{CWS_SOURCES}, emits {CWS_EMITS}")
+    fam = "cws" if source == "stored" else "cws_rng"
+    return fam + "_packed" if emit == "packed" else fam
+
+
+def _cws_vmem(bn: int, bk: int, bd: int) -> int:
+    """x tile + its transposed log u scratch + 3 parameter tiles (stored
+    operand blocks, or regenerated scratch — single-buffered, no
+    pipelined second copy) + 3 accumulators + 2 output tiles."""
+    return 4 * (2 * bn * bd + 3 * bd * bk + 5 * bn * bk)
+
+
+def _cws_packed_vmem(bn: int, bk: int, bd: int) -> int:
+    """As ``_cws_vmem`` with 3 fp32 accumulators (best_a/best_i/best_t)
+    plus the packed uint32 output tile of bn*bk*b/32 words — modeled at
+    the widest packed b (8 -> bk/4 words -> bn*bk bytes), so every legal
+    b fits whatever this admits."""
+    return 4 * (2 * bn * bd + 3 * bd * bk + 3 * bn * bk) + bn * bk
+
+
 # Per-family fp32 working-set models (b1, b2, bd) -> bytes.  Axis naming
 # follows choose_blocks: b1 tiles the first problem axis (rows), b2 the
-# third (hashes/cols), bd the contraction/dims axis.
+# third (hashes/cols), bd the contraction/dims axis.  Audited against the
+# BlockSpec/scratch footprint the kernels actually declare by
+# repro.analysis.vmem.
 _VMEM_MODELS: Dict[str, Callable[[int, int, int], int]] = {
-    # x tile + its transposed log u scratch + 3 param tiles
-    # + 3 accumulators + 2 output tiles
-    "cws": lambda bn, bk, bd: 4 * (2 * bn * bd + 3 * bd * bk + 5 * bn * bk),
-    # x tile + transposed log u + 3 regenerated param tiles (scratch,
-    # single-buffered — no pipelined second copy) + 3 accumulators
-    # + 2 output tiles
-    "cws_rng": lambda bn, bk, bd: 4 * (2 * bn * bd + 3 * bd * bk
-                                       + 5 * bn * bk),
-    # packed-emit twins: 3 fp32 accumulators (best_a/best_i/best_t) plus
-    # the packed uint32 output tile of bn*bk*b/32 words — modeled at the
-    # widest packed b (8 -> bk/4 words -> bn*bk bytes), so every legal b
-    # fits whatever these admit.  Audited against the BlockSpec/scratch
-    # footprint the kernels actually declare by repro.analysis.vmem.
-    "cws_packed": lambda bn, bk, bd: 4 * (2 * bn * bd + 3 * bd * bk
-                                          + 3 * bn * bk) + bn * bk,
-    "cws_rng_packed": lambda bn, bk, bd: 4 * (2 * bn * bd + 3 * bd * bk
-                                              + 3 * bn * bk) + bn * bk,
+    **{cws_family(s, e): (_cws_packed_vmem if e == "packed" else _cws_vmem)
+       for s in CWS_SOURCES for e in CWS_EMITS},
     # x tile + y tile, each with its transposed copy, + accumulator
     # + output tile
     "min_sum": lambda bm, bn, bd: 4 * (2 * bm * bd + 2 * bn * bd
                                        + 2 * bm * bn),
 }
-_FAMILY_ALIASES = {"gram": "min_sum", "cws_hash": "cws", "cws_encode": "cws",
-                   "cws_hash_rng": "cws_rng", "cws_encode_rng": "cws_rng",
-                   "cws_encode_packed": "cws_packed",
-                   "cws_encode_rng_packed": "cws_rng_packed",
-                   "minmax_gram": "min_sum"}
+_FAMILY_ALIASES = {"gram": "min_sum", "minmax_gram": "min_sum"}
+
+# Ops whose launches span several block families.
+_LAUNCH_FAMILIES = {"cws": tuple(sorted({cws_family(s, e)
+                                         for s in CWS_SOURCES
+                                         for e in CWS_EMITS}))}
 
 
 def _family(op: str) -> str:
@@ -394,9 +420,9 @@ def lookup(op: str, name: str) -> KernelImpl:
     return _REGISTRY[op][name]
 
 
-def family(op: str) -> str:
-    """Public alias-resolution: op name -> VMEM-model family name."""
-    return _family(op)
+def launch_families(op: str) -> Tuple[str, ...]:
+    """Every block family a launch of ``op`` may use."""
+    return _LAUNCH_FAMILIES.get(op, (_family(op),))
 
 
 def model_families() -> Tuple[str, ...]:
@@ -508,24 +534,30 @@ def numerics_sites() -> Tuple[AnalysisSite, ...]:
 
 @dataclasses.dataclass(frozen=True)
 class TrioProbe:
-    """A recipe for signature-checking one op's impl trio: ``build()``
-    returns ``(args, kwargs)`` such that every impl in ``impls`` accepts
-    ``impl.fn(*args, **kwargs)`` under jax.eval_shape (args may be
-    ShapeDtypeStructs — nothing executes).  The determinism check
-    requires the resulting output shape/dtype trees to agree exactly."""
+    """A recipe for signature-checking one op's impl trio: for each entry
+    of ``cases``, ``build(*case)`` returns ``(args, kwargs)`` such that
+    every impl in ``impls`` accepts ``impl.fn(*args, **kwargs)`` under
+    jax.eval_shape (args may be ShapeDtypeStructs — nothing executes).
+    The determinism check requires the resulting output shape/dtype trees
+    to agree exactly, case by case."""
     op: str
     impls: Tuple[str, ...]
-    build: Callable[[], tuple]
+    build: Callable[..., tuple]
+    cases: Tuple[tuple, ...] = ((),)
 
 
 _TRIO_PROBES: Dict[str, TrioProbe] = {}
 
 
 def register_trio(op: str, *, impls: Tuple[str, ...] = (
-        "pallas", "pallas-interpret", "reference")):
-    """Decorator: register a trio-signature probe for ``op``."""
+        "pallas", "pallas-interpret", "reference"),
+        cases: Tuple[tuple, ...] = ((),)):
+    """Decorator: register a trio-signature probe for ``op``, built once
+    per entry of ``cases`` (one op's variants, e.g. the CWS (source,
+    emit) pairs)."""
     def deco(build: Callable) -> Callable:
-        _TRIO_PROBES[op] = TrioProbe(op=op, impls=tuple(impls), build=build)
+        _TRIO_PROBES[op] = TrioProbe(op=op, impls=tuple(impls), build=build,
+                                     cases=tuple(cases))
         return build
     return deco
 

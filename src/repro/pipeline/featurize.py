@@ -9,7 +9,7 @@ therefore exposes the fused artifact directly:
     pipe = FeaturePipeline.create(key, dim, FeatureSpec(k=512, b_i=8))
     idx  = pipe.features(x)          # (n, k) int32 into pipe.num_features
 
-backed by the registry-dispatched fused kernel (``cws_encode``: Mosaic on
+backed by the registry-dispatched fused kernel (``ops.cws``: Mosaic on
 TPU, pure-JAX reference on CPU, Pallas interpreter for kernel-parity
 testing).  The staged composition (hash -> encode -> offsets) survives in
 two sanctioned places only: the registry's ``reference`` implementation
@@ -202,18 +202,15 @@ class FeaturePipeline:
     # -- single-launch building block ----------------------------------
 
     def _launch(self, x: Array) -> Array:
-        bn, bk, bd = self.blocks or (None, None, None)
-        if self.param_free:
-            fn = (ops.cws_encode_rng_packed if self.spec.packed
-                  else ops.cws_encode_rng)
-            return fn(
-                x, self._key_words, self.spec.num_hashes, b_i=self.spec.b_i,
-                b_t=self.spec.b_t, bn=bn, bk=bk, bd=bd,
-                impl=self._resolved_impl())
-        fn = ops.cws_encode_packed if self.spec.packed else ops.cws_encode
-        return fn(
-            x, self._state(), b_i=self.spec.b_i, b_t=self.spec.b_t,
-            bn=bn, bk=bk, bd=bd, impl=self._resolved_impl())
+        return self._cws(x, self._state(), self.emit)
+
+    def _cws(self, x: Array, state, emit: str):
+        """One kernel launch on ``state`` (CWSParams or key words) that
+        emits ``emit``, at the pipeline's blocks and impl."""
+        bn, bk, bd = self._blocks_for(x.shape[0], x.shape[1], emit)
+        return ops.cws(x, state, emit=emit, num_hashes=self.spec.num_hashes,
+                       b_i=self.spec.b_i, b_t=self.spec.b_t, bn=bn, bk=bk,
+                       bd=bd, impl=self._resolved_impl())
 
     def _state(self):
         """The replicated launch state: the (sliced) CWSParams matrices,
@@ -345,15 +342,7 @@ class FeaturePipeline:
         if x.shape[0] == 0:
             z = jnp.zeros((0, self.spec.num_hashes), jnp.int32)
             return z, z
-        bn, bk, bd = self.blocks or (None, None, None)
-        impl = self.impl
-        if impl is None and not registry.on_tpu():
-            impl = "reference"
-        if self.param_free:
-            return ops.cws_hash_rng(x, self._key_words, self.spec.num_hashes,
-                                    bn=bn, bk=bk, bd=bd, impl=impl)
-        return ops.cws_hash(x, self._state(), bn=bn, bk=bk, bd=bd,
-                            impl=impl)
+        return self._cws(x, self._state(), "hash")
 
     def features_from_hashes(self, i_star: Array, t_star: Array) -> Array:
         """Stage 2+3 on precomputed hashes (columns may be pre-sliced to a
@@ -446,37 +435,39 @@ class FeaturePipeline:
                     table, self._launch_with(xc, state)))
         return self._scoring_fn
 
-    def _blocks_for(self, rows: int, dim: int) -> Tuple[int, int, int]:
-        """(bn, bk, bd) of one kernel launch over (rows, dim)."""
-        fam = "cws_rng" if self.param_free else "cws"
-        if self.spec.packed:
-            fam += "_packed"
+    @property
+    def source(self) -> str:
+        """Where the CWS parameters come from: ``"stored"`` matrices, or
+        ``"regen"``erated in the kernel from two key words."""
+        return "regen" if self.param_free else "stored"
+
+    @property
+    def emit(self) -> str:
+        """What a feature launch emits: ``"packed"`` words or int32
+        bag ``"index"``es (``hashes`` emits ``"hash"``)."""
+        return "packed" if self.spec.packed else "index"
+
+    def _blocks_for(self, rows: int, dim: int,
+                    emit: str) -> Tuple[int, int, int]:
+        """(bn, bk, bd) of one kernel launch over (rows, dim) that emits
+        ``emit``."""
         return self.blocks or registry.choose_blocks(
-            rows, dim, self.spec.num_hashes, op=fam)
+            rows, dim, self.spec.num_hashes,
+            op=registry.cws_family(self.source, emit))
 
     def loop_share(self, rows: int) -> float:
         """Real D over the padded D, for a launch of ``rows`` rows on one
         device: the share of the padded dimension loop that the kernel
         runs (``cws_loop_share`` of the launch and fit set-up spans)."""
-        return loop_share(self.dim, self._blocks_for(rows, self.dim)[2])
+        return loop_share(self.dim,
+                          self._blocks_for(rows, self.dim, self.emit)[2])
 
     def _launch_with(self, x: Array, state) -> Array:
         """One kernel launch on explicit state (CWSParams or key words)."""
-        bn, bk, bd = self._blocks_for(x.shape[0], x.shape[1])
-        if self.param_free:
-            fn = registry.resolve(self._op_name(), self._resolved_impl()).fn
-            return fn(x, state, self.spec.num_hashes, b_i=self.spec.b_i,
-                      b_t=self.spec.b_t, bn=bn, bk=bk, bd=bd)
-        fn = registry.resolve(self._op_name(), self._resolved_impl()).fn
-        return fn(x, state, b_i=self.spec.b_i, b_t=self.spec.b_t,
-                  bn=bn, bk=bk, bd=bd)
-
-    def _op_name(self) -> str:
-        op = "cws_encode_rng" if self.param_free else "cws_encode"
-        return op + "_packed" if self.spec.packed else op
+        return self._cws(x, state, self.emit)
 
     def _resolved_impl(self) -> str:
-        return self.impl or registry.auto_impl(self._op_name())
+        return self.impl or registry.auto_impl("cws")
 
     def state_pspec(self):
         """PartitionSpec for the replicated launch state: the (2,) key

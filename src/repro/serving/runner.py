@@ -47,7 +47,7 @@ class BucketRunner:
         validate_bag_features(params, pipe.num_features, spec=pipe.spec)
         self.pipe = pipe
         self.params = params
-        fam = registry.family(pipe._op_name())
+        fam = registry.cws_family(pipe.source, pipe.emit)
         self.family = fam
         self.buckets: Tuple[int, ...] = tuple(
             sorted(set(int(b) for b in buckets))

@@ -575,7 +575,7 @@ class TestSuiteGreen:
         # structural sanity on a real kernel: grid, operands, scratch
         fam_blocks = (8, 128, 128)
         (rec,) = [r for r in probe_footprints("cws_rng", fam_blocks)
-                  if r["op"] == "cws_hash_rng"]
+                  if r["launch"].name == "cws_hash_rng_pallas"]
         launch = rec["launch"]
         assert len(launch.grid) == 3
         assert len(launch.outputs) == 2          # i*, t*

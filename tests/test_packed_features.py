@@ -89,12 +89,13 @@ class TestPackedKernels:
         n, d, k = 17, 33, 50      # ragged vs every block size
         x = rand_nonneg(jax.random.PRNGKey(0), (n, d))
         params = make_cws_params(jax.random.PRNGKey(1), d, k)
-        idx = ops.cws_encode(x, params, b_i=b_i, b_t=b_t, impl="reference")
+        idx = ops.cws(x, params, emit="index", b_i=b_i, b_t=b_t,
+                      impl="reference")
         codes = idx - jnp.arange(k, dtype=jnp.int32) * (1 << b)
         want = hashing.pack_codes(codes, b=b)
         for impl in ("reference", "pallas-interpret"):
-            got = ops.cws_encode_packed(x, params, b_i=b_i, b_t=b_t,
-                                        impl=impl)
+            got = ops.cws(x, params, emit="packed", b_i=b_i, b_t=b_t,
+                          impl=impl)
             assert got.dtype == jnp.uint32
             assert (got == want).all(), impl
             assert (hashing.unpack_codes(got, k, b=b) == codes).all(), impl
@@ -105,13 +106,13 @@ class TestPackedKernels:
         n, d, k = 13, 21, 40
         x = rand_nonneg(jax.random.PRNGKey(2), (n, d))
         key = jax.random.PRNGKey(5)
-        idx = ops.cws_encode_rng(x, key, k, b_i=b_i, b_t=b_t,
-                                 impl="reference")
+        idx = ops.cws(x, key, emit="index", num_hashes=k, b_i=b_i, b_t=b_t,
+                      impl="reference")
         want = hashing.pack_codes(
             idx - jnp.arange(k, dtype=jnp.int32) * (1 << b), b=b)
         for impl in ("reference", "pallas-interpret"):
-            got = ops.cws_encode_rng_packed(x, key, k, b_i=b_i, b_t=b_t,
-                                            impl=impl)
+            got = ops.cws(x, key, emit="packed", num_hashes=k, b_i=b_i,
+                          b_t=b_t, impl=impl)
             assert (got == want).all(), impl
 
     # the last D step's loop: a tail of 1, of bd - 1, bd dividing D (no
@@ -128,15 +129,12 @@ class TestPackedKernels:
         x = x.at[0].set(0.0).at[1, :last].set(0.0).at[1, last:].add(0.5)
         blocks = dict(b_i=b_i, bn=8, bk=8, bd=bd)
         if regen:
-            key = jax.random.PRNGKey(5)
-            idx = ops.cws_encode_rng(x, key, k, impl="reference", **blocks)
-            got = ops.cws_encode_rng_packed(x, key, k,
-                                            impl="pallas-interpret", **blocks)
+            state, blocks["num_hashes"] = jax.random.PRNGKey(5), k
         else:
-            params = make_cws_params(jax.random.PRNGKey(1), d, k)
-            idx = ops.cws_encode(x, params, impl="reference", **blocks)
-            got = ops.cws_encode_packed(x, params, impl="pallas-interpret",
-                                        **blocks)
+            state = make_cws_params(jax.random.PRNGKey(1), d, k)
+        idx = ops.cws(x, state, emit="index", impl="reference", **blocks)
+        got = ops.cws(x, state, emit="packed", impl="pallas-interpret",
+                      **blocks)
         codes = idx - jnp.arange(k, dtype=jnp.int32) * (1 << b_i)
         want = hashing.pack_codes(codes, b=b_i)
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
@@ -146,8 +144,8 @@ class TestPackedKernels:
         n, d, k = 9, 16, 24
         x = rand_nonneg(jax.random.PRNGKey(3), (n, d)).at[4].set(0.0)
         params = make_cws_params(jax.random.PRNGKey(1), d, k)
-        got = ops.cws_encode_packed(x, params, b_i=2, b_t=2,
-                                    impl="pallas-interpret")
+        got = ops.cws(x, params, emit="packed", b_i=2, b_t=2,
+                      impl="pallas-interpret")
         assert (hashing.unpack_codes(got, k, b=4)[4] == 0).all()
 
 

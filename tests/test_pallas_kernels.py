@@ -5,7 +5,9 @@ import numpy as np
 import pytest
 
 from repro.configs.minmax_paper import CONFIG
-from repro.core.cws import make_cws_params, cws_hash as cws_hash_core
+from repro.core.cws import (CWSParams, make_cws_params,
+                            cws_hash as cws_hash_core)
+from repro.analysis.launches import extract_launches
 from repro.kernels import cws_hash as ch
 from repro.kernels import ops, registry
 from repro.kernels.ref import cws_hash_ref, minmax_gram_ref, min_sum_ref
@@ -59,7 +61,8 @@ class TestCWSPallas:
         x = with_edge_rows(x, bd)
         p = make_cws_params(jax.random.PRNGKey(d * 7 + k), d, k)
         i_ref, t_ref = cws_hash_ref(x, p.r, p.log_c, p.beta)
-        i_pl, t_pl = ops.cws_hash(x, p, bn=bn, bk=bk, bd=bd, interpret=True)
+        i_pl, t_pl = ops.cws(x, p, emit="hash", bn=bn, bk=bk, bd=bd,
+                             interpret=True)
         np.testing.assert_array_equal(np.asarray(i_ref), np.asarray(i_pl))
         np.testing.assert_array_equal(np.asarray(t_ref), np.asarray(t_pl))
         assert (np.asarray(i_pl[0]) == -1).all()
@@ -69,14 +72,16 @@ class TestCWSPallas:
         x = rand_nonneg(jax.random.PRNGKey(0), (40, 70))
         p = make_cws_params(jax.random.PRNGKey(1), 70, 30)
         i_core, t_core = cws_hash_core(x, p, row_block=16, hash_block=8)
-        i_pl, t_pl = ops.cws_hash(x, p, bn=16, bk=8, bd=32, interpret=True)
+        i_pl, t_pl = ops.cws(x, p, emit="hash", bn=16, bk=8, bd=32,
+                             interpret=True)
         np.testing.assert_array_equal(np.asarray(i_core), np.asarray(i_pl))
         np.testing.assert_array_equal(np.asarray(t_core), np.asarray(t_pl))
 
     def test_zero_rows_sentinel(self):
         x = jnp.zeros((8, 16))
         p = make_cws_params(jax.random.PRNGKey(2), 16, 8)
-        i_pl, t_pl = ops.cws_hash(x, p, bn=4, bk=4, bd=8, interpret=True)
+        i_pl, t_pl = ops.cws(x, p, emit="hash", bn=4, bk=4, bd=8,
+                             interpret=True)
         assert (np.asarray(i_pl) == -1).all()
         assert (np.asarray(t_pl) == 0).all()
 
@@ -85,7 +90,8 @@ class TestCWSPallas:
         x = jnp.zeros((3, 12)).at[0].set(1.5).at[2, 5].set(3.0)
         p = make_cws_params(jax.random.PRNGKey(3), 12, 16)
         i_ref, t_ref = cws_hash_ref(x, p.r, p.log_c, p.beta)
-        i_pl, t_pl = ops.cws_hash(x, p, bn=2, bk=8, bd=4, interpret=True)
+        i_pl, t_pl = ops.cws(x, p, emit="hash", bn=2, bk=8, bd=4,
+                             interpret=True)
         np.testing.assert_array_equal(np.asarray(i_ref), np.asarray(i_pl))
         assert (np.asarray(i_pl[2]) == 5).all()   # only one active dim
 
@@ -95,7 +101,8 @@ class TestCWSPallas:
         x = rand_nonneg(jax.random.PRNGKey(4), (12, 24), dtype=in_dtype)
         p = make_cws_params(jax.random.PRNGKey(5), 24, 8)
         i_ref, t_ref = cws_hash_ref(x, p.r, p.log_c, p.beta)
-        i_pl, t_pl = ops.cws_hash(x, p, bn=4, bk=4, bd=8, interpret=True)
+        i_pl, t_pl = ops.cws(x, p, emit="hash", bn=4, bk=4, bd=8,
+                             interpret=True)
         np.testing.assert_array_equal(np.asarray(i_ref), np.asarray(i_pl))
 
 
@@ -130,24 +137,25 @@ def _loop_trips(jaxpr, trips=None, branches=0):
     return trips, branches
 
 
+# each kernel's name, less "_pallas" -> its (parameter source, emit)
+VARIANTS = {name[:-len("_pallas")]: variant
+            for variant, name in ch.KERNEL_NAMES.items()}
+
+
 def _kernel_call(name, n, d, k, b):
     """(wrapper, arg shapes) of one CWS kernel with choose_blocks'
     blocks at (n, D, k)."""
-    fam = {"cws_hash": "cws", "cws_encode": "cws",
-           "cws_encode_packed": "cws_packed", "cws_hash_rng": "cws_rng",
-           "cws_encode_rng": "cws_rng",
-           "cws_encode_rng_packed": "cws_rng_packed"}[name]
-    bn, bk, bd = registry.choose_blocks(n, d, k, op=fam)
-    fn = getattr(ch, name + "_pallas")
-    blocks = dict(bn=bn, bk=bk, bd=bd)
-    if name != "cws_hash" and name != "cws_hash_rng":
-        blocks["b_i"] = b
+    source, emit = VARIANTS[name]
+    bn, bk, bd = registry.choose_blocks(
+        n, d, k, op=registry.cws_family(source, emit))
+    blocks = dict(emit=emit, b_i=b, bn=bn, bk=bk, bd=bd)
     x = jax.ShapeDtypeStruct((n, d), jnp.float32)
-    if "rng" in name:
-        return (lambda x, key: fn(x, key, k, **blocks),
+    if source == "regen":
+        return (lambda x, key: ch.cws_pallas(x, key, k, **blocks),
                 [x, jax.ShapeDtypeStruct((2,), jnp.uint32)])
     p = jax.ShapeDtypeStruct((d, k), jnp.float32)
-    return (lambda x, r, c, be: fn(x, r, c, be, **blocks)), [x, p, p, p]
+    return (lambda x, r, c, be: ch.cws_pallas(x, CWSParams(r, c, be),
+                                              **blocks)), [x, p, p, p]
 
 
 @pytest.mark.parametrize("d,trips", [
@@ -168,6 +176,47 @@ def test_kernel_loops_over_the_real_dimensions(name, d, trips):
     got, branches = _loop_trips(jaxpr)
     assert got == trips
     assert branches == 2 * len(trips)
+
+
+# (n, D) -> choose_blocks' (bn, bk, bd) at k = 1,024 and the grid they
+# give: the train cell's step, the featurize cell's chunk, and the
+# serving ladder's two smallest buckets.  Every CWS family gives the same.
+CELL_LAUNCHES = [
+    (512, 784, (128, 128, 512), (4, 8, 2)),
+    (8192, 254, (128, 128, 128), (64, 8, 2)),
+    (1, 784, (1, 128, 512), (1, 8, 2)),
+    (8, 784, (8, 128, 512), (1, 8, 2)),
+]
+
+
+@pytest.mark.parametrize("name", list(VARIANTS))
+def test_variant_blocks_and_launch_shapes(name):
+    """Each (source, emit) variant keeps its block choice, its kernel's
+    name, and its grid, block and scratch shapes at the cells' widths."""
+    source, emit = VARIANTS[name]
+    fam = registry.cws_family(source, emit)
+    # the one measured-table entry these shapes reach is the stored
+    # unpacked family's; the others take the heuristic
+    assert registry.choose_blocks(4096, 1024, 1024, op=fam) == (
+        (256, 128, 512) if fam == "cws" else (128, 128, 1024))
+    for n, d, (bn, bk, bd), grid in CELL_LAUNCHES:
+        assert registry.choose_blocks(n, d, 1024, op=fam) == (bn, bk, bd)
+        fn, shapes = _kernel_call(name, n, d, 1024, 8)
+        (launch,) = extract_launches(fn, *shapes)
+        assert launch.name == f"{name}_pallas"
+        assert launch.grid == grid
+        params = [(bd, bk)] * 3 if source == "stored" else [(2,)]
+        assert [o.block_shape for o in launch.inputs] == [(bn, bd)] + params
+        outs = {"hash": [(bn, bk)] * 2, "index": [(bn, bk)],
+                "packed": [(1, bn, bk * 8 // 32)]}[emit]
+        assert [o.block_shape for o in launch.outputs] == outs
+        f32, i32 = "float32", "int32"
+        scratch = ([((bd, bn), f32)]
+                   + [((bd, bk), f32)] * 3 * (source == "regen")
+                   + [((bn, bk), f32), ((bn, bk), i32)]
+                   + [((bn, bk), f32)] * (emit == "hash"))
+        assert [(o.shape, jnp.dtype(o.dtype).name)
+                for o in launch.scratch] == scratch
 
 
 GRAM_SHAPES = [

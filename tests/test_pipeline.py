@@ -1,5 +1,6 @@
-"""Fused featurization (cws_encode) vs the staged reference composition,
-plus registry dispatch and FeaturePipeline streaming/sharding semantics.
+"""Fused featurization (ops.cws, emit="index") vs the staged reference
+composition, plus registry dispatch and FeaturePipeline
+streaming/sharding semantics.
 
 The staged composition ``feature_indices(encode(cws_hash_reference(...)))``
 survives in tests as the oracle; the fused kernel must be BIT-exact
@@ -45,8 +46,8 @@ class TestFusedEncodeBitExact:
         x = x.at[4].set(0.0)                        # an all-zero row too
         p = make_cws_params(jax.random.PRNGKey(1), d, k)
         want = staged_oracle(x, p, b_i, b_t)
-        got = ops.cws_encode(x, p, b_i=b_i, b_t=b_t, bn=4, bk=4, bd=8,
-                             interpret=True)
+        got = ops.cws(x, p, emit="index", b_i=b_i, b_t=b_t, bn=4, bk=4,
+                      bd=8, interpret=True)
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
     @pytest.mark.parametrize("n,d,k,bn,bk,bd", [
@@ -68,8 +69,8 @@ class TestFusedEncodeBitExact:
         x = x.at[0].set(0.0).at[1, :last].set(0.0).at[1, last:].add(0.5)
         p = make_cws_params(jax.random.PRNGKey(d + k), d, k)
         want = staged_oracle(x, p, b_i=4, b_t=1)
-        got = ops.cws_encode(x, p, b_i=4, b_t=1, bn=bn, bk=bk, bd=bd,
-                             interpret=True)
+        got = ops.cws(x, p, emit="index", b_i=4, b_t=1, bn=bn, bk=bk,
+                      bd=bd, interpret=True)
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
     def test_all_zero_rows_bucket0(self):
@@ -77,8 +78,8 @@ class TestFusedEncodeBitExact:
         n, d, k, b_i = 6, 16, 9, 3
         x = jnp.zeros((n, d))
         p = make_cws_params(jax.random.PRNGKey(2), d, k)
-        got = np.asarray(ops.cws_encode(x, p, b_i=b_i, bn=4, bk=4, bd=8,
-                                        interpret=True))
+        got = np.asarray(ops.cws(x, p, emit="index", b_i=b_i, bn=4, bk=4,
+                                 bd=8, interpret=True))
         want = np.arange(k, dtype=np.int32)[None, :] * (1 << b_i)
         np.testing.assert_array_equal(got, np.broadcast_to(want, (n, k)))
 
@@ -86,7 +87,7 @@ class TestFusedEncodeBitExact:
         x = rand_nonneg(jax.random.PRNGKey(5), (19, 31))
         p = make_cws_params(jax.random.PRNGKey(6), 31, 14)
         want = staged_oracle(x, p, b_i=8, b_t=2)
-        got = ops.cws_encode(x, p, b_i=8, b_t=2, impl="reference")
+        got = ops.cws(x, p, emit="index", b_i=8, b_t=2, impl="reference")
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
 
@@ -157,13 +158,13 @@ class TestFeaturePipeline:
 
 class TestRegistry:
     def test_ops_registered(self):
-        for op in ("cws_hash", "cws_encode", "minmax_gram", "min_sum"):
+        for op in ("cws", "minmax_gram", "min_sum"):
             names = registry.impl_names(op)
             assert "pallas-interpret" in names and "reference" in names
             assert "pallas" in names
 
     def test_auto_dispatch_by_capability(self):
-        impl = registry.resolve("cws_encode")
+        impl = registry.resolve("cws")
         if registry.on_tpu():
             assert impl.name == "pallas"
         else:
@@ -173,11 +174,24 @@ class TestRegistry:
         if registry.on_tpu():
             pytest.skip("pallas is available on TPU")
         with pytest.raises(RuntimeError):
-            registry.resolve("cws_hash", "pallas")
+            registry.resolve("cws", "pallas")
 
     def test_unknown_impl_rejected(self):
         with pytest.raises(KeyError):
-            registry.resolve("cws_hash", "no-such-impl")
+            registry.resolve("cws", "no-such-impl")
+
+    def test_cws_rejects_what_it_cannot_launch(self):
+        x = jnp.ones((4, 8))
+        p = make_cws_params(jax.random.PRNGKey(0), 8, 4)
+        key = jax.random.PRNGKey(1)
+        with pytest.raises(ValueError, match="num_hashes"):
+            ops.cws(x, key, emit="index", b_i=2)      # regen needs k
+        with pytest.raises(ValueError, match="carry 4"):
+            ops.cws(x, p, emit="index", num_hashes=3, b_i=2)
+        with pytest.raises(ValueError, match="no CWS variant"):
+            ops.cws(x, p, emit="codes", b_i=2)
+        with pytest.raises(ValueError, match="no CWS variant"):
+            registry.cws_family("sparse", "index")
 
     def test_choose_blocks_bounds(self):
         for (n, d, k) in [(4, 8, 4), (33, 50, 21), (1024, 512, 512),

@@ -1,10 +1,11 @@
-"""Zero-parameter-traffic (regenerated-RNG) CWS: the rng Pallas kernels
-vs the counter-based oracle, tile/key-order independence, the param-free
-pipeline mode, and the measured-autotune registry plumbing.
+"""Zero-parameter-traffic (regenerated-RNG) CWS: the CWS kernel on key
+words vs the counter-based oracle, tile/key-order independence, the
+param-free pipeline mode, and the measured-autotune registry plumbing.
 
-Contract (DESIGN.md §3 + §7): `cws_hash_rng_pallas` / `cws_encode_rng_pallas`
-and `cws_hash_regen` all evaluate the SAME elementwise (key, d, k) ->
-(r, log_c, beta) map (threefry2x32 counter spec in repro.core.regen), so
+Contract (DESIGN.md §3 + §7): the kernel's regenerated-parameter variants
+(`ops.cws` on key words) and `cws_hash_regen` all evaluate the SAME
+elementwise (key, d, k) -> (r, log_c, beta) map (threefry2x32 counter
+spec in repro.core.regen), so
 (i*, t*) — and therefore the fused indices — are BIT-identical across
 implementations and across any tile decomposition.  Tests enforce
 equality, not allclose.
@@ -119,8 +120,8 @@ class TestHashRngBitExact:
         x = with_edge_rows(x, bd)
         key = jax.random.PRNGKey(d + k)
         want_i, want_t = cws_hash_regen(x, key, k)
-        got_i, got_t = ops.cws_hash_rng(x, key, k, bn=bn, bk=bk, bd=bd,
-                                        interpret=True)
+        got_i, got_t = ops.cws(x, key, emit="hash", num_hashes=k, bn=bn,
+                               bk=bk, bd=bd, interpret=True)
         np.testing.assert_array_equal(np.asarray(want_i), np.asarray(got_i))
         np.testing.assert_array_equal(np.asarray(want_t), np.asarray(got_t))
         assert (np.asarray(got_i[1]) >= last_block(d, bd)).all()
@@ -131,8 +132,10 @@ class TestHashRngBitExact:
         coordinates, not tile-local state."""
         x = rand_nonneg(jax.random.PRNGKey(2), (19, 30))
         key = jax.random.PRNGKey(9)
-        a = ops.cws_hash_rng(x, key, 14, bn=4, bk=4, bd=8, interpret=True)
-        b = ops.cws_hash_rng(x, key, 14, bn=16, bk=8, bd=32, interpret=True)
+        a = ops.cws(x, key, emit="hash", num_hashes=14, bn=4, bk=4, bd=8,
+                    interpret=True)
+        b = ops.cws(x, key, emit="hash", num_hashes=14, bn=16, bk=8, bd=32,
+                    interpret=True)
         np.testing.assert_array_equal(np.asarray(a[0]), np.asarray(b[0]))
         np.testing.assert_array_equal(np.asarray(a[1]), np.asarray(b[1]))
 
@@ -146,8 +149,8 @@ class TestEncodeRngBitExact:
         x = x.at[4].set(0.0)
         key = jax.random.PRNGKey(1)
         want = regen_staged_oracle(x, key, k, b_i, b_t)
-        got = ops.cws_encode_rng(x, key, k, b_i=b_i, b_t=b_t, bn=4, bk=4,
-                                 bd=8, interpret=True)
+        got = ops.cws(x, key, emit="index", num_hashes=k, b_i=b_i, b_t=b_t,
+                      bn=4, bk=4, bd=8, interpret=True)
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
     @pytest.mark.parametrize("n,d,k,bn,bk,bd", TAIL_SHAPES)
@@ -155,16 +158,16 @@ class TestEncodeRngBitExact:
         x = with_edge_rows(rand_nonneg(jax.random.PRNGKey(d), (n, d)), bd)
         key = jax.random.PRNGKey(3)
         want = regen_staged_oracle(x, key, k, 4, 1)
-        got = ops.cws_encode_rng(x, key, k, b_i=4, b_t=1, bn=bn, bk=bk,
-                                 bd=bd, interpret=True)
+        got = ops.cws(x, key, emit="index", num_hashes=k, b_i=4, b_t=1,
+                      bn=bn, bk=bk, bd=bd, interpret=True)
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
     def test_all_zero_rows_bucket0(self):
         n, d, k, b_i = 6, 16, 9, 3
         x = jnp.zeros((n, d))
         key = jax.random.PRNGKey(2)
-        got = np.asarray(ops.cws_encode_rng(x, key, k, b_i=b_i, bn=4, bk=4,
-                                            bd=8, interpret=True))
+        got = np.asarray(ops.cws(x, key, emit="index", num_hashes=k,
+                                 b_i=b_i, bn=4, bk=4, bd=8, interpret=True))
         want = np.arange(k, dtype=np.int32)[None, :] * (1 << b_i)
         np.testing.assert_array_equal(got, np.broadcast_to(want, (n, k)))
 
@@ -172,7 +175,8 @@ class TestEncodeRngBitExact:
         x = rand_nonneg(jax.random.PRNGKey(5), (19, 31))
         key = jax.random.PRNGKey(6)
         want = regen_staged_oracle(x, key, 14, 8, 2)
-        got = ops.cws_encode_rng(x, key, 14, b_i=8, b_t=2, impl="reference")
+        got = ops.cws(x, key, emit="index", num_hashes=14, b_i=8, b_t=2,
+                      impl="reference")
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
     def test_collision_rate_estimates_kernel(self):
@@ -254,7 +258,7 @@ class TestParamFreePipeline:
 
 class TestRegistryAutotune:
     def test_new_op_families_registered(self):
-        for op in ("cws_hash_rng", "cws_encode_rng", "min_sum"):
+        for op in ("cws", "min_sum"):
             names = registry.impl_names(op)
             assert {"pallas", "pallas-interpret", "reference"} <= set(names)
 

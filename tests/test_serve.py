@@ -303,15 +303,17 @@ def test_runner_rejects_non_bucket_dispatch():
 
 def test_serve_bucket_table_roundtrip(tmp_path):
     try:
-        registry.update_serve_buckets({"cws_encode_rng": (2, 16, 256)})
-        # aliases resolve to the family, like the block table
-        assert registry.serve_buckets("cws_rng") == (2, 16, 256)
+        registry.update_serve_buckets({"cws_rng": (2, 16, 256)})
+        # keyed by block family, like the block table: a regen pipeline's
+        # launches are (regen, index) -> cws_rng
+        assert registry.serve_buckets(
+            registry.cws_family("regen", "index")) == (2, 16, 256)
         assert registry.serve_buckets("cws") == registry.DEFAULT_SERVE_BUCKETS
         registry.save_serve_buckets(tmp_path / "buckets.json")
         registry.SERVE_BUCKET_TABLE.clear()
         entries = registry.load_serve_buckets(tmp_path / "buckets.json")
         assert entries == {"cws_rng": (2, 16, 256)}
-        assert registry.serve_buckets("cws_encode_rng") == (2, 16, 256)
+        assert registry.serve_buckets("cws_rng") == (2, 16, 256)
         # a runner built without buckets= picks the persisted ladder
         params, pipe = make_problem("regen")
         assert BucketRunner(params, pipe).buckets == (2, 16, 256)

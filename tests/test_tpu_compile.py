@@ -26,6 +26,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.minmax_paper import CONFIG
+from repro.core.cws import CWSParams
 from repro.kernels import cws_hash as ch
 from repro.kernels import registry
 from repro.kernels.minmax_gram import min_sum_pallas
@@ -66,50 +67,30 @@ def _compiled_text(fn, shapes, sharding) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _stored(n, fam, d=D):
-    bn, bk, bd = registry.choose_blocks(n, d, K, op=fam)
-    x, p = ((n, d), jnp.float32), ((d, K), jnp.float32)
-    return (bn, bk, bd), [x, p, p, p]
-
-
-def _regen(n, fam, d=D):
-    bn, bk, bd = registry.choose_blocks(n, d, K, op=fam)
-    return (bn, bk, bd), [((n, d), jnp.float32), ((2,), jnp.uint32)]
+# each kernel's name, less "_pallas" -> its (parameter source, emit)
+VARIANTS = {name[:-len("_pallas")]: variant
+            for variant, name in ch.KERNEL_NAMES.items()}
 
 
 def _case(name, b=B_I, n=N, d=D):
-    """(fn, arg shapes) for one kernel family at the config widths (D =
-    ``d`` where given)."""
-    if name == "cws_hash":
-        (bn, bk, bd), shapes = _stored(n, "cws")
-        return (lambda x, r, c, be: ch.cws_hash_pallas(
-            x, r, c, be, bn=bn, bk=bk, bd=bd)), shapes
-    if name == "cws_encode":
-        (bn, bk, bd), shapes = _stored(n, "cws", d)
-        return (lambda x, r, c, be: ch.cws_encode_pallas(
-            x, r, c, be, b_i=b, bn=bn, bk=bk, bd=bd)), shapes
-    if name == "cws_encode_packed":
-        (bn, bk, bd), shapes = _stored(n, "cws_packed")
-        return (lambda x, r, c, be: ch.cws_encode_packed_pallas(
-            x, r, c, be, b_i=b, bn=bn, bk=bk, bd=bd)), shapes
-    if name == "cws_hash_rng":
-        (bn, bk, bd), shapes = _regen(n, "cws_rng")
-        return (lambda x, key: ch.cws_hash_rng_pallas(
-            x, key, K, bn=bn, bk=bk, bd=bd)), shapes
-    if name == "cws_encode_rng":
-        (bn, bk, bd), shapes = _regen(n, "cws_rng")
-        return (lambda x, key: ch.cws_encode_rng_pallas(
-            x, key, K, b_i=b, bn=bn, bk=bk, bd=bd)), shapes
-    if name == "cws_encode_rng_packed":
-        (bn, bk, bd), shapes = _regen(n, "cws_rng_packed", d)
-        return (lambda x, key: ch.cws_encode_rng_packed_pallas(
-            x, key, K, b_i=b, bn=bn, bk=bk, bd=bd)), shapes
+    """(fn, arg shapes) for one kernel at the config widths (D = ``d``
+    where given)."""
     if name == "min_sum":
         bm, bn, bd = registry.choose_blocks(n, D, n, op="min_sum")
         x = ((n, D), jnp.float32)
         return (lambda a, c: min_sum_pallas(a, c, bm=bm, bn=bn,
                                             bd=bd)), [x, x]
-    raise KeyError(name)
+    source, emit = VARIANTS[name]
+    bn, bk, bd = registry.choose_blocks(
+        n, d, K, op=registry.cws_family(source, emit))
+    blocks = dict(emit=emit, b_i=b, bn=bn, bk=bk, bd=bd)
+    x = ((n, d), jnp.float32)
+    if source == "regen":
+        return (lambda x, key: ch.cws_pallas(x, key, K, **blocks)), [
+            x, ((2,), jnp.uint32)]
+    p = ((d, K), jnp.float32)
+    return (lambda x, r, c, be: ch.cws_pallas(
+        x, CWSParams(r, c, be), **blocks)), [x, p, p, p]
 
 
 @pytest.mark.parametrize("name,b", [
@@ -151,9 +132,10 @@ def test_serving_bucket_blocks_compile_for_v5e(one_chip, rows):
     "cws_encode_packed", "cws_encode_rng_packed",
 ])
 def test_kernel_keeps_its_name_in_the_tpu_lowering(name):
-    """Each Mosaic kernel is named after its wrapper, whatever the kernel
-    body's Python function is called; profiles and their readers match
-    these names.  Lowering for the TPU needs no chip and no topology."""
+    """Each Mosaic kernel is named after its (source, emit) variant,
+    whatever the kernel body's Python function is called; profiles and
+    their readers match these names.  Lowering for the TPU needs no chip
+    and no topology."""
     fn, shapes = _case(name)
     args = [jax.ShapeDtypeStruct(s, dt) for s, dt in shapes]
     text = jax.jit(fn).trace(*args).lower(

@@ -56,23 +56,14 @@ def _make_launcher(op: str, n: int, d: int, k: int):
     pinned to the kernel-body impl of the local backend."""
     impl = registry.pallas_impl()
     x = rand_nonneg(jax.random.PRNGKey(0), (n, d))
-    if op == "cws":
-        params = make_cws_params(jax.random.PRNGKey(1), d, k)
-        return lambda b: ops.cws_encode(x, params, b_i=8, bn=b[0], bk=b[1],
-                                        bd=b[2], impl=impl)
-    if op == "cws_rng":
-        key = jax.random.PRNGKey(1)
-        return lambda b: ops.cws_encode_rng(x, key, k, b_i=8, bn=b[0],
-                                            bk=b[1], bd=b[2], impl=impl)
-    if op == "cws_packed":
-        params = make_cws_params(jax.random.PRNGKey(1), d, k)
-        return lambda b: ops.cws_encode_packed(x, params, b_i=8, bn=b[0],
-                                               bk=b[1], bd=b[2], impl=impl)
-    if op == "cws_rng_packed":
-        key = jax.random.PRNGKey(1)
-        return lambda b: ops.cws_encode_rng_packed(x, key, k, b_i=8,
-                                                   bn=b[0], bk=b[1],
-                                                   bd=b[2], impl=impl)
+    for source in registry.CWS_SOURCES:
+        for emit in ("index", "packed"):
+            if op == registry.cws_family(source, emit):
+                state = (make_cws_params(jax.random.PRNGKey(1), d, k)
+                         if source == "stored" else jax.random.PRNGKey(1))
+                return lambda b: ops.cws(x, state, emit=emit, num_hashes=k,
+                                         b_i=8, bn=b[0], bk=b[1], bd=b[2],
+                                         impl=impl)
     if op == "min_sum":
         y = rand_nonneg(jax.random.PRNGKey(2), (k, d))
         return lambda b: ops.min_sum(x, y, bm=b[0], bn=b[1], bd=b[2],
