@@ -8,16 +8,27 @@ TPU adaptation (vs the paper's per-vector CPU loop):
   * grid (rows/BN, hashes/BK, D/BD) with the D axis innermost — a running
     (best log_a, best index, best t) accumulator lives in VMEM scratch and
     is written to HBM once per (row, hash) tile at the last D step;
-  * inside a grid step we loop over the BD dimensions with a fori_loop,
-    each iteration doing rank-2 (BN x BK) VPU math (broadcast of the
-    column log u against the parameter row) — no rank-3 temporaries.
-    log u is staged once per grid step as a transposed (BD, BN) tile so
-    the loop reads dimension d as a sublane row (Mosaic refuses a
-    dynamic lane slice).  The loop runs over the real dimensions only:
+  * inside a grid step a fori_loop folds the BD dimensions into the
+    accumulators U at a time (``dims_per_trip``, a static function of the
+    tile, the carries and the trip count), each dimension rank-2
+    (BN x BK) VPU math (its log u column broadcast against the parameter
+    row) — no rank-3 temporaries.  log u is staged once per grid step as
+    a transposed (BD, BN) tile; a trip reads its U dimensions as one
+    (U, BN) sublane slab (Mosaic refuses a dynamic lane slice) and
+    relays it out to (BN, U) once, so the cross-lane transpose that one
+    dimension a trip paid every time is paid once per U, and the U
+    independent candidate tiles fill the VLIW bundles that one
+    dimension's latency chain left empty.  The candidates fold in
+    increasing d with the strict ``<``, so every output bit is as with
+    one dimension a trip.  The loop runs over the real dimensions only:
     where BD does not divide D, the last D step's loop stops at D
-    (``tail_dims``), so the zero pad columns cost no iterations;
-  * the kernel is VPU-bound (log/floor/mul on 8x128 lanes) and
-    HBM-traffic-dominated by the 3 parameter matrices (DESIGN.md §2).
+    (``tail_dims``), so the zero pad columns cost no iterations; a count
+    that U does not divide ends in one static trip of the rest;
+  * the kernel is VPU- and cross-lane-bound (floor/mul/select on 8x128
+    lanes, one lane broadcast of log u per 8 rows and dimension; the
+    divide lowers to one reciprocal of the parameter row and a
+    multiply) and HBM-traffic-dominated by the 3 parameter matrices
+    (DESIGN.md §2).
 
 One kernel body (``_cws_kernel``) behind one wrapper (``cws_pallas``),
 with two static choices:
@@ -96,31 +107,96 @@ def loop_share(d: int, bd: int) -> float:
     return d / (-(-d // bd) * bd)
 
 
-def _accum_loop(lut_ref, r_ref, logc_ref, beta_ref, d_step, bd, trips,
-                carry):
-    """Run the argmin update over the grid step's first ``trips``
-    dimensions on a (best_a, best_i[, best_t]) carry; t tracking is
-    skipped when the carry has no t slot."""
-    track_t = len(carry) == 3
+# The accumulation loop folds U dimensions a trip (``dims_per_trip``).
+# Its per-dimension chain (the load of log u, its cross-lane relayout and
+# lane broadcast, the compares) is long in latency and short in work, so
+# one dimension a trip leaves the VLIW bundles nearly empty; U
+# independent candidate tiles a trip fill them.  A v5e core has 64
+# vector registers of (8, 128) 32-bit words; what a trip holds beyond
+# them the LLO scheduler spills to VMEM.  A trip's candidates and
+# carries are bounded at five register files, and U at 16: for a
+# (128, 128) tile the bundles a dimension fall from 150 at U = 1 to 60 at
+# U = 16, and to only 57 at U = 32, which doubles the trip's code
+# (PERF.md §6).
+VREGS = 64
+TRIP_VREGS = 5 * VREGS
+MAX_DIMS_PER_TRIP = 16
 
-    def body(d, carry):
-        a, i = carry[0], carry[1]
-        lu = jnp.transpose(lut_ref[pl.ds(d, 1), :])  # (BN, 1)
-        r = r_ref[pl.ds(d, 1), :]                    # (1, BK)
-        lc = logc_ref[pl.ds(d, 1), :]
-        be = beta_ref[pl.ds(d, 1), :]
-        tt = jnp.floor(lu / r + be)                  # (BN, BK)
-        la = lc - r * (tt - be + 1.0)
-        la = jnp.where(jnp.isfinite(lu), la, jnp.inf)
-        upd = la < a
-        d_global = (d_step * bd + d).astype(jnp.int32)
-        a = jnp.where(upd, la, a)
-        i = jnp.where(upd, d_global, i)
-        if track_t:
-            return a, i, jnp.where(upd, tt, carry[2])
-        return a, i
 
-    return jax.lax.fori_loop(0, trips, body, carry)
+def tile_vregs(bn: int, bk: int) -> int:
+    """Vector registers one (bn, bk) float32 tile takes."""
+    return -(-bn // 8) * -(-bk // 128)
+
+
+def trip_vregs(bn: int, bk: int, carries: int, u: int) -> int:
+    """Vector registers of a trip's U candidate tiles and its carries."""
+    return (u + carries) * tile_vregs(bn, bk)
+
+
+def dims_per_trip(bn: int, bk: int, carries: int, trips: int) -> int:
+    """U: the dimensions each trip of the accumulation loop folds over a
+    (bn, bk) tile with ``carries`` accumulators, for a loop of ``trips``
+    dimensions.  The largest power of two up to ``MAX_DIMS_PER_TRIP``
+    whose trip keeps within ``TRIP_VREGS``, and at most ``trips``."""
+    u = MAX_DIMS_PER_TRIP
+    while u > 1 and trip_vregs(bn, bk, carries, u) > TRIP_VREGS:
+        u //= 2
+    return min(u, trips)
+
+
+def _fold(lu, r, lc, be, d_global, best):
+    """The argmin update of one dimension: ``lu`` its log u broadcast
+    over the (BN, BK) tile, ``r``/``lc``/``be`` its parameter rows,
+    ``best`` the (best_a, best_i[, best_t]) values it updates."""
+    a, i = best[0], best[1]
+    tt = jnp.floor(lu / r + be)                      # (BN, BK)
+    la = lc - r * (tt - be + 1.0)
+    la = jnp.where(jnp.isfinite(lu), la, jnp.inf)
+    upd = la < a
+    a = jnp.where(upd, la, a)
+    i = jnp.where(upd, d_global, i)
+    if len(best) == 3:
+        return a, i, jnp.where(upd, tt, best[2])
+    return a, i
+
+
+def _trip(lut_ref, r_ref, logc_ref, beta_ref, best, d_base, d0, u):
+    """Fold the grid step's dimensions d0 .. d0 + u - 1 into the
+    accumulators ``best``, in increasing d.  Their log u is read as one
+    (u, BN) slab of the staged transposed tile and relaid out once, to
+    (BN, u); each dimension's column is then broadcast over the tile, so
+    ``isfinite`` reads the broadcast and needs no relayout of its own."""
+    lus = jnp.transpose(lut_ref[pl.ds(d0, u), :])    # (BN, u)
+    vals = tuple(ref[...] for ref in best)
+    for j in range(u):
+        d = d0 + j
+        vals = _fold(jnp.broadcast_to(lus[:, j:j + 1], vals[0].shape),
+                     r_ref[pl.ds(d, 1), :], logc_ref[pl.ds(d, 1), :],
+                     beta_ref[pl.ds(d, 1), :],
+                     (d_base + d).astype(jnp.int32), vals)
+    for ref, v in zip(best, vals):
+        ref[...] = v
+
+
+def _accum_loop(lut_ref, r_ref, logc_ref, beta_ref, best, d_step, bd,
+                dims):
+    """Fold the grid step's first ``dims`` dimensions into ``best``:
+    ``dims // U`` trips of U dimensions, then the ``dims % U`` left as
+    one static trip.  No trip count is dynamic."""
+    bn, bk = best[0].shape
+    u = dims_per_trip(bn, bk, len(best), dims)
+    trips, rest = divmod(dims, u)
+    d_base = d_step * bd
+
+    def body(t, carry):
+        _trip(lut_ref, r_ref, logc_ref, beta_ref, best, d_base,
+              pl.multiple_of(t * u, u), u)
+        return carry
+
+    jax.lax.fori_loop(0, trips, body, 0)
+    if rest:
+        _trip(lut_ref, r_ref, logc_ref, beta_ref, best, d_base,
+              trips * u, rest)
 
 
 def _accumulate(lut_ref, r_ref, logc_ref, beta_ref, best, *, bd: int,
@@ -131,14 +207,12 @@ def _accumulate(lut_ref, r_ref, logc_ref, beta_ref, best, *, bd: int,
     The last D step loops over its ``tail`` real dimensions only: a pad
     column (log u = -inf) can never win the strict ``<``, so leaving it
     out changes no output bit.  Where ``bd`` divides D (``tail == bd``)
-    every step runs one loop of ``bd`` trips, with no branch."""
+    every step runs one loop over ``bd`` dimensions, with no branch."""
     d_step = pl.program_id(2)
 
-    def run(trips):
-        out = _accum_loop(lut_ref, r_ref, logc_ref, beta_ref, d_step, bd,
-                          trips, tuple(ref[...] for ref in best))
-        for ref, v in zip(best, out):
-            ref[...] = v
+    def run(dims):
+        _accum_loop(lut_ref, r_ref, logc_ref, beta_ref, best, d_step, bd,
+                    dims)
 
     if tail == bd:
         run(bd)
@@ -310,6 +384,28 @@ def hash_count(state, num_hashes: int | None) -> int:
     return num_hashes
 
 
+def _tile(n, d, k, emit, b, bn, bk, bd):
+    """The launch's (bn, bk, bd): each clamped to its dimension, and
+    bk legalized for the packed emit."""
+    bk = _packed_bk(bk, k, b) if emit == "packed" else min(bk, k)
+    return min(bn, n), bk, min(bd, d)
+
+
+def _carries(emit, b_t):
+    """Accumulators of a launch: (best_a, best_i), and best_t where the
+    emit keeps t* (the ``"hash"`` emit, or b_t > 0)."""
+    return 3 if emit == "hash" or b_t else 2
+
+
+def launch_dims_per_trip(n: int, d: int, k: int, *, emit: str,
+                         b_i: int = 0, b_t: int = 0, bn: int, bk: int,
+                         bd: int) -> int:
+    """U of a ``cws_pallas`` launch's main D steps (its ``bd``
+    dimensions a step), from the same static function the kernel uses."""
+    bn, bk, bd = _tile(n, d, k, emit, b_i + b_t, bn, bk, bd)
+    return dims_per_trip(bn, bk, _carries(emit, b_t), bd)
+
+
 def _packed_out(np_, kp_, bn, bk, b):
     """(out_spec, out_shape) of the packed emit: one (1, BN, BK·b/32)
     block per (row block, hash block)."""
@@ -350,8 +446,7 @@ def cws_pallas(x: jax.Array, state, num_hashes: int | None = None, *,
     n, d = x.shape
     k = hash_count(state, num_hashes)
     b = b_i + b_t
-    bn, bd = min(bn, n), min(bd, d)
-    bk = _packed_bk(bk, k, b) if emit == "packed" else min(bk, k)
+    bn, bk, bd = _tile(n, d, k, emit, b, bn, bk, bd)
     pad_n, pad_d, pad_k = (-n) % bn, (-d) % bd, (-k) % bk
     np_, dp_, kp_ = n + pad_n, d + pad_d, k + pad_k
     n_d_steps = dp_ // bd
@@ -375,7 +470,7 @@ def cws_pallas(x: jax.Array, state, num_hashes: int | None = None, *,
                                ] * 3
     scratch += [pltpu.VMEM((bn, bk), jnp.float32),     # best log_a
                 pltpu.VMEM((bn, bk), jnp.int32)]       # best index
-    if emit == "hash" or b_t:
+    if _carries(emit, b_t) == 3:
         scratch.append(pltpu.VMEM((bn, bk), jnp.float32))   # best t
 
     tile = pl.BlockSpec((bn, bk), lambda i, j, s: (i, j))

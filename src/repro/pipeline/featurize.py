@@ -43,7 +43,7 @@ from repro.core.hashing import (encode, feature_indices, hashed_dim,
                                 unpack_codes)
 from repro.core.regen import key_words
 from repro.kernels import ops, registry
-from repro.kernels.cws_hash import loop_share
+from repro.kernels.cws_hash import launch_dims_per_trip, loop_share
 from repro.launch.mesh import data_axis_size
 
 Array = jax.Array
@@ -295,7 +295,8 @@ class FeaturePipeline:
                       else m + ((-m) % ndev))
             with jax.profiler.TraceAnnotation(
                     LAUNCH_SPAN, rows=m,
-                    cws_loop_share=self.loop_share(target // ndev)):
+                    cws_loop_share=self.loop_share(target // ndev),
+                    cws_dims_per_trip=self.dims_per_trip(target // ndev)):
                 # host-resident rows (numpy/memmap) slice on the host, so
                 # only the chunk ever crosses to the device
                 chunk = (jax.lax.slice_in_dim(x, lo, hi, axis=0)
@@ -320,7 +321,8 @@ class FeaturePipeline:
         ``chunk_rows(mesh)`` row chunks; with a ``mesh`` every launch is
         shard_mapped over its ``data`` axis.  Each launch's host work
         (slice, pad, enqueue) is one ``LAUNCH_SPAN`` in a profile, with
-        the chunk's ``rows`` and the launch's ``cws_loop_share``."""
+        the chunk's ``rows`` and the launch's ``cws_loop_share`` and
+        ``cws_dims_per_trip``."""
         self._require_bucketed("features")
         n = x.shape[0]
         if n == 0:   # empty stream chunk: nothing to launch
@@ -331,7 +333,8 @@ class FeaturePipeline:
             ndev = 1 if mesh is None else data_axis_size(mesh)
             with jax.profiler.TraceAnnotation(
                     LAUNCH_SPAN, rows=n,
-                    cws_loop_share=self.loop_share(-(-n // ndev))):
+                    cws_loop_share=self.loop_share(-(-n // ndev)),
+                    cws_dims_per_trip=self.dims_per_trip(-(-n // ndev))):
                 return self._launch(x) if mesh is None else \
                     self._features_sharded(x, mesh)
         return self._features_streamed(x, mesh=mesh)
@@ -461,6 +464,16 @@ class FeaturePipeline:
         runs (``cws_loop_share`` of the launch and fit set-up spans)."""
         return loop_share(self.dim,
                           self._blocks_for(rows, self.dim, self.emit)[2])
+
+    def dims_per_trip(self, rows: int) -> int:
+        """The dimensions each trip of the kernel's accumulation loop
+        folds in the main D steps of a launch of ``rows`` rows on one
+        device (``cws_dims_per_trip`` of the launch and fit set-up
+        spans)."""
+        bn, bk, bd = self._blocks_for(rows, self.dim, self.emit)
+        return launch_dims_per_trip(
+            rows, self.dim, self.spec.num_hashes, emit=self.emit,
+            b_i=self.spec.b_i, b_t=self.spec.b_t, bn=bn, bk=bk, bd=bd)
 
     def _launch_with(self, x: Array, state) -> Array:
         """One kernel launch on explicit state (CWSParams or key words)."""

@@ -106,10 +106,12 @@ def _setup_args(pipe: FeaturePipeline, cfg: TrainCfg, mesh) -> dict:
     """The setup span's arguments: ``shards``, the devices the fit splits
     each batch over (the mesh's ``data`` axis; 1 without a mesh), and
     ``cws_loop_share``, the share of the padded dimension loop that the
-    kernel runs in each device's launch of a step."""
+    kernel runs in each device's launch of a step, and
+    ``cws_dims_per_trip``, the dimensions each trip of that loop folds."""
     shards = 1 if mesh is None else data_axis_size(mesh)
-    return {"shards": shards, "cws_loop_share":
-            pipe.loop_share(max(cfg.batch_size // shards, 1))}
+    rows = max(cfg.batch_size // shards, 1)
+    return {"shards": shards, "cws_loop_share": pipe.loop_share(rows),
+            "cws_dims_per_trip": pipe.dims_per_trip(rows)}
 
 
 __all__ = ["fit_linear_streamed", "resume_linear_streamed",

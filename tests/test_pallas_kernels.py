@@ -5,8 +5,10 @@ import numpy as np
 import pytest
 
 from repro.configs.minmax_paper import CONFIG
+from repro.core import hashing
 from repro.core.cws import (CWSParams, make_cws_params,
                             cws_hash as cws_hash_core)
+from repro.core.regen import regen_params
 from repro.analysis.launches import extract_launches
 from repro.kernels import cws_hash as ch
 from repro.kernels import ops, registry
@@ -120,21 +122,130 @@ def test_tail_trip_count_and_loop_share(d, bd, tail, share):
     assert ch.loop_share(d, bd) == share
 
 
-def _loop_trips(jaxpr, trips=None, branches=0):
-    """(trip count of every loop, number of branches) in ``jaxpr`` and
-    the jaxprs inside it, the kernel body's included."""
-    trips = [] if trips is None else trips
+# (n, D, bn, bd) of each case, with its trips at the U = 16 that a
+# (bn, 16) tile takes: every variant, with and without t* tracked, at a
+# step of 8 trips and a tail of 35 (2 trips and a remainder of 3) at
+# bn = 128; then one or two variants at the train cell's tail of 272 (17
+# trips), the featurize cell's tail of 126 (7 trips and 14) at bn = 128
+# and at the serving bucket's bn = 8, and tails of 16 at bn = 1, 7 (below
+# U: one trip of 7) and 1
+TRIP_CASES = [
+    (source, emit, b_t, 128, 163, 128, 128)
+    for source, emit in ch.KERNEL_NAMES for b_t in (0, 2)
+] + [
+    ("stored", "index", 0, 128, 784, 128, 512),
+    ("stored", "hash", 0, 128, 784, 128, 512),
+    ("regen", "packed", 0, 128, 254, 128, 128),
+    ("stored", "index", 2, 128, 254, 128, 128),
+    ("stored", "index", 0, 8, 254, 8, 128),
+    ("regen", "index", 0, 1, 48, 1, 32),
+    ("stored", "hash", 0, 8, 39, 8, 32),
+    ("stored", "packed", 2, 128, 33, 128, 32),
+]
+
+
+def _plain_reference(x, p, emit, b_i, b_t):
+    """The naive oracle's (i*, t*) through the b-bit encode and, for the
+    index and packed emits, the offsets or the packing."""
+    i_star, t_star = cws_hash_ref(x, p.r, p.log_c, p.beta)
+    if emit == "hash":
+        return i_star, t_star
+    codes = hashing.encode(i_star, t_star, b_i=b_i, b_t=b_t)
+    if emit == "packed":
+        return hashing.pack_codes(codes, b=b_i + b_t)
+    return hashing.feature_indices(codes, b_i=b_i, b_t=b_t)
+
+
+@pytest.mark.parametrize("source,emit,b_t,n,d,bn,bd", TRIP_CASES)
+def test_variant_bit_identical_at_every_trip_count(source, emit, b_t, n, d,
+                                                   bn, bd):
+    """Every (source, emit) variant, with t* tracked (b_t > 0, or the hash
+    emit) and without, equals the plain oracle and core.cws bit for bit,
+    whether or not U divides the tail's dimensions.  Row 0 is all zero
+    (the sentinel), row 1 nonzero only in the last D block, and one
+    entry is +inf (log u not finite: it never wins).  With stored
+    parameters, four dimensions repeat an earlier one: 3 repeats 2 (one
+    trip), 16 repeats 15 (two trips), D - 2 repeats D - 3 (the tail's
+    last trip, or its remainder) and the last repeats 0 (another D
+    step): exact ties, which the strict ``<`` must break to the lower
+    index whatever trip each dimension falls in."""
+    k, bk = 24, 16
+    b_i = 8 - b_t if emit == "packed" else 4
+    x = rand_nonneg(jax.random.PRNGKey(d), (n, d))
+    if source == "stored":
+        p = make_cws_params(jax.random.PRNGKey(1), d, k)
+        for src, dst in [(2, 3), (15, 16), (d - 3, d - 2), (0, d - 1)]:
+            x = x.at[:, dst].set(x[:, src])
+            p = CWSParams(*(m.at[dst].set(m[src])
+                            for m in (p.r, p.log_c, p.beta)))
+        state = p
+    else:
+        state = jax.random.PRNGKey(5)
+        p = regen_params(state, d, k)
+    x = with_edge_rows(x, bd).at[n - 1, d // 2].set(jnp.inf)
+    kw = dict(emit=emit, num_hashes=None if source == "stored" else k,
+              b_i=b_i, b_t=b_t, bn=bn, bk=bk, bd=bd)
+    got = ops.cws(x, state, impl="pallas-interpret", **kw)
+    for want in (_plain_reference(x, p, emit, b_i, b_t),
+                 ops.cws(x, state, impl="reference", **kw)):
+        for g, w in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("carries", [2, 3])
+@pytest.mark.parametrize("bn,bk", [(1, 128), (8, 128), (128, 128),
+                                   (256, 128), (128, 256), (8, 16)])
+def test_dims_per_trip_fits_the_trip_and_the_vreg_budget(bn, bk, carries):
+    """U never exceeds the trip count, is the trip count below its
+    nominal value, and keeps the trip within the budget it states."""
+    nominal = ch.dims_per_trip(bn, bk, carries, 1 << 20)
+    assert ch.trip_vregs(bn, bk, carries, nominal) <= ch.TRIP_VREGS
+    for trips in range(1, 2 * nominal + 3):
+        u = ch.dims_per_trip(bn, bk, carries, trips)
+        assert 1 <= u <= trips
+        assert u == min(trips, nominal)
+
+
+def _loop_trips(jaxpr, dims=None, branches=0):
+    """(real dimensions of every accumulation loop, number of branches)
+    in ``jaxpr`` and the jaxprs inside it, the kernel body's included.
+
+    Each dimension a loop folds is one ``floor``: a loop covers its
+    trips times the floors of its body, plus the floors of the static
+    trip that follows it in the same block."""
+    dims = [] if dims is None else dims
+    if any(eqn.primitive.name == "scan" for eqn in jaxpr.eqns):
+        dims.append(_folds(jaxpr))
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "scan":
-            trips.append(eqn.params["length"])
         assert eqn.primitive.name != "while", "a loop with no static trips"
         branches += eqn.primitive.name == "cond"
-        for v in eqn.params.values():
-            for sub in v if isinstance(v, (tuple, list)) else (v,):
-                sub = getattr(sub, "jaxpr", sub)
-                if isinstance(sub, jax.extend.core.Jaxpr):
-                    _, branches = _loop_trips(sub, trips, branches)
-    return trips, branches
+        if eqn.primitive.name == "scan":
+            continue
+        for sub in _subjaxprs(eqn):
+            _, branches = _loop_trips(sub, dims, branches)
+    return dims, branches
+
+
+def _subjaxprs(eqn):
+    for v in eqn.params.values():
+        for sub in v if isinstance(v, (tuple, list)) else (v,):
+            sub = getattr(sub, "jaxpr", sub)
+            if isinstance(sub, jax.extend.core.Jaxpr):
+                yield sub
+
+
+def _folds(jaxpr):
+    """Dimensions folded in ``jaxpr`` outside its branches: its floors,
+    and each loop's trips times its body's."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "floor":
+            n += 1
+        elif eqn.primitive.name == "scan":
+            n += eqn.params["length"] * sum(_folds(sub)
+                                            for sub in _subjaxprs(eqn))
+    return n
 
 
 # each kernel's name, less "_pallas" -> its (parameter source, emit)
@@ -167,10 +278,11 @@ def _kernel_call(name, n, d, k, b):
     "cws_encode_rng", "cws_encode_rng_packed",
 ])
 def test_kernel_loops_over_the_real_dimensions(name, d, trips):
-    """Where bd divides D, each D step runs one loop of bd trips and the
+    """Where bd divides D, each D step's loop covers bd dimensions and the
     body branches only for its init and emit, as before the tail; else
-    the last D step's loop runs the tail's trips under a branch of its
-    own."""
+    the last D step's loop covers the tail's real dimensions under a
+    branch of its own.  A loop covers its trips times the U dimensions a
+    trip folds, plus the static remainder."""
     fn, shapes = _kernel_call(name, 512, d, CONFIG.num_hashes, CONFIG.b_i)
     jaxpr = jax.make_jaxpr(fn)(*shapes).jaxpr
     got, branches = _loop_trips(jaxpr)

@@ -7,7 +7,8 @@ around the call, ``repro.fit.setup`` before the first step (carrying
 ``FeaturePipeline.features`` records one ``repro.featurize.launch`` per
 chunk it launches, with the chunk's ``rows``.  The set-up and launch
 spans also carry ``cws_loop_share``, the real D over the padded D that
-the CWS kernel would otherwise loop over.  Each test takes a profile
+the CWS kernel would otherwise loop over, and ``cws_dims_per_trip``, the
+dimensions each trip of its accumulation loop folds.  Each test takes a profile
 on the CPU with ``jax.profiler.trace`` and reads it back with
 ``jax.profiler.ProfileData``: the spans are there when a profile is being
 taken, and the outputs are the same bits whether one is or not.
@@ -203,6 +204,33 @@ def test_setup_and_launches_carry_the_loop_share(tmp_path, dim, share):
     assert setup.args.get("cws_loop_share") == share
     launches = named(spans, "repro.featurize.launch")
     assert [s.args.get("cws_loop_share") for s in launches] == [share] * 4
+
+
+@pytest.mark.parametrize("dim,u", [
+    (8, 8),             # bd = 8: U falls back to the 8 dimensions a step
+    (200, 16),          # bd = 128: U = 16 over the (16, 16) and (8, 16) tiles
+])
+def test_setup_and_launches_carry_the_dims_per_trip(tmp_path, dim, u):
+    """The set-up and launch spans carry ``cws_dims_per_trip``, the U that
+    the kernel's accumulation loop folds a trip in its main D steps."""
+    pipe = FeaturePipeline.create(jax.random.PRNGKey(7), dim,
+                                  FeatureSpec(num_hashes=24, b_i=4),
+                                  row_chunk=ROW_CHUNK)
+    x = jax.random.uniform(jax.random.PRNGKey(0), (64, dim))
+    y = (x[:, 0] > x[:, 1]).astype(jnp.int32)
+    cfg = TrainCfg(n_classes=2, steps=2, batch_size=16)
+    p0 = init_bag(jax.random.PRNGKey(1), pipe.num_features, 2)
+    assert pipe.dims_per_trip(cfg.batch_size) == u
+
+    def work():
+        return (fit_linear_streamed(p0, pipe, x, y, cfg=cfg),
+                pipe.features(x[:3 * ROW_CHUNK + 5]))
+
+    _, spans = profiled(tmp_path, work)
+    (setup,) = named(spans, "repro.fit.setup")
+    assert setup.args.get("cws_dims_per_trip") == u
+    launches = named(spans, "repro.featurize.launch")
+    assert [s.args.get("cws_dims_per_trip") for s in launches] == [u] * 4
 
 
 FOUR_DEVICE_SETUP = r"""

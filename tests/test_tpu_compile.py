@@ -72,9 +72,9 @@ VARIANTS = {name[:-len("_pallas")]: variant
             for variant, name in ch.KERNEL_NAMES.items()}
 
 
-def _case(name, b=B_I, n=N, d=D):
+def _case(name, b=B_I, n=N, d=D, b_t=0):
     """(fn, arg shapes) for one kernel at the config widths (D = ``d``
-    where given)."""
+    where given; ``b`` is b_i)."""
     if name == "min_sum":
         bm, bn, bd = registry.choose_blocks(n, D, n, op="min_sum")
         x = ((n, D), jnp.float32)
@@ -83,7 +83,7 @@ def _case(name, b=B_I, n=N, d=D):
     source, emit = VARIANTS[name]
     bn, bk, bd = registry.choose_blocks(
         n, d, K, op=registry.cws_family(source, emit))
-    blocks = dict(emit=emit, b_i=b, bn=bn, bk=bk, bd=bd)
+    blocks = dict(emit=emit, b_i=b, b_t=b_t, bn=bn, bk=bk, bd=bd)
     x = ((n, d), jnp.float32)
     if source == "regen":
         return (lambda x, key: ch.cws_pallas(x, key, K, **blocks)), [
@@ -115,6 +115,20 @@ def test_tail_loop_compiles_for_v5e_at_the_cells_widths(one_chip, name, n,
     loop stops at the real D under a branch of its own; Mosaic must
     lower it."""
     fn, shapes = _case(name, 8, n=n, d=d)
+    assert "tpu_custom_call" in _compiled_text(fn, shapes, one_chip)
+
+
+@pytest.mark.parametrize("name,b_i,b_t", [
+    ("cws_hash", B_I, 2),
+    ("cws_encode", 6, 2),
+    ("cws_encode_rng_packed", 6, 2),
+])
+def test_tracked_t_compiles_for_v5e_at_the_train_cells_widths(one_chip, name,
+                                                              b_i, b_t):
+    """Where t* is tracked (the hash emit, or b_t > 0) the accumulation
+    loop carries a third accumulator; Mosaic must lower it at the train
+    cell's launch (512 rows, D = 784: bd 512, tail 272)."""
+    fn, shapes = _case(name, b_i, n=N, d=784, b_t=b_t)
     assert "tpu_custom_call" in _compiled_text(fn, shapes, one_chip)
 
 
